@@ -1095,6 +1095,8 @@ def main() -> None:
     selected = [b.__name__ for b in BENCHES
                 if not args.only or args.only in b.__name__]
     _force_cpu_mesh_for(selected)   # must precede any repro/jax import
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.engine is not None:
         from repro.fl.engine import ENGINE_PRESETS
         if args.engine not in ENGINE_PRESETS:

@@ -72,7 +72,6 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import aggregation as agg
@@ -238,7 +237,10 @@ class FleetExecutor:
                     jax.tree.map(sel, new_state["mu"], mom), loss)
 
         self._one = one          # per-client step; ShardedFleetExecutor remaps
-        self._step = jax.jit(jax.vmap(one))
+        # Params and momentum are replaced every step, so the step donates
+        # them: the outputs reuse the inputs' device buffers, which is what
+        # lets a client-stacked full-width model fit on one chip.
+        self._step = jax.jit(jax.vmap(one), donate_argnums=(0, 1))
         self.profile = bool(getattr(cfg, "profile_phases", False))
         self._phase: dict = {}
 
@@ -292,7 +294,9 @@ class FleetExecutor:
             return params
         steps, actives = self._draw_session(mask)
         mom = jax.tree.map(lambda p: jnp.zeros_like(p, jnp.float32), params)
-        anchor = params      # prox anchor = the received model (host default)
+        # prox anchor = the received model (host default); a copy of its
+        # own, because the fleet step donates the params buffers it reads.
+        anchor = jax.tree.map(jnp.copy, params) if self.prox else None
         for batch, active in zip(steps, actives):
             params, mom, _ = self._step(params, mom, batch, active, anchor)
         return params
@@ -647,8 +651,8 @@ class ShardedFleetExecutor(FleetExecutor):
     # ------------------------------------------------------- compiled planes
 
     def _shmap(self, f, in_specs, out_specs):
-        return jax.jit(shard_map(f, mesh=self.mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False))
+        return jax.jit(jax.shard_map(f, mesh=self.mesh, in_specs=in_specs,
+                                     out_specs=out_specs, check_vma=False))
 
     def _build(self) -> None:
         axes = self._axes

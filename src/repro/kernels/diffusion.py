@@ -40,9 +40,9 @@ instead of long per-leaf ``jnp`` chains:
   tested against (which is itself validated against
   ``repro.core.dol.iid_distance_candidates``).
 
-All kernels carry ``interpret=`` so CI's pallas-interpret job runs the
-bodies on CPU; dispatch (auto/pallas/pallas_interpret/ref) lives in
-``kernels.ops``.
+All kernels compile for the TPU by default and take ``interpret=True``
+only when asked, so CI's pallas-interpret job runs the bodies on CPU;
+dispatch (auto/pallas/pallas_interpret/ref) lives in ``kernels.ops``.
 """
 from __future__ import annotations
 
@@ -57,7 +57,12 @@ __all__ = ["mix_aggregate_pallas", "stc_rows_pallas", "dol_bid_scores_pallas",
 
 BLOCK_F = 8192      # feature-axis tile (fp32 (C, BF) block in VMEM)
 BLOCK_C = 1024      # client-axis tile (streaming accumulate over C)
-VMEM_BUDGET = 4 << 20   # per-operand VMEM budget used to shrink BLOCK_F
+BLOCK_G = 256       # output-row tile (a MixOp has G = C rows)
+STC_ROWS = 8        # client rows per stc tile (the fp32 sublane count)
+VMEM_BUDGET = 2 << 20   # per-operand VMEM budget used to shrink BLOCK_F;
+                        # fp32-exact MXU dots also hold split copies of each
+                        # tile, and a G = C = 2048 MixOp with 4 MiB operands
+                        # overruns the 16 MiB VMEM scope
 
 
 def stack_ravel(params) -> tuple[jax.Array, tuple]:
@@ -117,59 +122,68 @@ def _feature_block(rows: int, block: int, n: int) -> int:
 # ------------------------------------------------------------ mix/aggregate
 
 def _mix_kernel(w_ref, x_ref, o_ref):
-    k = pl.program_id(1)
+    k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    w = w_ref[...].astype(jnp.float32)             # (G, BC)
+    w = w_ref[...].astype(jnp.float32)             # (BG, BC)
     x = x_ref[...].astype(jnp.float32)             # (BC, BF)
-    o_ref[...] += jax.lax.dot(w, x,
+    # HIGHEST: an fp32 weighted sum on the MXU, not one bf16 pass (which
+    # would round every aggregated parameter to ~3 significant digits).
+    o_ref[...] += jax.lax.dot(w, x, precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("block_f", "block_c",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_f", "block_c", "interpret"))
 def mix_aggregate_pallas(x: jax.Array, w: jax.Array, *,
                          block_f: int = BLOCK_F, block_c: int = BLOCK_C,
-                         interpret: bool = True) -> jax.Array:
-    """``w @ x`` over (feature, client) tiles: x (C, F), w (G, C) → (G, F).
+                         interpret: bool = False) -> jax.Array:
+    """``w @ x`` over (output-row, feature, client) tiles: x (C, F), w (G, C)
+    → (G, F).
 
-    Grid cell (i, k) streams the (BC, BF) client tile through VMEM and
-    accumulates its Wᵀ-partial into the *revolving* (G, BF) output block:
+    Grid cell (r, i, k) streams the (BC, BF) client tile through VMEM and
+    accumulates its Wᵀ-partial into the *revolving* (BG, BF) output block:
     the output index map ignores k, so the block stays resident in VMEM
     across the inner client loop while Pallas double-buffers the next x
     tile's HBM fetch behind the current MXU pass — Eq. (10)/(11) streams
     over fleets far larger than VMEM instead of barriering on one (C, BF)
-    slab.  Fleets with C ≤ block_c keep the original single-tile schedule
-    (and its exact summation order).
+    slab.  Fleets with C ≤ block_c keep the single-tile schedule (and its
+    exact summation order) with a tile as tall as the fleet.  A full MixOp
+    of a large fleet (G = C = 2048) tiles its output rows too, so the
+    weight block stays within VMEM.
     """
     c, f = x.shape
     g = w.shape[0]
     assert w.shape == (g, c), (w.shape, x.shape)
-    bc = min(block_c, max(8, -(-c // 8) * 8))
+    # A fleet of at most block_c clients is one client tile spanning the
+    # whole axis (a block dim equal to the array dim needs no alignment),
+    # so the fleet is never copied to pad it.  Larger fleets stream
+    # block_c-row tiles; zero client rows / weight columns contribute
+    # nothing to any sum.
+    bc = c if c <= block_c else block_c
     pad_c = (-c) % bc
     if pad_c:
-        # Zero client rows / weight columns contribute nothing to any sum.
         x = jnp.pad(x, ((0, pad_c), (0, 0)))
         w = jnp.pad(w, ((0, 0), (0, pad_c)))
     nc = x.shape[0] // bc
-    bf = _feature_block(max(bc, g), block_f, f)
-    pad = (-f) % bf
-    if pad:
-        x = jnp.pad(x, ((0, 0), (0, pad)))
-    grid = (x.shape[1] // bf, nc)
-    out = pl.pallas_call(
+    bg = g if g <= BLOCK_G else BLOCK_G
+    bf = _feature_block(max(bc, bg), block_f, f)
+    # The last output-row and feature tiles may overhang G and F: output
+    # (row, column) depends on weight row and input column alone, so the
+    # overhang's unspecified reads land only in output cells that are never
+    # written back.
+    grid = (pl.cdiv(g, bg), pl.cdiv(f, bf), nc)
+    return pl.pallas_call(
         _mix_kernel,
         grid=grid,
-        in_specs=[pl.BlockSpec((g, bc), lambda i, k: (0, k)),
-                  pl.BlockSpec((bc, bf), lambda i, k: (k, i))],
-        out_specs=pl.BlockSpec((g, bf), lambda i, k: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((g, x.shape[1]), jnp.float32),
+        in_specs=[pl.BlockSpec((bg, bc), lambda r, i, k: (r, k)),
+                  pl.BlockSpec((bc, bf), lambda r, i, k: (k, i))],
+        out_specs=pl.BlockSpec((bg, bf), lambda r, i, k: (r, i)),
+        out_shape=jax.ShapeDtypeStruct((g, f), jnp.float32),
         interpret=interpret,
     )(w.astype(jnp.float32), x.astype(jnp.float32))
-    return out[:, :f]
 
 
 # ------------------------------------------------------------------ stc rows
@@ -180,7 +194,8 @@ def _stc_reduce_kernel(x_ref, r_ref, thr_ref, sum_ref, cnt_ref, *,
     # odd tiles in bank 1, so consecutive grid steps extend *independent*
     # serial FP-add chains (the banks are summed on the host side).  That
     # halves the loop-carried latency the pipeline must hide while the
-    # next x tile streams in.
+    # next x tile streams in.  Each grid cell holds STC_ROWS client rows
+    # and reduces them row by row into (rows, 2) bank columns.
     j = pl.program_id(1)
 
     @pl.when(j == 0)
@@ -190,28 +205,29 @@ def _stc_reduce_kernel(x_ref, r_ref, thr_ref, sum_ref, cnt_ref, *,
 
     d = x_ref[...].astype(jnp.float32) - r_ref[...].astype(jnp.float32)
     idx = j * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
-    keep = jnp.logical_and(jnp.abs(d) >= thr_ref[0, 0], idx < n_valid)
+    keep = jnp.logical_and(jnp.abs(d) >= thr_ref[...], idx < n_valid)
     bank = (jax.lax.broadcasted_iota(jnp.int32, (1, 2), 1)
             == j % 2).astype(jnp.float32)
-    sum_ref[...] += jnp.sum(jnp.where(keep, jnp.abs(d), 0.0)) * bank
-    cnt_ref[...] += jnp.sum(keep.astype(jnp.float32)) * bank
+    sum_ref[...] += jnp.sum(jnp.where(keep, jnp.abs(d), 0.0), axis=1,
+                            keepdims=True) * bank
+    cnt_ref[...] += jnp.sum(keep.astype(jnp.float32), axis=1,
+                            keepdims=True) * bank
 
 
 def _stc_apply_kernel(x_ref, r_ref, thr_ref, mu_ref, mask_ref, o_ref):
     x = x_ref[...].astype(jnp.float32)
     r = r_ref[...].astype(jnp.float32)
     d = x - r
-    tern = jnp.where(jnp.abs(d) >= thr_ref[0, 0],
-                     jnp.sign(d) * mu_ref[0, 0], 0.0)
-    o_ref[...] = jnp.where(mask_ref[0, 0] != 0, r + tern, x).astype(
-        o_ref.dtype)
+    tern = jnp.where(jnp.abs(d) >= thr_ref[...], jnp.sign(d) * mu_ref[...],
+                     0.0)
+    o_ref[...] = jnp.where(mask_ref[...] != 0, r + tern, x)
 
 
 @functools.partial(jax.jit, static_argnames=("sparsity", "block",
                                              "interpret"))
 def stc_rows_pallas(x: jax.Array, ref_row: jax.Array, mask: jax.Array,
                     sparsity: float, *, block: int = BLOCK_F,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """Masked per-row STC against a shared reference row.
 
     x (C, n); ref_row (n,) — the broadcast global every PUE holds; mask
@@ -219,50 +235,50 @@ def stc_rows_pallas(x: jax.Array, ref_row: jax.Array, mask: jax.Array,
     (the compressed D2D payload), other rows pass through bit-untouched.
     The top-k threshold is an XLA per-row sort (a quantile serializes a
     Pallas grid — see kernels/stc_compress.py); the survivor reduction and
-    the fused ternarize+blend are tiled row-wise passes.
+    the fused ternarize+blend are tiled passes over (STC_ROWS, block)
+    tiles, C padded up to a tile multiple with inert rows (mask 0).
     """
     c, n = x.shape
     k = max(1, int(n * sparsity))
     delta = x.astype(jnp.float32) - ref_row.astype(jnp.float32)[None, :]
     thr = jnp.sort(jnp.abs(delta), axis=1)[:, n - k]            # (C,)
 
-    blk = _feature_block(1, block, n)
+    rows = STC_ROWS
+    blk = _feature_block(rows, block, n)
     pad = (-n) % blk
-    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (0, pad)))
+    pad_c = (-c) % rows
+    xp = jnp.pad(x.astype(jnp.float32), ((0, pad_c), (0, pad)))
     rp = jnp.pad(ref_row.astype(jnp.float32), (0, pad)).reshape(1, -1)
-    nb = xp.shape[1] // blk
-    thr2 = thr.reshape(c, 1)
+    cp, nb = xp.shape[0], xp.shape[1] // blk
+    thr2 = jnp.pad(thr, (0, pad_c)).reshape(cp, 1)
+    row_blk = pl.BlockSpec((rows, blk), lambda i, j: (i, j))
+    ref_blk = pl.BlockSpec((1, blk), lambda i, j: (0, j))
+    col_blk = pl.BlockSpec((rows, 1), lambda i, j: (i, 0))
+    bank_blk = pl.BlockSpec((rows, 2), lambda i, j: (i, 0))
     reduce_kernel = functools.partial(_stc_reduce_kernel, n_valid=n,
-                                     block=blk)
+                                      block=blk)
     ssum, cnt = pl.pallas_call(
         reduce_kernel,
-        grid=(c, nb),
-        in_specs=[pl.BlockSpec((1, blk), lambda i, j: (i, j)),
-                  pl.BlockSpec((1, blk), lambda i, j: (0, j)),
-                  pl.BlockSpec((1, 1), lambda i, j: (i, 0))],
-        out_specs=[pl.BlockSpec((1, 2), lambda i, j: (i, 0)),
-                   pl.BlockSpec((1, 2), lambda i, j: (i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((c, 2), jnp.float32),
-                   jax.ShapeDtypeStruct((c, 2), jnp.float32)],
+        grid=(cp // rows, nb),
+        in_specs=[row_blk, ref_blk, col_blk],
+        out_specs=[bank_blk, bank_blk],
+        out_shape=[jax.ShapeDtypeStruct((cp, 2), jnp.float32),
+                   jax.ShapeDtypeStruct((cp, 2), jnp.float32)],
         interpret=interpret,
     )(xp, rp, thr2)
-    ssum = ssum.sum(axis=1, keepdims=True)                      # (C, 1)
+    ssum = ssum.sum(axis=1, keepdims=True)                      # (Cp, 1)
     cnt = cnt.sum(axis=1, keepdims=True)
-    mu = ssum / jnp.maximum(cnt, 1.0)                           # (C, 1)
-    mask2 = mask.astype(jnp.int32).reshape(c, 1)
+    mu = ssum / jnp.maximum(cnt, 1.0)                           # (Cp, 1)
+    mask2 = jnp.pad(mask.astype(jnp.int32), (0, pad_c)).reshape(cp, 1)
     out = pl.pallas_call(
         _stc_apply_kernel,
-        grid=(c, nb),
-        in_specs=[pl.BlockSpec((1, blk), lambda i, j: (i, j)),
-                  pl.BlockSpec((1, blk), lambda i, j: (0, j)),
-                  pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-                  pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
-                  pl.BlockSpec((1, 1), lambda i, j: (i, 0))],
-        out_specs=pl.BlockSpec((1, blk), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct(xp.shape, x.dtype),
+        grid=(cp // rows, nb),
+        in_specs=[row_blk, ref_blk, col_blk, col_blk, col_blk],
+        out_specs=row_blk,
+        out_shape=jax.ShapeDtypeStruct(xp.shape, jnp.float32),
         interpret=interpret,
     )(xp, rp, thr2, mu, mask2)
-    return out[:, :n]
+    return out[:c, :n].astype(x.dtype)
 
 
 # ------------------------------------------------------------ dol bid scores
@@ -319,6 +335,7 @@ def _bid_kernel(psi_ref, a_ref, ppsi_ref, spsi_ref,
     d = d_ref[...].astype(jnp.float32)                        # (BN, C)
     cross = jax.lax.dot_general(
         psi, d, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)                   # (BM, BN)
     o_ref[...] = _bid_scores_from_stats(
         cross, a_ref[...], b_ref[...].reshape(1, -1),
@@ -331,7 +348,7 @@ def _bid_kernel(psi_ref, a_ref, ppsi_ref, spsi_ref,
 def dol_bid_scores_pallas(dol: jax.Array, chain_size: jax.Array,
                           dsi: jax.Array, data_size: jax.Array, *,
                           block_m: int = 128, block_n: int = 256,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: bool = False) -> jax.Array:
     """Candidate IID-distance matrix (M, N) on the MXU, tiled over (M, N).
 
     Grid cell (i, j) loads the centered (BM, C) DoL block and (BN, C) DSI
@@ -383,7 +400,7 @@ def _bid_value_kernel(bids_ref, val_ref, w_ref, o_ref):
 def bid_value_fuse_pallas(bids: jax.Array, value: jax.Array,
                           weight: jax.Array | float, *,
                           block_m: int = 128, block_n: int = 256,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: bool = False) -> jax.Array:
     """Fuse the per-client learning value into the (M, N) bid matrix.
 
     Elementwise VPU tile: grid cell (i, j) scales its bid block by

@@ -100,7 +100,7 @@ def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, window: int | None = None,
                            scale: float | None = None, block_q: int = 256,
                            block_k: int = 512,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool = False) -> jax.Array:
     """q: (B, Sq, H, D); k/v: (B, Sk, H, D) — same head count (pre-repeated
     for GQA by the caller).  Returns (B, Sq, H, D)."""
     b, sq, h, d = q.shape

@@ -10,6 +10,8 @@ The ``REPRO_KERNELS_IMPL`` environment variable overrides what ``"auto"``
 resolves to (explicit ``implementation=`` arguments always win).  CI's
 ``pallas-interpret`` job sets it to ``pallas_interpret`` so the Pallas
 kernel bodies — not just the XLA fallbacks — are exercised on CPU runners.
+Interpret mode is never a silent fallback: ``"pallas"`` on a backend other
+than the TPU raises.
 
 LM-side kernels (flash_attention / stc_compress / ssm_scan / ssd_scan) are
 joined by the FL diffusion data plane (mix_aggregate / stc_topk /
@@ -53,6 +55,24 @@ def _resolve(implementation: str) -> str:
     return "pallas" if jax.default_backend() == "tpu" else "xla"
 
 
+def _interpret(impl: str) -> bool:
+    """Whether a resolved Pallas implementation runs interpreted.
+
+    Interpret mode happens only when asked for ("pallas_interpret", directly
+    or through ``REPRO_KERNELS_IMPL``).  An explicit "pallas" off a TPU
+    raises instead of interpreting, so no run can look as though it took
+    the compiled kernel path without touching the chip."""
+    if impl == "pallas_interpret":
+        return True
+    backend = jax.default_backend()
+    if backend != "tpu":
+        raise RuntimeError(
+            f"implementation='pallas' compiles for a TPU, but the backend is "
+            f"{backend!r}; ask for 'pallas_interpret' to run the kernel "
+            f"bodies in interpret mode")
+    return False
+
+
 def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                     scale: float | None = None,
                     implementation: str = "auto") -> jax.Array:
@@ -62,7 +82,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int | None = None,
                                        scale=scale)
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
                                   scale=scale,
-                                  interpret=(impl == "pallas_interpret"))
+                                  interpret=_interpret(impl))
 
 
 def stc_compress(x, sparsity: float = 0.01, *,
@@ -75,7 +95,7 @@ def stc_compress(x, sparsity: float = 0.01, *,
     k = max(1, int(n * sparsity))
     # τ = k-th largest |x| (global sort: stays in XLA; see stc_compress.py)
     thr = jnp.sort(jnp.abs(flat))[n - k]
-    interpret = impl == "pallas_interpret" or jax.default_backend() != "tpu"
+    interpret = _interpret(impl)
     ssum, cnt = stc_reduce_pallas(flat, thr, interpret=interpret)
     mu = ssum / jnp.maximum(cnt, 1.0)
     out = stc_apply_pallas(flat, thr, mu, interpret=interpret)
@@ -89,7 +109,7 @@ def mix_aggregate(x, w, *, implementation: str = "auto") -> jax.Array:
     impl = _resolve(implementation)
     if impl == "xla":
         return ref.mix_aggregate_ref(x, w)
-    interpret = impl == "pallas_interpret" or jax.default_backend() != "tpu"
+    interpret = _interpret(impl)
     return mix_aggregate_pallas(x, w, interpret=interpret)
 
 
@@ -136,7 +156,7 @@ def stc_topk(x, ref_row, mask, sparsity: float = 0.01, *,
     impl = _resolve(implementation)
     if impl == "xla":
         return ref.stc_rows_ref(x, ref_row, mask, sparsity)
-    interpret = impl == "pallas_interpret" or jax.default_backend() != "tpu"
+    interpret = _interpret(impl)
     return stc_rows_pallas(x, ref_row, mask, sparsity, interpret=interpret)
 
 
@@ -148,7 +168,7 @@ def quant_pack(x, *, implementation: str = "auto"):
     impl = _resolve(implementation)
     if impl == "xla":
         return ref.quant_pack_ref(x)
-    interpret = impl == "pallas_interpret" or jax.default_backend() != "tpu"
+    interpret = _interpret(impl)
     return quant_pack_pallas(x, interpret=interpret)
 
 
@@ -158,7 +178,7 @@ def quant_unpack(q, scale, *, implementation: str = "auto") -> jax.Array:
     impl = _resolve(implementation)
     if impl == "xla":
         return ref.quant_unpack_ref(q, scale)
-    interpret = impl == "pallas_interpret" or jax.default_backend() != "tpu"
+    interpret = _interpret(impl)
     return quant_unpack_pallas(q, scale, interpret=interpret)
 
 
@@ -176,7 +196,7 @@ def dol_bid_scores(dol, chain_size, dsi, data_size, *,
     if impl == "xla" or metric != "w1_norm":
         return ref.dol_bid_scores_ref(dol, chain_size, dsi, data_size,
                                       metric)
-    interpret = impl == "pallas_interpret" or jax.default_backend() != "tpu"
+    interpret = _interpret(impl)
     return dol_bid_scores_pallas(dol, chain_size, dsi, data_size,
                                  interpret=interpret)
 
@@ -189,7 +209,7 @@ def bid_value_fuse(bids, value, weight, *,
     impl = _resolve(implementation)
     if impl == "xla":
         return ref.bid_value_fuse_ref(bids, value, weight)
-    interpret = impl == "pallas_interpret" or jax.default_backend() != "tpu"
+    interpret = _interpret(impl)
     return bid_value_fuse_pallas(bids, value, weight, interpret=interpret)
 
 
@@ -197,7 +217,7 @@ def ssm_scan(da, dbx, *, implementation: str = "auto") -> jax.Array:
     impl = _resolve(implementation)
     if impl == "xla":
         return ref.ssm_scan_ref(da, dbx)
-    interpret = impl == "pallas_interpret" or jax.default_backend() != "tpu"
+    interpret = _interpret(impl)
     return ssm_scan_pallas(da, dbx, interpret=interpret)
 
 
@@ -207,5 +227,5 @@ def ssd_scan(xh, a, bmat, cmat, *, implementation: str = "auto") -> jax.Array:
     impl = _resolve(implementation)
     if impl == "xla":
         return ssd_scan_ref(xh, a, bmat, cmat)
-    interpret = impl == "pallas_interpret" or jax.default_backend() != "tpu"
+    interpret = _interpret(impl)
     return ssd_scan_pallas(xh, a, bmat, cmat, interpret=interpret)

@@ -87,7 +87,7 @@ def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, h_scr, *, chunk: int):
 @functools.partial(jax.jit, static_argnames=("chunk", "block_h", "interpret"))
 def ssd_scan_pallas(xh: jax.Array, a: jax.Array, bmat: jax.Array,
                     cmat: jax.Array, *, chunk: int = 128, block_h: int = 8,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool = False) -> jax.Array:
     """xh (B,S,H,P), a (B,S,H), b/c (B,S,N) -> y (B,S,H,P)."""
     b_, s, h, p = xh.shape
     n = bmat.shape[-1]

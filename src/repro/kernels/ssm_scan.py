@@ -45,7 +45,7 @@ def _scan_kernel(da_ref, dbx_ref, h_ref, h_scr, *, chunk: int):
 
 @functools.partial(jax.jit, static_argnames=("chunk", "block_d", "interpret"))
 def ssm_scan_pallas(da: jax.Array, dbx: jax.Array, *, chunk: int = 128,
-                    block_d: int = 256, interpret: bool = True) -> jax.Array:
+                    block_d: int = 256, interpret: bool = False) -> jax.Array:
     """da/dbx: (B, S, D, N) -> all states (B, S, D, N)."""
     b, s, d, n = da.shape
     chunk = min(chunk, s)

@@ -53,7 +53,7 @@ def _apply_kernel(x_ref, thr_ref, mu_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block"))
 def stc_reduce_pallas(flat: jax.Array, thr: jax.Array, *,
-                      block: int = BLOCK, interpret: bool = True):
+                      block: int = BLOCK, interpret: bool = False):
     """Returns (Σ|x| over survivors, #survivors) for a flat fp32 array."""
     n = flat.shape[0]
     block = min(block, max(128, n))
@@ -80,7 +80,7 @@ def stc_reduce_pallas(flat: jax.Array, thr: jax.Array, *,
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block"))
 def stc_apply_pallas(flat: jax.Array, thr: jax.Array, mu: jax.Array, *,
-                     block: int = BLOCK, interpret: bool = True):
+                     block: int = BLOCK, interpret: bool = False):
     n = flat.shape[0]
     block = min(block, max(128, n))
     pad = (-n) % block

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from repro.configs.base import ARCH_IDS, SHAPES, ModelConfig, ShapeConfig, get_config
 from repro.distributed import sharding as sh
 from repro.distributed.fedshard import make_diffusion_step
-from repro.launch.mesh import activate_mesh, make_production_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models.zoo import build_model
 from repro.train import optimizer as opt_lib
 from repro.train.trainstep import (TrainState, make_serve_step,
@@ -109,7 +109,7 @@ def lower_one(arch: str, shape_name: str, multi_pod: bool,
     batch = input_specs(cfg, shape)
     t0 = time.time()
 
-    with activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.mode == "train":
             key_spec = jax.ShapeDtypeStruct((2,), jnp.uint32)
             state_shapes = jax.eval_shape(
@@ -232,7 +232,7 @@ def feddif_lower(arch: str, fsdp: bool | None = None) -> dict:
     npod = mesh.shape["pod"]
     t0 = time.time()
 
-    with activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         key_spec = jax.ShapeDtypeStruct((2,), jnp.uint32)
         base_state = jax.eval_shape(
             lambda k: TrainState(params=model.init(k),

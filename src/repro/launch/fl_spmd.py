@@ -49,6 +49,7 @@ from repro.distributed.fedshard import (fleet_aggregate,
                                         make_fleet_train_step)
 from repro.fl.schedulers import RoundContext, schedule_feddif
 from repro.fl.server import FLConfig, _uplink_gamma
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.train import optimizer as opt_lib
 from repro.train.trainstep import TrainState
@@ -174,6 +175,7 @@ def main():
                          "(use XLA_FLAGS=--xla_force_host_platform_device_"
                          "count=K for a multi-device CPU mesh)")
     args = ap.parse_args()
+    enable_compile_cache()
     _, hist = run_spmd_feddif(args.arch, args.clients, args.rounds,
                               args.alpha, args.seq_len, args.batch,
                               shard_clients=args.shard_clients)

@@ -11,9 +11,10 @@ Target hardware (constants used by §Roofline): TPU v5e,
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_production_mesh", "make_host_mesh", "make_clients_mesh",
-           "make_fl_mesh", "activate_mesh", "PEAK_FLOPS", "HBM_BW", "ICI_BW",
+           "make_fl_mesh", "PEAK_FLOPS", "HBM_BW", "ICI_BW",
            "mesh_axes"]
 
 PEAK_FLOPS = 197e12       # bf16 FLOP/s per chip
@@ -21,24 +22,12 @@ HBM_BW = 819e9            # bytes/s per chip
 ICI_BW = 50e9             # bytes/s per link
 
 
-def _auto_axis_types_kwargs(n: int) -> dict:
-    """``axis_types=(Auto,)*n`` where supported.
-
-    ``jax.sharding.AxisType`` only exists on newer jax; on 0.4.x the
-    default mesh axis type already IS auto, so omitting the kwarg is
-    equivalent — this shim keeps one mesh constructor working on both.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single-pod (256 chips) or 2×16×16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_auto_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -47,7 +36,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     data = min(data, n)
     model = max(1, min(model, n // max(data, 1)))
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_auto_axis_types_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_clients_mesh(num_clients: int, max_devices: int | None = None):
@@ -64,7 +53,7 @@ def make_clients_mesh(num_clients: int, max_devices: int | None = None):
     if max_devices is not None:
         n = max(1, min(n, max_devices))
     k = max(d for d in range(1, n + 1) if num_clients % d == 0)
-    return jax.make_mesh((k,), ("clients",), **_auto_axis_types_kwargs(1))
+    return jax.make_mesh((k,), ("clients",), axis_types=(AxisType.Auto,))
 
 
 def make_fl_mesh(num_clients: int, model: int = 1,
@@ -93,19 +82,7 @@ def make_fl_mesh(num_clients: int, model: int = 1,
     # N=20 would be absurd; excess devices simply sit out of the mesh.
     kc = min(n // km, max(1, num_clients))
     return jax.make_mesh((kc, km), ("clients", "model"),
-                         **_auto_axis_types_kwargs(2))
-
-
-def activate_mesh(mesh):
-    """Context manager making ``mesh`` the ambient mesh.
-
-    ``jax.set_mesh`` on new jax; on 0.4.x the ``Mesh`` object itself is the
-    context manager that enters the mesh context.
-    """
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def mesh_axes(mesh) -> tuple[str, ...]:

@@ -31,6 +31,7 @@ import sys
 from repro.experiments import REGISTRY, run_sweep, sweep_names
 from repro.experiments.artifacts import default_out_dir
 from repro.fl.engine import ENGINE_PRESETS
+from repro.launch.compile_cache import enable_compile_cache
 
 __all__ = ["main"]
 
@@ -93,6 +94,7 @@ def main(argv: list[str] | None = None) -> int:
     ap.add_argument("--list", action="store_true",
                     help="list registered sweeps and exit")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.list or not args.sweep:
         print(f"{'name':20s} {'paper':16s} axis        description")
@@ -117,6 +119,7 @@ def main(argv: list[str] | None = None) -> int:
         overrides["num_samples"] = args.num_samples
     durable = (args.checkpoint_every > 0 or args.resume
                or args.state_dir is not None)
+    n_failed = 0
     for name in names:
         print(f"# === sweep {name} ({'smoke' if smoke else 'full'}, "
               f"seeds={list(seeds)}) ===", flush=True)
@@ -151,7 +154,10 @@ def main(argv: list[str] | None = None) -> int:
         for fc in failed:
             print(f"# FAILED cell {fc['label']}: {fc['error']}",
                   file=sys.stderr, flush=True)
-    return 0
+        n_failed += len(failed)
+    # Each failed cell is isolated so the sweep finishes, but the run as a
+    # whole did not succeed.
+    return 1 if n_failed else 0
 
 
 if __name__ == "__main__":

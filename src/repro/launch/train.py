@@ -8,7 +8,9 @@ and checkpointing the global model.
         --rounds 8 --clients 4 --steps-per-round 8
 
 ``--smoke`` uses the reduced same-family config (CPU-friendly); omit it on
-real hardware to train the full config (e.g. the ~360M smollm on a pod).
+real hardware to train the full config.  ``chip_smoke.py`` runs the full
+smollm_360m (361.8M params) with ``--engine fleet --clients 2`` on one
+TPU v5e chip.
 """
 from __future__ import annotations
 
@@ -23,11 +25,14 @@ from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.data.partitioner import dirichlet_partition
 from repro.data.synthetic import class_labels_for_lm, lm_corpus
 from repro.fl.server import FLConfig, run_federated
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.train import save_checkpoint
 
 
-def main():
+def main(argv: list[str] | None = None):
+    """Parse ``argv`` (default: the command line), train, return the
+    :class:`~repro.fl.engine.RunResult`."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS, default="smollm_360m")
     ap.add_argument("--smoke", action="store_true",
@@ -46,7 +51,8 @@ def main():
                          "async_barrier); default: legacy host loop")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.frontend is not None:
@@ -120,6 +126,7 @@ def main():
                         {"arch": cfg.name, "strategy": args.strategy,
                          "loss_history": result.loss})
         print(f"global model checkpointed to {args.ckpt_dir}")
+    return result
 
 
 if __name__ == "__main__":

@@ -31,7 +31,7 @@ def _spec(executor="host", task="lm", hop_quant="int8", adapter_hops=True,
 
 
 def _run_forced(code: str, devices: int, timeout: int = 600):
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH="src" + os.pathsep + os.environ.get("PYTHONPATH",
                                                               ""))

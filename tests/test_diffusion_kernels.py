@@ -45,6 +45,19 @@ def test_mix_aggregate_client_tiled_accumulate_matches_ref():
                                atol=1e-4, rtol=1e-4)
 
 
+@pytest.mark.parametrize("g", [300, 1, 33])
+def test_mix_aggregate_row_tiled_matches_ref(g):
+    """G > BLOCK_G tiles the output rows (a MixOp of a large fleet); the
+    ragged last row and feature tiles must not leak into the result."""
+    x = jnp.asarray(RNG.normal(size=(90, 700)), jnp.float32)
+    w = jnp.asarray(RNG.random(size=(g, 90)), jnp.float32)
+    out = mix_aggregate_pallas(x, w, block_c=32, block_f=256, interpret=True)
+    want = jnp.einsum("gc,cf->gf", w, x)
+    assert out.shape == (g, 700)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=1e-4, rtol=1e-4)
+
+
 def test_mix_aggregate_tree_paths_agree():
     """Tree-level dispatch: the per-leaf XLA chain and the flattened Pallas
     pass compute the same mix and the same (squeezed) aggregate."""
@@ -93,6 +106,8 @@ def test_mix_aggregate_ref_is_flat_einsum():
     (4, 4096, 0.01),
     (10, 64, 0.1),       # n below one lane tile
     (3, 10000, 0.001),
+    (20, 9000, 0.01),    # C pads 20 -> 24 rows, n spans two feature tiles
+    (3, 8192 + 7, 0.01),  # C below one row tile, ragged last feature tile
 ])
 def test_stc_topk_matches_ref(c, n, sparsity):
     x = jnp.asarray(RNG.normal(size=(c, n)), jnp.float32)
@@ -207,6 +222,29 @@ def test_dol_bid_scores_vmaps():
 
 
 # ------------------------------------------------------------- dispatch
+
+@pytest.mark.parametrize("call", [
+    lambda: ops.mix_aggregate(jnp.ones((4, 8)), jnp.ones((1, 4)),
+                              implementation="pallas"),
+    lambda: ops.stc_topk(jnp.ones((4, 8)), jnp.ones((8,)),
+                         jnp.ones((4,), bool), implementation="pallas"),
+    lambda: ops.dol_bid_scores(*_planner_inputs(4, 8, 6),
+                               implementation="pallas"),
+    lambda: ops.bid_value_fuse(jnp.ones((4, 8)), jnp.ones((8,)), 0.5,
+                               implementation="pallas"),
+    lambda: ops.quant_pack(jnp.ones((4, 512)), implementation="pallas"),
+    lambda: ops.quant_unpack(jnp.ones((4, 512), jnp.int8), jnp.ones((4,)),
+                             implementation="pallas"),
+], ids=["mix_aggregate", "stc_topk", "dol_bid_scores", "bid_value_fuse",
+        "quant_pack", "quant_unpack"])
+def test_explicit_pallas_off_tpu_raises(monkeypatch, call):
+    """An explicit "pallas" off a TPU raises instead of quietly running the
+    interpreter, so no CPU run can pass for a compiled-kernel run."""
+    monkeypatch.delenv("REPRO_KERNELS_IMPL", raising=False)
+    assert jax.default_backend() != "tpu"
+    with pytest.raises(RuntimeError, match="pallas_interpret"):
+        call()
+
 
 def test_resolve_accepts_ref_alias(monkeypatch):
     assert ops._resolve("ref") == "xla"

@@ -21,7 +21,7 @@ from repro.launch.mesh import make_fl_mesh
 
 
 def _run_forced(code: str, devices: int, timeout: int = 600):
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
                PYTHONPATH="src" + os.pathsep + os.environ.get("PYTHONPATH",
                                                               ""))

@@ -28,7 +28,9 @@ def _rows(r, b, zero_row=True):
 
 # --------------------------------------------------------- kernel parity
 
-@pytest.mark.parametrize("r,b", [(1, 512), (7, 512), (16, 128), (3, 8)])
+@pytest.mark.parametrize("r,b", [(1, 512), (7, 512), (16, 128), (3, 8),
+                                 (57, 512),    # one tile of all 57 rows
+                                 (300, 512)])  # ragged second 256-row tile
 def test_pack_pallas_matches_ref_bitwise(r, b):
     """Same int8 codes and bit-identical fp32 scales on both bodies — the
     wire format cannot depend on which implementation packed it."""

@@ -256,7 +256,10 @@ def test_sigterm_kill_resume_cli(tmp_path):
     """The real thing: SIGTERM a durable CLI sweep mid-run, resume it with
     ``--resume``, and diff the artifact against an uninterrupted run."""
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"))
+    # the child runs on the CPU and keeps its compile cache in tmp_path
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"),
+               JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
     state, out = str(tmp_path / "state"), str(tmp_path / "out")
     args = [sys.executable, "-m", "repro.launch.sweep",
             "--sweep", "fig3_alpha", "--smoke", "--seeds", "2",
@@ -299,3 +302,27 @@ def test_sigterm_kill_resume_cli(tmp_path):
     assert resumed["failed_cells"] == []
     assert json.dumps(strip_volatile(clean), sort_keys=True, default=str) \
         == json.dumps(strip_volatile(resumed), sort_keys=True, default=str)
+
+
+def test_sweep_cli_exits_nonzero_when_a_cell_fails(tmp_path, monkeypatch,
+                                                   capsys):
+    """The durable sweep isolates a crashing cell and finishes the grid,
+    but the CLI run as a whole reports failure through its exit code."""
+    from repro.experiments import orchestrator
+    from repro.launch import sweep
+
+    def poisoned(spec, seeds, plan_cache=None, checkpoint_root=None):
+        raise RuntimeError("injected cell crash")
+
+    monkeypatch.setattr(orchestrator, "run_replicates_loop", poisoned)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        rc = sweep.main(["--sweep", "fig3_alpha", "--smoke",
+                         "--num-samples", "400", "--checkpoint-every", "1",
+                         "--out-dir", str(tmp_path / "out"),
+                         "--state-dir", str(tmp_path / "state")])
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+    assert rc == 1
+    assert "FAILED cell" in capsys.readouterr().err

@@ -117,7 +117,7 @@ for a, b in zip(jax.tree.leaves(host.final_params),
                                atol=5e-4, rtol=5e-3)
 print("MULTI_DEVICE_PARITY_OK")
 """
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=2",
                PYTHONPATH="src" + os.pathsep + os.environ.get("PYTHONPATH",
                                                               ""))
@@ -168,7 +168,7 @@ for a, b in zip(jax.tree.leaves(host.final_params),
                                atol=5e-4, rtol=5e-3)
 print("RING_TRANSPORT_PARITY_OK")
 """
-    env = dict(os.environ,
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=2",
                PYTHONPATH="src" + os.pathsep + os.environ.get("PYTHONPATH",
                                                               ""))

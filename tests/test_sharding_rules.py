@@ -127,14 +127,14 @@ def test_cache_specs_seq_sharding():
 
 def test_end_to_end_lower_on_host_mesh():
     """Real (1-device) mesh: specs must be accepted by jit and compile."""
-    from repro.launch.mesh import activate_mesh, make_host_mesh
+    from repro.launch.mesh import make_host_mesh
     from repro.train import optimizer as opt_lib
     from repro.train.trainstep import TrainState, make_train_step
     mesh = make_host_mesh(1, 1)
     cfg = get_smoke_config("smollm_360m")
     model = build_model(cfg)
     opt = opt_lib.sgd()
-    with activate_mesh(mesh):
+    with jax.set_mesh(mesh):
         key_spec = jax.ShapeDtypeStruct((2,), jnp.uint32)
         state_shapes = jax.eval_shape(
             lambda k: TrainState(params=model.init(k),
