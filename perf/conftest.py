@@ -1,0 +1,9 @@
+"""The benchmark's tests import the program from the checkout's ``src``,
+as ``perf/run.py`` does."""
+import os
+import sys
+
+SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))), "src")
+if SRC not in sys.path:
+    sys.path.insert(0, SRC)
