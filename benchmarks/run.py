@@ -465,9 +465,7 @@ def fleet_scaling(full: bool):
     CPU mesh (``main()`` forces K=2 when this bench runs standalone; CI's
     mesh2d job uses K=8); on one device the planes are the same program
     and the speedup checks are skipped (also skipped by the budget gate
-    via ``device_count``).  Also emits the per-phase wall-clock breakdown
-    (train / hop_collective / mix / plan — from a short profiled op-by-op
-    rerun, since the fused round cannot be sub-timed) and the
+    via ``device_count``).  Also emits the
     :mod:`benchmarks.roofline` readout for one round at the largest N
     (achieved FLOP/s and wire bytes vs the machine's measured GEMM peak).
     Emits ``BENCH_fleet_scaling.json``.
@@ -552,21 +550,6 @@ def fleet_scaling(full: bool):
     overlap_speedup = (by[(big_n, "sharded_off")]
                        / max(by[(big_n, "sharded")], 1e-9))
 
-    # --- per-phase breakdown (satellite of the overlap work): a short
-    # profiled rerun on the op-by-op plane — the fused round is one device
-    # call and cannot be sub-timed — so overlap wins are attributable to
-    # phases, not just end-to-end deltas.
-    phases = {}
-    for label in ("fleet", "sharded"):
-        spec = make_spec(big_n, label, rounds=2, profile_phases=True)
-        r = run_experiment(spec)
-        ph = r.phase_s[-1] if r.phase_s else {}
-        phases[label] = {k: round(v, 4) for k, v in sorted(ph.items())}
-        print(f"fleet_scaling,phase_breakdown,executor={label},"
-              f"clients={big_n}," +
-              ",".join(f"{k}_s={v:.3f}" for k, v in sorted(ph.items())),
-              flush=True)
-
     # --- roofline readout for one steady round at the largest N on the
     # overlapped sharded arm: analytic FLOPs/bytes (Eq. 15 ledger terms)
     # vs the machine's measured GEMM peak.
@@ -602,7 +585,6 @@ def fleet_scaling(full: bool):
         "speedup_by_n": {str(n): s for n, s in speedups.items()},
         "speedup_at_scale": speedups[big_n], "scale_n": big_n,
         "overlap_speedup": overlap_speedup, "overlap_scale_n": big_n,
-        "phases": phases,
         "roofline": roofline,
         "max_wall_clock_s": max(c["wall_clock_s"] for c in cells),
     }

@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.channels.resources import (outage_probability_jax,
                                       required_bandwidth_jax,
                                       spectral_efficiency,
@@ -455,22 +456,26 @@ def plan_communication_round_jax(planner, state, dsi: np.ndarray,
             plan, post_state = entry
             state.restore(post_state)
             return plan
-    inp, gamma64 = plan_round_inputs(planner, state, dsi, data_sizes, rng,
-                                     positions, interference=interference,
-                                     values=values,
-                                     value_weight=value_weight, world=world)
-    out = plan_rounds(inp, metric=planner.auction.metric,
-                      allow_retraining=planner.auction.allow_retraining,
-                      mobility=world is not None, step_m=float(step_m),
-                      use_value=inp.value is not None)
-    if not bool(out.converged):
+    with obs.span("fl.plan.inputs"):
+        inp, gamma64 = plan_round_inputs(
+            planner, state, dsi, data_sizes, rng, positions,
+            interference=interference, values=values,
+            value_weight=value_weight, world=world)
+    with obs.span("fl.plan.auction"):
+        out = plan_rounds(inp, metric=planner.auction.metric,
+                          allow_retraining=planner.auction.allow_retraining,
+                          mobility=world is not None, step_m=float(step_m),
+                          use_value=inp.value is not None)
+        converged = bool(out.converged)      # waits for the device plan
+    if not converged:
         warnings.warn("jax planner: an auction hit its iteration cap; the "
                       "plan may schedule fewer hops than the host oracle",
                       RuntimeWarning, stacklevel=2)
-    plan = decode_plan(out, num_models=state.dol.shape[0],
-                       gamma_seq64=gamma64,
-                       model_bits=planner.auction.model_bits)
-    state.update_from(out.state, rounds_advanced=int(out.num_rounds))
+    with obs.span("fl.plan.decode"):
+        plan = decode_plan(out, num_models=state.dol.shape[0],
+                           gamma_seq64=gamma64,
+                           model_bits=planner.auction.model_bits)
+        state.update_from(out.state, rounds_advanced=int(out.num_rounds))
     if cache is not None and cache_key is not None:
         cache.store(cache_key, plan, state)
     return plan

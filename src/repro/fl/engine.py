@@ -312,7 +312,6 @@ class RunHistory:
     diffusion_rounds: list = dataclasses.field(default_factory=list)
     iid_distance: list = dataclasses.field(default_factory=list)
     round_wall_s: list = dataclasses.field(default_factory=list)
-    phase_s: list = dataclasses.field(default_factory=list)
     # --- async round plane only (empty under the sync engines) ---
     virtual_s: list = dataclasses.field(default_factory=list)   # tick clock
     arrivals: list = dataclasses.field(default_factory=list)    # agg'd per tick
@@ -366,10 +365,6 @@ class RunResult:
     def round_wall_s(self) -> list:
         return self.history.round_wall_s
 
-    @property
-    def phase_s(self) -> list:
-        return self.history.phase_s
-
     def rounds_to_accuracy(self, target: float) -> int | None:
         for i, a in enumerate(self.history.accuracy):
             if a >= target:
@@ -390,14 +385,13 @@ class RunResult:
     @classmethod
     def from_histories(cls, *, accuracy, loss, ledger, diffusion_rounds,
                        iid_distance, config=None, final_params=None,
-                       round_wall_s=(), phase_s=(), engine=None,
+                       round_wall_s=(), engine=None,
                        **async_hist) -> "RunResult":
         """Build a result from the flat legacy field spelling (replication
         engines, tests)."""
         hist = RunHistory(accuracy=list(accuracy), loss=list(loss),
                           diffusion_rounds=list(diffusion_rounds),
                           iid_distance=list(iid_distance),
-                          round_wall_s=list(round_wall_s),
-                          phase_s=list(phase_s), **async_hist)
+                          round_wall_s=list(round_wall_s), **async_hist)
         return cls(params=final_params, ledger=ledger, history=hist,
                    engine=engine, config=config)

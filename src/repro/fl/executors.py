@@ -27,10 +27,10 @@ Two data planes consume the same schedule object:
   with zero-weighted slots when N does not divide the mesh, and runs in one
   of two shard_map planes selected by ``FLConfig.shard_overlap``:
 
-  - the **op-by-op plane** (``shard_overlap="off"``, and the plane phase
-    profiling runs on): one compiled collective per schedule op — sessions
-    are ``shard_map``-ped with the per-shard block microbatched (``lax.map``
-    over chunks of ``FLConfig.shard_microbatch`` clients) so N=256–4096
+  - the **op-by-op plane** (``shard_overlap="off"``): one compiled
+    collective per schedule op — sessions are ``shard_map``-ped with the
+    per-shard block microbatched (``lax.map`` over chunks of
+    ``FLConfig.shard_microbatch`` clients) so N=256–4096
     fleets fit in memory, a :class:`~repro.core.schedule.PermuteOp` is a
     ring-shift-decomposed permutation collective (static routing tables +
     per-shift ``lax.ppermute``; with a model axis the flattened parameter
@@ -66,7 +66,6 @@ from __future__ import annotations
 
 import copy
 import functools
-import time
 from typing import Any, Callable, Sequence
 
 import jax
@@ -74,6 +73,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.core import aggregation as agg
 from repro.core.schedule import MixOp, PermuteOp, RoundSchedule, TrainOp
 from repro.distributed.fedshard import diffuse_params, masked_stc_compress
@@ -241,27 +241,15 @@ class FleetExecutor:
         # them: the outputs reuse the inputs' device buffers, which is what
         # lets a client-stacked full-width model fit on one chip.
         self._step = jax.jit(jax.vmap(one), donate_argnums=(0, 1))
-        self.profile = bool(getattr(cfg, "profile_phases", False))
-        self._phase: dict = {}
 
-    # ------------------------------------------------------- phase profiling
+    # ------------------------------------------------------------------ spans
 
-    def _timed(self, phase: str, fn, *args):
-        """Run a round primitive; under ``cfg.profile_phases`` sync the
-        device and charge the wall-clock to ``phase`` (train /
-        hop_collective / mix — "plan" is added by the server)."""
-        if not self.profile:
+    @staticmethod
+    def _traced(name: str, fn, *args):
+        """Run a round primitive inside the ``repro.obs`` span ``name``
+        (``fl.exec.*``); a no-op wrapper while recording is off."""
+        with obs.span(name):
             return fn(*args)
-        t0 = time.time()
-        out = fn(*args)
-        jax.block_until_ready(out)
-        self._phase[phase] = self._phase.get(phase, 0.0) + time.time() - t0
-        return out
-
-    def pop_phase_times(self) -> dict:
-        """Return and reset the per-round phase accumulator."""
-        out, self._phase = self._phase, {}
-        return out
 
     # ---------------------------------------------------------------- batches
 
@@ -272,20 +260,27 @@ class FleetExecutor:
         Returns ``(steps, actives)``: per padded step, a client-stacked batch
         dict and the (C,) bool mask of slots genuinely training that step.
         """
-        per_slot = [list(self.client_batches[c]()) if mask[c] else []
-                    for c in range(len(mask))]
-        nb = max((len(b) for b in per_slot), default=0)
-        if nb == 0:
-            return [], []
-        template = jax.tree.map(
-            np.zeros_like, next(b[0] for b in per_slot if b))
-        steps, actives = [], []
-        for k in range(nb):
-            rows = [b[k] if k < len(b) else template for b in per_slot]
-            steps.append(jax.tree.map(
-                lambda *xs: jnp.asarray(np.stack(xs)), *rows))
-            actives.append(jnp.asarray(
-                np.array([k < len(b) for b in per_slot])))
+        with obs.span("fl.exec.draw"):
+            per_slot = [list(self.client_batches[c]()) if mask[c] else []
+                        for c in range(len(mask))]
+            nb = max((len(b) for b in per_slot), default=0)
+            if nb == 0:
+                return [], []
+            template = jax.tree.map(
+                np.zeros_like, next(b[0] for b in per_slot if b))
+            steps, actives = [], []
+            for k in range(nb):
+                rows = [b[k] if k < len(b) else template for b in per_slot]
+                steps.append(jax.tree.map(
+                    lambda *xs: jnp.asarray(np.stack(xs)), *rows))
+                actives.append(jnp.asarray(
+                    np.array([k < len(b) for b in per_slot])))
+            if obs.enabled():
+                obs.count("fl.exec.slot_steps", len(mask) * nb)
+                obs.count("fl.exec.active_slot_steps",
+                          sum(len(b) for b in per_slot))
+                obs.count("fl.exec.h2d_bytes", sum(
+                    x.nbytes for x in jax.tree.leaves((steps, actives))))
         return steps, actives
 
     def _session(self, params: Params, mask: np.ndarray) -> Params:
@@ -297,6 +292,7 @@ class FleetExecutor:
         # prox anchor = the received model (host default); a copy of its
         # own, because the fleet step donates the params buffers it reads.
         anchor = jax.tree.map(jnp.copy, params) if self.prox else None
+        obs.count("fl.exec.steps", len(steps))
         for batch, active in zip(steps, actives):
             params, mom, _ = self._step(params, mom, batch, active, anchor)
         return params
@@ -361,24 +357,26 @@ class FleetExecutor:
         if sched.persistent and slots is not None:
             params = slots
         else:
-            params = self._timed("hop_collective", self._broadcast,
-                                 global_params, c_slots)
+            params = self._traced("fl.exec.broadcast", self._broadcast,
+                                  global_params, c_slots)
         ref = global_params
         for op in sched.ops:
             if isinstance(op, TrainOp):
-                params = self._timed("train", self._session, params,
-                                     op.train_mask)
+                params = self._traced("fl.exec.train", self._session, params,
+                                      op.train_mask)
             elif isinstance(op, PermuteOp):
                 if op.compress:
-                    params = self._timed("hop_collective", self._masked_stc,
-                                         params, ref, op.compress_src_mask(),
-                                         sched.stc_sparsity)
-                params = self._timed("hop_collective", self._permute,
-                                     params, op)
-                params = self._timed("train", self._session, params,
-                                     op.train_mask)
+                    params = self._traced("fl.exec.hop", self._masked_stc,
+                                          params, ref,
+                                          op.compress_src_mask(),
+                                          sched.stc_sparsity)
+                params = self._traced("fl.exec.hop", self._permute, params,
+                                      op)
+                params = self._traced("fl.exec.train", self._session, params,
+                                      op.train_mask)
             elif isinstance(op, MixOp):
-                params = self._timed("mix", self._mix, params, op, c_slots)
+                params = self._traced("fl.exec.mix", self._mix, params, op,
+                                      c_slots)
             else:
                 raise TypeError(f"unknown op {type(op).__name__}")
         return params
@@ -392,11 +390,11 @@ class FleetExecutor:
         wvec = sched.slot_weights()
         w = jnp.asarray((wvec / wvec.sum()).astype(np.float32))
         if sched.agg_mode == "stc_delta":
-            payload = self._timed("hop_collective", self._masked_stc,
-                                  params, ref, wvec > 0, sched.stc_sparsity)
+            payload = self._traced("fl.exec.aggregate", self._masked_stc,
+                                   params, ref, wvec > 0, sched.stc_sparsity)
         else:
             payload = params
-        return self._timed("mix", self._aggregate, payload, w)
+        return self._traced("fl.exec.aggregate", self._aggregate, payload, w)
 
     def run_round(self, sched: RoundSchedule, global_params: Params,
                   slots: Params | None) -> tuple[Params, Params | None]:
@@ -505,8 +503,8 @@ class ShardedFleetExecutor(FleetExecutor):
       the inverse ``all_to_all`` restores the train layout.
 
     * **Planes.**  ``FLConfig.shard_overlap`` picks between the inherited
-      op-by-op round loop (one compiled collective per schedule op; the
-      plane phase profiling must run on) and the fused round plane: the
+      op-by-op round loop (one compiled collective per schedule op) and
+      the fused round plane: the
       whole round is ONE jitted shard_map program per round *signature*
       (op kinds + step counts + compress/agg flags + hop transport), with
       each hop's ring shifts issued per double-buffered destination chunk
@@ -566,11 +564,10 @@ class ShardedFleetExecutor(FleetExecutor):
         self.fused_mb = self.nl // self.fused_chunks
         mode = str(getattr(cfg, "shard_overlap", "auto"))
         assert mode in ("auto", "on", "off"), mode
-        # Phase profiling needs per-op dispatch boundaries, and below
-        # FUSED_MIN_CLIENTS the fused program's compile cost and round-
-        # signature sensitivity outweigh the dispatch it saves — "auto"
-        # therefore takes the fused plane only for large unprofiled fleets.
-        self.overlap = mode == "on" or (mode == "auto" and not self.profile
+        # Below FUSED_MIN_CLIENTS the fused program's compile cost and
+        # round-signature sensitivity outweigh the dispatch it saves —
+        # "auto" therefore takes the fused plane only for large fleets.
+        self.overlap = mode == "on" or (mode == "auto"
                                         and c >= self.FUSED_MIN_CLIENTS)
         transport = str(getattr(cfg, "shard_hop_transport", "auto"))
         assert transport in ("auto", "ring", "gather"), transport
@@ -1145,8 +1142,9 @@ class ShardedFleetExecutor(FleetExecutor):
         # The mesh/tables were built for cfg.num_clients slots.
         assert sched.num_slots == self.cfg.num_clients, \
             (sched.num_slots, self.cfg.num_clients)
-        if self.overlap and not self.profile:
-            return self._run_round_fused(sched, global_params, slots)
+        if self.overlap:
+            return self._traced("fl.exec.fused", self._run_round_fused,
+                                sched, global_params, slots)
         return super().run_round(sched, global_params, slots)
 
 

@@ -21,6 +21,7 @@ from typing import Callable
 
 import numpy as np
 
+from repro import obs
 from repro.channels.fading import ChannelModel
 from repro.channels.resources import GAMMA_FLOOR, spectral_efficiency
 from repro.channels.topology import CellTopology
@@ -204,13 +205,14 @@ def schedule_feddif(ctx: RoundContext) -> RoundSchedule:
     hop_bits = (compressed_bits(ctx.param_template, cfg.stc_sparsity)
                 if compress else ctx.d2d_bits())
 
-    state = DiffusionState.init(m, n, ctx.dsi.shape[1])
-    init_mask = np.zeros(n, dtype=bool)
-    for mi in range(m):
-        holder = int(state.holder[mi])
-        init_mask[holder] = True
-        state.record_training(mi, holder, ctx.dsi[holder],
-                              float(ctx.data_sizes[holder]))
+    with obs.span("fl.plan.state"):
+        state = DiffusionState.init(m, n, ctx.dsi.shape[1])
+        init_mask = np.zeros(n, dtype=bool)
+        for mi in range(m):
+            holder = int(state.holder[mi])
+            init_mask[holder] = True
+            state.record_training(mi, holder, ctx.dsi[holder],
+                                  float(ctx.data_sizes[holder]))
     ops: list = [TrainOp(init_mask)]
     wire: list = [_downlink(ctx)]
 
@@ -234,27 +236,30 @@ def schedule_feddif(ctx: RoundContext) -> RoundSchedule:
         value_weight=float(getattr(cfg, "uncertainty_weight", 0.0)),
         world=planner_world, step_m=step_m)
 
-    slot_of_model = np.arange(m) % max(n, 1)
-    for k in range(plan.num_rounds):
-        hops = plan.hops_in_round(k)
-        for h in hops:
-            wire.append(WireEvent("d2d", hop_bits,
-                                  max(h.gamma, GAMMA_FLOOR), src=int(h.src)))
-        src_of_dst, mask, slot_of_model = complete_round_permutation(
-            [(h.model, h.dst) for h in hops], slot_of_model, n)
-        ops.append(PermuteOp(src_of_dst, mask, compress=compress))
+    obs.count("fl.plan.hops", len(plan.hops))
+    with obs.span("fl.plan.build"):
+        slot_of_model = np.arange(m) % max(n, 1)
+        for k in range(plan.num_rounds):
+            hops = plan.hops_in_round(k)
+            for h in hops:
+                wire.append(WireEvent("d2d", hop_bits,
+                                      max(h.gamma, GAMMA_FLOOR),
+                                      src=int(h.src)))
+            src_of_dst, mask, slot_of_model = complete_round_permutation(
+                [(h.model, h.dst) for h in hops], slot_of_model, n)
+            ops.append(PermuteOp(src_of_dst, mask, compress=compress))
 
-    for mi in range(m):
-        wire.append(_uplink(ctx, int(state.holder[mi])))
-    return RoundSchedule(
-        num_slots=n,
-        ops=ops,
-        wire=wire,
-        agg=[(int(slot_of_model[mi]), float(state.chain_size[mi]))
-             for mi in range(m)],
-        stc_sparsity=cfg.stc_sparsity,
-        diffusion_rounds=plan.num_rounds,
-        mean_iid=float(np.mean(plan.final_iid_distance)))
+        for mi in range(m):
+            wire.append(_uplink(ctx, int(state.holder[mi])))
+        return RoundSchedule(
+            num_slots=n,
+            ops=ops,
+            wire=wire,
+            agg=[(int(slot_of_model[mi]), float(state.chain_size[mi]))
+                 for mi in range(m)],
+            stc_sparsity=cfg.stc_sparsity,
+            diffusion_rounds=plan.num_rounds,
+            mean_iid=float(np.mean(plan.final_iid_distance)))
 
 
 def schedule_fedswap(ctx: RoundContext) -> RoundSchedule:

@@ -27,6 +27,11 @@ in three strategy-agnostic stages, mirroring the paper's PUCCH/PUSCH split:
    schedule first (``apply_round_churn``): dropped clients neither train nor
    carry aggregation weight, while their wire events still charge. [PUSCH]
 
+Each round is a ``repro.obs`` span, ``fl.round``, holding one span per stage
+(``fl.world``, ``fl.plan``, ``fl.charge``, ``fl.exec``, ``fl.eval``); they
+record only inside ``obs.recording()`` or while a profiler trace is being
+captured.
+
 Adding a strategy therefore means: append its name to :data:`STRATEGIES` and
 write one scheduler in ``repro.fl.schedulers`` — both executors, the ledger,
 the experiment harness (``repro.fl.experiment``), the sweep registry
@@ -46,12 +51,12 @@ once per sweep cell and replay the plan across replicate seeds.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Any, Callable, Sequence
 
 import jax
 import numpy as np
 
+from repro import obs
 from repro.channels.fading import ChannelModel
 from repro.channels.resources import (GAMMA_FLOOR, PRB_HZ, ResourceLedger,
                                       spectral_efficiency)
@@ -116,16 +121,13 @@ class FLConfig:
                                      # ("off"); "auto" = fused at large N
                                      # (executors.FUSED_MIN_CLIENTS) where
                                      # per-op dispatch dominates, op-by-op
-                                     # below it and while profiling phases
+                                     # below it
     shard_hop_transport: str = "auto"  # fused-plane hop collective:
                                      # "gather" (one all_gather per hop, the
                                      # fast path while the gathered stack
                                      # fits memory) | "ring" (per-shift
                                      # ppermute, O(block) memory) | "auto"
                                      # = gather under the byte budget
-    profile_phases: bool = False     # per-round train/hop/mix wall-clock
-                                     # breakdown (forces the op-by-op plane —
-                                     # a fused round cannot be sub-timed)
     churn_rate: float = 0.0          # per-round P(client drops out) — see
                                      # schedulers.apply_round_churn
     scenario: str = "static"         # wireless world evolution
@@ -175,7 +177,7 @@ class FLConfig:
 # Legacy alias, one release: ``run_federated`` now returns the structured
 # :class:`repro.fl.engine.RunResult` (params, ledger, history, engine), whose
 # properties reproduce the old flat FLResult surface (``accuracy``, ``loss``,
-# ``final_params``, ``round_wall_s``, ``phase_s``, ``rounds_to_accuracy``).
+# ``final_params``, ``round_wall_s``, ``rounds_to_accuracy``).
 FLResult = RunResult
 
 
@@ -291,7 +293,6 @@ def run_federated(init_fn: Callable, loss_fn: Callable,
 
     acc_hist, loss_hist, dif_hist, iid_hist = [], [], [], []
     round_wall: list[float] = []
-    phase_hist: list[dict] = []
     slots = None            # persistent per-slot state (gossip / tthf)
     start_t = 0
 
@@ -319,67 +320,76 @@ def run_federated(init_fn: Callable, loss_fn: Callable,
                         np.random.default_rng([cfg.topology_seed, tt]))
 
     for t in range(start_t, cfg.rounds):
-        # Control-plane stream: per-round and model-seed-independent when
-        # topology_seed is set, so diffusion plans are cacheable across seeds.
-        if cfg.topology_seed is not None:
-            ctrl_rng = np.random.default_rng([cfg.topology_seed, t])
-        else:
-            ctrl_rng = rng
-        pos = world.advance_round(ctrl_rng)
-        up_gamma = np.maximum(world.uplink_gamma(ctrl_rng), GAMMA_FLOOR)
-        learning_value = None
-        if value_fn is not None and cfg.uncertainty_weight > 0.0:
-            learning_value = np.asarray(value_fn(global_params), np.float64)
+        with obs.fl_round(t):
+            with obs.span("fl.world"):
+                # Control-plane stream: per-round and model-seed-independent
+                # when topology_seed is set, so diffusion plans are cacheable
+                # across seeds.
+                if cfg.topology_seed is not None:
+                    ctrl_rng = np.random.default_rng([cfg.topology_seed, t])
+                else:
+                    ctrl_rng = rng
+                pos = world.advance_round(ctrl_rng)
+                up_gamma = np.maximum(world.uplink_gamma(ctrl_rng),
+                                      GAMMA_FLOOR)
+                interference = world.interference()
+                learning_value = None
+                if value_fn is not None and cfg.uncertainty_weight > 0.0:
+                    learning_value = np.asarray(value_fn(global_params),
+                                                np.float64)
 
-        t_plan = time.time()
-        ctx = RoundContext(cfg=cfg, t=t, dsi=dsi, data_sizes=data_sizes,
-                           pos=pos, rng=ctrl_rng, up_gamma=up_gamma,
-                           topology=topology, channel=channel,
-                           planner=planner, model_bits=model_bits,
-                           param_template=global_params,
-                           plan_cache=plan_cache, hop_bits=hop_bits,
-                           world=world, interference=world.interference(),
-                           learning_value=learning_value)
-        schedule = SCHEDULERS[cfg.strategy](ctx)
-        if t == 0 and base_bits > 0.0:
-            # One-time frozen-base broadcast (adapter view): every round-t
-            # state derives from base + hopped adapter, so the base ships
-            # once on the round-0 downlink, strategy-independent.
-            schedule.wire.append(WireEvent("downlink", float(base_bits),
-                                           float(np.median(up_gamma)), n))
-        schedule = apply_round_churn(ctx, schedule)
-        if world.has_energy_cap:
-            schedule = apply_energy_cap(ctx, schedule, world.depleted())
-        charge_schedule(ledger, schedule)
-        if world.has_energy_cap:
-            world.charge_energy(per_client_energy_j(schedule, n, PRB_HZ))
-        plan_s = time.time() - t_plan
-        t_exec = time.time()
-        global_params, slots = executor.run_round(schedule, global_params,
-                                                  slots)
-        jax.block_until_ready(global_params)
-        round_wall.append(time.time() - t_exec)
-        if cfg.profile_phases:
-            phases = dict(getattr(executor, "pop_phase_times",
-                                  lambda: {})())
-            phases["plan"] = plan_s
-            phase_hist.append(phases)
-        dif_hist.append(schedule.diffusion_rounds)
-        iid_hist.append(schedule.mean_iid)
+            with obs.span("fl.plan"):
+                ctx = RoundContext(cfg=cfg, t=t, dsi=dsi,
+                                   data_sizes=data_sizes, pos=pos,
+                                   rng=ctrl_rng, up_gamma=up_gamma,
+                                   topology=topology, channel=channel,
+                                   planner=planner, model_bits=model_bits,
+                                   param_template=global_params,
+                                   plan_cache=plan_cache, hop_bits=hop_bits,
+                                   world=world, interference=interference,
+                                   learning_value=learning_value)
+                schedule = SCHEDULERS[cfg.strategy](ctx)
+                if t == 0 and base_bits > 0.0:
+                    # One-time frozen-base broadcast (adapter view): every
+                    # round-t state derives from base + hopped adapter, so
+                    # the base ships once on the round-0 downlink,
+                    # strategy-independent.
+                    schedule.wire.append(WireEvent(
+                        "downlink", float(base_bits),
+                        float(np.median(up_gamma)), n))
+                schedule = apply_round_churn(ctx, schedule)
+                if world.has_energy_cap:
+                    schedule = apply_energy_cap(ctx, schedule,
+                                                world.depleted())
+            with obs.span("fl.charge"):
+                charge_schedule(ledger, schedule)
+                if world.has_energy_cap:
+                    world.charge_energy(per_client_energy_j(schedule, n,
+                                                            PRB_HZ))
+            with obs.timed("fl.exec") as exec_span:
+                global_params, slots = executor.run_round(
+                    schedule, global_params, slots)
+                jax.block_until_ready(global_params)
+            round_wall.append(exec_span.seconds)
+            dif_hist.append(schedule.diffusion_rounds)
+            iid_hist.append(schedule.mean_iid)
 
-        if (t + 1) % cfg.eval_every == 0 or t == cfg.rounds - 1:
-            a, l = eval_fn(global_params)
-            acc_hist.append(float(a))
-            loss_hist.append(float(l))
+            if (t + 1) % cfg.eval_every == 0 or t == cfg.rounds - 1:
+                with obs.span("fl.eval"):
+                    a, l = eval_fn(global_params)
+                acc_hist.append(float(a))
+                loss_hist.append(float(l))
 
-        if checkpointer is not None and checkpointer.due(t + 1, cfg.rounds):
-            checkpointer.save(t + 1, executor, global_params, slots, ledger,
-                              cfg, acc_hist=acc_hist, loss_hist=loss_hist,
-                              dif_hist=dif_hist, iid_hist=iid_hist,
-                              round_wall=round_wall, rng=rng)
+            if checkpointer is not None and checkpointer.due(t + 1,
+                                                             cfg.rounds):
+                checkpointer.save(t + 1, executor, global_params, slots,
+                                  ledger, cfg, acc_hist=acc_hist,
+                                  loss_hist=loss_hist, dif_hist=dif_hist,
+                                  iid_hist=iid_hist, round_wall=round_wall,
+                                  rng=rng)
 
     hist = RunHistory(accuracy=acc_hist, loss=loss_hist,
                       diffusion_rounds=dif_hist, iid_distance=iid_hist,
-                      round_wall_s=round_wall, phase_s=phase_hist)
+                      round_wall_s=round_wall)
     return RunResult(params=global_params, ledger=ledger, history=hist,
                      engine=espec, config=cfg)

@@ -183,6 +183,7 @@ def mix_aggregate_pallas(x: jax.Array, w: jax.Array, *,
         out_specs=pl.BlockSpec((bg, bf), lambda r, i, k: (r, i)),
         out_shape=jax.ShapeDtypeStruct((g, f), jnp.float32),
         interpret=interpret,
+        name="mix_aggregate",
     )(w.astype(jnp.float32), x.astype(jnp.float32))
 
 
@@ -265,6 +266,7 @@ def stc_rows_pallas(x: jax.Array, ref_row: jax.Array, mask: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((cp, 2), jnp.float32),
                    jax.ShapeDtypeStruct((cp, 2), jnp.float32)],
         interpret=interpret,
+        name="stc_rows_reduce",
     )(xp, rp, thr2)
     ssum = ssum.sum(axis=1, keepdims=True)                      # (Cp, 1)
     cnt = cnt.sum(axis=1, keepdims=True)
@@ -277,6 +279,7 @@ def stc_rows_pallas(x: jax.Array, ref_row: jax.Array, mask: jax.Array,
         out_specs=row_blk,
         out_shape=jax.ShapeDtypeStruct(xp.shape, jnp.float32),
         interpret=interpret,
+        name="stc_rows_apply",
     )(xp, rp, thr2, mu, mask2)
     return out[:c, :n].astype(x.dtype)
 
@@ -385,6 +388,7 @@ def dol_bid_scores_pallas(dol: jax.Array, chain_size: jax.Array,
         out_shape=jax.ShapeDtypeStruct((psi_c.shape[0], d_c.shape[0]),
                                        jnp.float32),
         interpret=interpret,
+        name="dol_bid_scores",
     )(psi_c, a, p_psi, s_psi, d_c, b, p_d, s_d)
     return out[:m, :n]
 
@@ -427,5 +431,6 @@ def bid_value_fuse_pallas(bids: jax.Array, value: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct(bp.shape, jnp.float32),
         interpret=interpret,
+        name="bid_value_fuse",
     )(bp, vp, w)
     return out[:m, :n]
