@@ -14,8 +14,10 @@ Implements Section III-B of the paper:
   Corollary 1 (Eq. A.16).
 * **Closed-form real-world IID distance** of Lemma 2 (Eq. 30).
 
-Everything is pure ``jax.numpy`` on small ``(C,)``/``(N, C)`` arrays so it can
-run inside jitted schedulers and on host alike.
+The primitives and :class:`PlannerState` are ``jax.numpy`` on small
+``(C,)``/``(N, C)`` arrays, so they run inside the jitted planner.
+:class:`DiffusionState` is the host's mutable bookkeeping: it folds Eq. (2) in
+NumPy float32 through the same body as :func:`update_dol`, with no device call.
 """
 from __future__ import annotations
 
@@ -62,6 +64,17 @@ def dsi_from_counts(counts: Array) -> Array:
     return jnp.where(total > 0, counts / jnp.maximum(total, 1.0), 1.0 / c)
 
 
+def _fold_dol(xp, dol, chain_size, dsi, data_size):
+    """Eq. (2) in the array module ``xp`` (``jnp`` or ``np``), in float32."""
+    chain_size = xp.asarray(chain_size, xp.float32)
+    data_size = xp.asarray(data_size, xp.float32)
+    new_size = chain_size + data_size
+    num = chain_size[..., None] * dol + data_size[..., None] * dsi
+    new_dol = num / xp.maximum(new_size[..., None], np.float32(1.0))
+    # A model that has never trained (chain 0) adopts the client's DSI.
+    return new_dol, new_size
+
+
 def update_dol(dol: Array, chain_size: Array, dsi: Array, data_size: Array
                ) -> tuple[Array, Array]:
     """Eq. (2): fold one client's data into a model's DoL.
@@ -71,13 +84,7 @@ def update_dol(dol: Array, chain_size: Array, dsi: Array, data_size: Array
     Returns ``(new_dol, new_chain_size)``.  Broadcasts over leading axes so a
     whole fleet of models can be updated in one call.
     """
-    chain_size = jnp.asarray(chain_size, jnp.float32)
-    data_size = jnp.asarray(data_size, jnp.float32)
-    new_size = chain_size + data_size
-    num = chain_size[..., None] * dol + data_size[..., None] * dsi
-    new_dol = num / jnp.maximum(new_size[..., None], 1.0)
-    # A model that has never trained (chain 0) adopts the client's DSI.
-    return new_dol, new_size
+    return _fold_dol(jnp, dol, chain_size, dsi, data_size)
 
 
 def _w1_norm(p: Array, num_classes: int) -> Array:
@@ -278,7 +285,10 @@ class DiffusionState:
     """Host-side bookkeeping for one communication round of FedDif.
 
     Tracks, per model m: the DoL, the chain data size, and the set of clients
-    already visited (constraint 18c — no retraining).
+    already visited (constraint 18c — no retraining).  Every field is a NumPy
+    array and :meth:`record_training` folds Eq. (2) in NumPy float32, bit for
+    bit :func:`update_dol` on the CPU, without touching the device; the jitted
+    planner takes the state to the device through :meth:`functional`.
     """
     dol: np.ndarray            # (M, C)
     chain_size: np.ndarray     # (M,)
@@ -300,10 +310,11 @@ class DiffusionState:
 
     def record_training(self, model: int, client: int, dsi: np.ndarray,
                         data_size: float) -> None:
-        new_dol, new_size = update_dol(self.dol[model], self.chain_size[model],
-                                       jnp.asarray(dsi), data_size)
-        self.dol[model] = np.asarray(new_dol)
-        self.chain_size[model] = float(new_size)
+        new_dol, new_size = _fold_dol(np, self.dol[model],
+                                      self.chain_size[model],
+                                      np.asarray(dsi, np.float32), data_size)
+        self.dol[model] = new_dol
+        self.chain_size[model] = new_size
         self.visited[model, client] = True
         self.holder[model] = client
 

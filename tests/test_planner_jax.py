@@ -133,10 +133,11 @@ def test_channel_jax_twins_match_numpy():
     assert bool(jnp.all(jnp.linalg.norm(p1, axis=-1) <= topo.radius_m + 1e-3))
 
 
-def test_planner_state_matches_mutable_state():
+@pytest.mark.parametrize("n,m,c", [(5, 4, 6), (256, 256, 10)])
+def test_planner_state_matches_mutable_state(n, m, c):
     """PlannerState.record_training / record_round mirror the mutable
-    DiffusionState bookkeeping bit for bit."""
-    n, m, c = 5, 4, 6
+    DiffusionState bookkeeping bit for bit, at a toy size and at the
+    benchmark's FedDif size (N=M=256, C=10)."""
     rng = np.random.default_rng(1)
     dsi = rng.dirichlet(np.ones(c), n).astype(np.float32)
     sizes = rng.integers(50, 200, n).astype(np.float64)
@@ -147,10 +148,14 @@ def test_planner_state_matches_mutable_state():
         fstate = fstate.record_training(mi, mi % n, dsi[mi % n],
                                         float(sizes[mi % n]))
     np.testing.assert_allclose(np.asarray(fstate.dol), host.dol, atol=0)
+    np.testing.assert_allclose(np.asarray(fstate.chain_size),
+                               host.chain_size, atol=0)
     np.testing.assert_array_equal(np.asarray(fstate.holder), host.holder)
-    # one masked round: models 0 and 2 hop
-    dst = np.array([3, 0, 4, 0])
-    mask = np.array([True, False, True, False])
+    # one masked round from non-zero chains: distinct destinations, about
+    # half the models hop
+    dst = rng.permutation(n)[:m]
+    mask = rng.random(m) < 0.5
+    mask[0] = True
     fstate2 = fstate.record_round(jnp.asarray(dst), jnp.asarray(mask),
                                   jnp.asarray(dsi), jnp.asarray(sizes))
     for mi in range(m):
@@ -158,6 +163,8 @@ def test_planner_state_matches_mutable_state():
             host.record_training(mi, int(dst[mi]), dsi[dst[mi]],
                                  float(sizes[dst[mi]]))
     np.testing.assert_allclose(np.asarray(fstate2.dol), host.dol, atol=0)
+    np.testing.assert_allclose(np.asarray(fstate2.chain_size),
+                               host.chain_size, atol=0)
     np.testing.assert_array_equal(np.asarray(fstate2.visited), host.visited)
     np.testing.assert_array_equal(np.asarray(fstate2.holder), host.holder)
     # functional() / update_from round-trip
@@ -165,6 +172,32 @@ def test_planner_state_matches_mutable_state():
     host2.update_from(fstate2, rounds_advanced=1)
     np.testing.assert_allclose(host2.dol, host.dol, atol=0)
     assert host2.round_index == 1
+
+
+def test_diffusion_state_initial_training_stays_on_host():
+    """FedDif's initial-training stage (``DiffusionState.init`` and one
+    Eq.-(2) fold a model, as ``schedule_feddif`` runs it) at the benchmark's
+    size moves nothing to or from the device, and keeps its fields NumPy
+    float32."""
+    n = m = 256
+    c = 10
+    rng = np.random.default_rng(2)
+    dsi = rng.dirichlet(np.ones(c), n).astype(np.float32)
+    sizes = rng.integers(50, 200, n).astype(np.float64)
+    with jax.transfer_guard("disallow"):
+        state = DiffusionState.init(m, n, c)
+        for mi in range(m):
+            holder = int(state.holder[mi])
+            state.record_training(mi, holder, dsi[holder],
+                                  float(sizes[holder]))
+    for field in (state.dol, state.chain_size):
+        assert type(field) is np.ndarray
+        assert field.dtype == np.float32
+    # one fold from an empty chain adopts the client's DSI and data size
+    np.testing.assert_array_equal(state.chain_size,
+                                  sizes[state.holder].astype(np.float32))
+    np.testing.assert_allclose(state.dol, dsi[state.holder], rtol=1e-6)
+    assert state.visited[np.arange(m), state.holder].all()
 
 
 def test_as_permutations_keeps_never_hopping_models():
