@@ -3,9 +3,11 @@
 ``BENCHMARK.json`` at the checkout's root names the cells; each is a
 configuration under a traffic mix, and everything else is found by name:
 
-* ``configs/<config>.json``: the configuration's sizes, as run;
-  ``configs/<config>_ref.py``: its plain reference (weights from a seed,
-  the loss in plain ``jax.numpy``, FLOPs per training row);
+* ``configs/<config>.json``: the configuration's sizes, as run, its rows
+  (``data``: images or tokens) and, optionally, smaller sizes for the CPU
+  tests (``cpu``); ``configs/<config>_ref.py``: its plain reference
+  (trained weights from a seed, the loss in plain ``jax.numpy``, FLOPs per
+  training row and, optionally, a frozen tree, ``frozen``);
   ``configs/<config>.py``: the glue to the program under test.
 * ``traffic/<mix>.json``: a traffic mix, read by ``traffic/generate.py``.
 * ``cells/<workload>.json``: the limits of the numbers that decide

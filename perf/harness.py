@@ -94,6 +94,29 @@ def load_config(name: str):
             importlib.import_module(f"perf.configs.{name}"))
 
 
+def cpu_sizes(conf: dict) -> dict:
+    """The configuration at the sizes of its ``"cpu"`` object, merged
+    deeply into it: what the per-cell CPU tests run.  Chip runs use the
+    configuration itself."""
+    def merge(base: dict, over: dict) -> dict:
+        out = dict(base)
+        for k, v in over.items():
+            out[k] = (merge(base[k], v) if isinstance(v, dict)
+                      and isinstance(base.get(k), dict) else v)
+        return out
+    return merge(conf, conf.get("cpu", {}))
+
+
+def frozen_tree(conf: dict, ref, seeds: dict):
+    """The tree a reference's ``frozen(conf, key)`` builds from the run's
+    ``"frozen"`` sub-seed, on the device in one call; None where the
+    reference defines no ``frozen``."""
+    if not hasattr(ref, "frozen"):
+        return None
+    build = jax.jit(lambda k: ref.frozen(conf, k))
+    return jax.block_until_ready(build(jax.random.PRNGKey(seeds["frozen"])))
+
+
 @dataclasses.dataclass
 class Records:
     """What the first rounds and the window left for the check and the
@@ -111,6 +134,7 @@ class Records:
     window_rounds: int = 0
     window_compiles: int = 0
     rows_window: int = 0
+    frozen: object = None               # the frozen tree, or None
 
 
 def _engine(mix: dict):
@@ -149,7 +173,11 @@ def drive(conf: dict, ref, glue, mix: dict, seed: int,
     gc.collect()
     seeds = sub_seeds(seed)
     traffic = make_traffic(mix, conf["data"], seed)
-    loss_fn, evaluate, shapes = glue.program(conf, mix)
+    rec = Records(frozen=frozen_tree(conf, ref, seeds))
+    # A configuration with a frozen tree hands the program the same arrays.
+    loss_fn, evaluate, shapes = (
+        glue.program(conf, mix) if rec.frozen is None
+        else glue.program(conf, mix, rec.frozen))
     if fault == "half_batch":
         inner = loss_fn
 
@@ -169,7 +197,6 @@ def drive(conf: dict, ref, glue, mix: dict, seed: int,
                          "program's")
 
     spans = Spans(annotate=trace_dir is not None)
-    rec = Records()
     batch_span = spans.wrap("batch", lambda ld: list(ld.epoch()))
 
     def draws(loader):
@@ -286,10 +313,11 @@ def drive(conf: dict, ref, glue, mix: dict, seed: int,
 
 def reference_globals(conf: dict, ref, glue, mix: dict, traffic, seeds: dict,
                       schedules: list, compute_dtype="float32",
-                      param_dtype="float32"):
+                      param_dtype="float32", frozen=None):
     """The reference's initial params (host), its global params after each
     of the first rounds, its first gradient at every slot, the schedule
-    faults it found, and its ledger."""
+    faults it found, and its ledger.  ``frozen`` is the run's frozen tree,
+    the program's own arrays."""
     init = jax.jit(lambda k: ref.init(conf, k))
     params0 = init(jax.random.PRNGKey(seeds["init"]))
     bits = R.model_bits(params0)
@@ -304,7 +332,7 @@ def reference_globals(conf: dict, ref, glue, mix: dict, traffic, seeds: dict,
         faults += plan.faults
         plans.append(plan)
     trainer = R.Trainer(ref, conf, mix, jnp.dtype(compute_dtype),
-                        jnp.dtype(param_dtype), glue.SLOT_BLOCK)
+                        jnp.dtype(param_dtype), glue.SLOT_BLOCK, frozen)
     host0 = jax.device_get(params0)
     grad1 = R.first_gradient(mix, traffic, seeds["loader"], params0, trainer)
     globals3 = R.replay(ref, conf, mix, traffic, seeds["loader"], params0,
@@ -324,9 +352,10 @@ def check(conf, ref, glue, mix, rec, traffic, seeds) -> dict:
     jax.clear_caches()                  # the program's programs, unloaded
     gc.collect()
     params0, ref_g, grad1, faults, ledger = reference_globals(
-        conf, ref, glue, mix, traffic, seeds, rec.schedules)
+        conf, ref, glue, mix, traffic, seeds, rec.schedules,
+        frozen=rec.frozen)
     ref_reading = R.reading_of(ref, conf, traffic, params0, ref_g, grad1,
-                               glue.EVAL_BLOCK)
+                               glue.EVAL_BLOCK, frozen=rec.frozen)
     numbers = R.compare(program_reading(rec, params0), ref_reading)
     numbers["ledger_gap"] = ledger.gap(rec.ledger)
     numbers["schedule_faults"] = len(faults)

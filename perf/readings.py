@@ -39,10 +39,11 @@ def read_seed(cell: dict, seed: int, control: bool, faults: bool) -> dict:
     rec, traffic, _, seeds = H.drive(conf, ref, glue, mix,
                                      seed, 0.0, None,
                                      stop_after=H.WARMUP_ROUNDS)
+    frozen = rec.frozen
     params0, ref_g, grad1, found, ledger = H.reference_globals(
-        conf, ref, glue, mix, traffic, seeds, rec.schedules)
+        conf, ref, glue, mix, traffic, seeds, rec.schedules, frozen=frozen)
     ref_reading = R.reading_of(ref, conf, traffic, params0, ref_g, grad1,
-                               glue.EVAL_BLOCK)
+                               glue.EVAL_BLOCK, frozen=frozen)
     out = {"seed": seed}
     out["sound"] = R.compare(H.program_reading(rec, params0), ref_reading)
     out["sound"]["ledger_gap"] = ledger.gap(rec.ledger)
@@ -52,10 +53,11 @@ def read_seed(cell: dict, seed: int, control: bool, faults: bool) -> dict:
         _, ctl_g, ctl_grad1, _, _ = H.reference_globals(
             conf, ref, glue, mix, traffic, seeds, rec.schedules,
             compute_dtype=ctl["compute_dtype"],
-            param_dtype=ctl["param_dtype"])
+            param_dtype=ctl["param_dtype"], frozen=frozen)
         out["control"] = R.compare(
             R.reading_of(ref, conf, traffic, params0, ctl_g, ctl_grad1,
-                         glue.EVAL_BLOCK, jnp.dtype(ctl["compute_dtype"])),
+                         glue.EVAL_BLOCK, jnp.dtype(ctl["compute_dtype"]),
+                         frozen),
             ref_reading)
     if faults:
         kinds = ["half_batch"] + (["no_hop"] if mix["strategy"] == "feddif"

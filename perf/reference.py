@@ -2,10 +2,13 @@
 decides ``correct``.  It imports nothing of the program.
 
 What the reference takes: the configuration's plain model (``<config>_ref``),
-the traffic the benchmark generated, the seeds it handed the program, and
-the round schedules the program's scheduler returned for the first three
-rounds.  A schedule is an answer of the program's control plane, so it is
-checked before it is followed, by the strategy's own file
+the traffic the benchmark generated, the seeds it handed the program, the
+round schedules the program's scheduler returned for the first three
+rounds and, where the plain model defines ``frozen``, the frozen tree the
+harness built once and handed the program too.  That tree is an argument
+of every call that runs the model (``model_loss``), never a constant of a
+compiled program.  A schedule is an answer of the program's control
+plane, so it is checked before it is followed, by the strategy's own file
 (``perf/strategies/<strategy>.py``) on the channels the scenario's file
 (``perf/worlds/<scenario>.py``) draws from the round's seed:
 
@@ -147,17 +150,30 @@ def epoch_indices(loader_seed: int, client: int, epoch: int, rows: int,
     return np.stack(out)
 
 
+def model_loss(ref, conf: dict, dtype):
+    """``(q, x, y, frozen) -> loss`` of the configuration's reference: its
+    ``loss(q, x, y, conf, dtype, frozen)`` where it defines ``frozen``,
+    and ``loss(q, x, y, conf, dtype)`` otherwise."""
+    if hasattr(ref, "frozen"):
+        return lambda q, x, y, fz: ref.loss(q, x, y, conf, dtype, fz)
+    return lambda q, x, y, fz: ref.loss(q, x, y, conf, dtype)
+
+
 class Trainer:
-    """Client sessions in plain JAX, over blocks of slots."""
+    """Client sessions in plain JAX, over blocks of slots.  The frozen
+    tree (None without one) is an argument of the step, shared by every
+    slot, so no compiled program holds a copy of it."""
 
     def __init__(self, ref, conf: dict, mix: dict, compute_dtype,
-                 param_dtype, block: int):
+                 param_dtype, block: int, frozen=None):
         lr, mom = float(mix["lr"]), float(mix["momentum"])
         self.block = block
         self.param_dtype = jnp.dtype(param_dtype)
+        self.frozen = frozen
+        loss = model_loss(ref, conf, compute_dtype)
 
-        def one(p, mu, x, y, active):
-            g = jax.grad(lambda q: ref.loss(q, x, y, conf, compute_dtype))(p)
+        def one(p, mu, x, y, active, fz):
+            g = jax.grad(lambda q: loss(q, x, y, fz))(p)
             norm = jnp.sqrt(sum(jnp.sum(jnp.square(v.astype(jnp.float32)))
                                 for v in jax.tree.leaves(g)))
             scale = jnp.minimum(1.0, CLIP_NORM / jnp.maximum(norm, 1e-9))
@@ -168,7 +184,8 @@ class Trainer:
             sel = lambda a, b: jnp.where(active, a, b)     # noqa: E731
             return jax.tree.map(sel, p2, p), jax.tree.map(sel, mu2, mu)
 
-        self.step = jax.jit(jax.vmap(one), donate_argnums=(0, 1))
+        self.step = jax.jit(jax.vmap(one, in_axes=(0, 0, 0, 0, 0, None)),
+                            donate_argnums=(0, 1))
 
     def session(self, blocks: list, xs: np.ndarray, ys: np.ndarray,
                 active: np.ndarray) -> list:
@@ -187,7 +204,8 @@ class Trainer:
                 if active[j, sl].any():
                     p, mu = self.step(p, mu, jnp.asarray(xs[j, sl]),
                                       jnp.asarray(ys[j, sl]),
-                                      jnp.asarray(active[j, sl]))
+                                      jnp.asarray(active[j, sl]),
+                                      self.frozen)
             out.append(p)
             del mu
         return out
@@ -202,7 +220,8 @@ class Trainer:
             p = jax.tree.map(jnp.copy, blk)
             mu = jax.tree.map(lambda a: jnp.zeros(a.shape, jnp.float32), p)
             _, mu = self.step(p, mu, jnp.asarray(xs[lo:lo + c]),
-                              jnp.asarray(ys[lo:lo + c]), jnp.ones(c, bool))
+                              jnp.asarray(ys[lo:lo + c]), jnp.ones(c, bool),
+                              self.frozen)
             out.append(jax.device_get(mu))
             lo += c
         return out
@@ -291,12 +310,13 @@ def replay(ref, conf: dict, mix: dict, traffic, loader_seed: int,
 
 
 def test_loss(ref, conf: dict, params, x: np.ndarray, y: np.ndarray,
-              block: int, dtype=jnp.float32) -> float:
-    f = jax.jit(lambda p, a, b: ref.loss(p, a, b, conf, dtype))
+              block: int, dtype=jnp.float32, frozen=None) -> float:
+    f = jax.jit(model_loss(ref, conf, dtype))
     total = 0.0
     for i in range(0, len(y), block):
         total += float(f(params, jnp.asarray(x[i:i + block]),
-                         jnp.asarray(y[i:i + block]))) * len(y[i:i + block])
+                         jnp.asarray(y[i:i + block]), frozen)) \
+            * len(y[i:i + block])
     return total / len(y)
 
 
@@ -363,9 +383,9 @@ def compare(side: Reading, ref: Reading) -> dict:
 
 def reading_of(ref_mod, conf, traffic, params0, globals3: list,
                grad1: list, eval_block: int,
-               dtype=jnp.float32) -> Reading:
+               dtype=jnp.float32, frozen=None) -> Reading:
     losses = [test_loss(ref_mod, conf, g, traffic.test_x, traffic.test_y,
-                        eval_block, dtype) for g in globals3]
+                        eval_block, dtype, frozen) for g in globals3]
     return Reading(losses=losses, grad1=grad1,
                    norms3=leaf_change_norms(globals3[-1], params0))
 

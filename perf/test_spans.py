@@ -73,6 +73,7 @@ def test_readers_read_a_small_cell_driven_under_the_profiler(cell, tmp_path,
     obs.snapshot()
     c = RUN.find_cell(BENCH, cell)
     conf, ref, glue = H.load_config(c["config"])
+    conf = H.cpu_sizes(conf)
     mix = load_mix(c["traffic"])
     mix.update(clients=6, models=6, rows_per_client=40, test_rows=100)
     with jax.profiler.trace(str(tmp_path)):

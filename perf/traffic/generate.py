@@ -1,13 +1,15 @@
 """The one traffic generator.  A traffic mix is a data file beside this one
 (``<mix>.json``: strategy, fleet size, engine, batch, learning rate, rows
 per client, Dirichlet alpha, ...); a configuration's file says what a row
-is (an image of ``dim`` features).  From ``--seed``
-this module makes every array the program receives: the training rows
-split over the clients, and the held-out test rows.
+is: an image of ``dim`` features (``data.kind`` ``"image"``), or
+``seq + 1`` token ids from a vocabulary of ``vocab`` (``"tokens"``).  From
+``--seed`` this module makes every array the program receives: the training
+rows split over the clients, and the held-out test rows.
 
-The data generators are copied from the program (``repro.data.synthetic``
-and ``repro.data.partitioner``) so that a change to the program cannot move
-the yardstick.  One departure, for steady work from seed to seed: every
+The image generator and the partition are copied from the program
+(``repro.data.synthetic`` and ``repro.data.partitioner``) so that a change
+to the program cannot move the yardstick; the token generator is the
+benchmark's own.  One departure, for steady work from seed to seed: every
 client holds exactly ``rows_per_client`` rows.  The Dirichlet(alpha) draw
 sets each client's class mixture, and the counts are its largest-remainder
 rounding; the rows of each class are then generated, not drawn from a
@@ -26,7 +28,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 
 # Named sub-streams of one run's seed.  Each is a 31-bit integer, so the
 # program may add small offsets to it (its loaders use seed + 1000 i + k).
-STREAMS = ("data", "loader", "topology", "init")
+# A stream is only ever appended: ``generate_state`` keeps the words of the
+# earlier streams as its prefix, so their values never change.
+STREAMS = ("data", "loader", "topology", "init", "frozen")
 
 
 def load_mix(name: str) -> dict:
@@ -81,6 +85,48 @@ def gaussian_image_rows(labels: np.ndarray, means: np.ndarray,
     return x.astype(np.float32)
 
 
+# ----------------------------------------------------------------- tokens
+
+# Zipf exponent of a topic's noise and the share of tokens that follow its
+# rule: those of the program's ``repro.data.synthetic.lm_corpus``.
+ZIPF, FOLLOW = 1.1, 0.5
+
+
+def topic_model(classes: int, vocab: int, rng: np.random.Generator):
+    """Per topic (class): a successor of every id, the first-order rule,
+    and a ranking of the vocabulary that its Zipf noise follows; and the
+    noise's cumulative distribution over ranks."""
+    succ = np.stack([rng.permutation(vocab) for _ in range(classes)])
+    rank = np.stack([rng.permutation(vocab) for _ in range(classes)])
+    cdf = np.cumsum(1.0 / np.arange(1, vocab + 1) ** ZIPF)
+    return succ, rank, cdf / cdf[-1]
+
+
+def token_rows(labels: np.ndarray, succ: np.ndarray, rank: np.ndarray,
+               cdf: np.ndarray, seq: int,
+               rng: np.random.Generator) -> np.ndarray:
+    """(rows, seq + 1) int32 ids: each row's first id is its topic's Zipf
+    noise; each next one follows the topic's rule with probability
+    ``FOLLOW``, and is fresh noise otherwise."""
+    n = len(labels)
+    ranks = np.minimum(np.searchsorted(cdf, rng.random((n, seq + 1)),
+                                       side="right"), len(cdf) - 1)
+    noise = rank[labels[:, None], ranks]
+    keep = rng.random((n, seq)) < FOLLOW
+    out = np.empty((n, seq + 1), np.int32)
+    out[:, 0] = noise[:, 0]
+    for t in range(1, seq + 1):
+        out[:, t] = np.where(keep[:, t - 1], succ[labels, out[:, t - 1]],
+                             noise[:, t])
+    return out
+
+
+def row_width(data: dict) -> int:
+    """Features (images) or token ids (tokens) in one row."""
+    return int(data["dim"]) if data["kind"] == "image" else \
+        int(data["seq"]) + 1
+
+
 # ------------------------------------------------------------------- mix
 
 def _labels_by_client(counts: np.ndarray, rng: np.random.Generator):
@@ -113,6 +159,11 @@ def make_traffic(mix: dict, data: dict, seed: int) -> Traffic:
         noise = float(data["noise"])
         train_x = gaussian_image_rows(train_y, means, warp, noise, rng)
         test_x = gaussian_image_rows(test_y, means, warp, noise, rng)
+    elif data["kind"] == "tokens":
+        succ, rank, cdf = topic_model(classes, int(data["vocab"]), rng)
+        seq = int(data["seq"])
+        train_x = token_rows(train_y, succ, rank, cdf, seq, rng)
+        test_x = token_rows(test_y, succ, rank, cdf, seq, rng)
     else:
         raise ValueError(f"unknown data kind {data['kind']!r}")
     indices = [np.arange(i * rows, (i + 1) * rows) for i in range(n)]
