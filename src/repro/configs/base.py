@@ -10,8 +10,9 @@ import dataclasses
 import importlib
 from typing import Optional
 
-__all__ = ["MoEConfig", "SSMConfig", "ModelConfig", "ShapeConfig", "SHAPES",
-           "ARCH_IDS", "get_config", "get_smoke_config", "FamilyLiteral"]
+__all__ = ["MoEConfig", "MLAConfig", "SSMConfig", "ModelConfig",
+           "ShapeConfig", "SHAPES", "ARCH_IDS", "get_config",
+           "get_smoke_config", "FamilyLiteral"]
 
 FamilyLiteral = str  # 'dense' | 'moe' | 'ssm' | 'hybrid' | 'vlm' | 'audio'
 
@@ -30,6 +31,27 @@ class MoEConfig:
     # Capacity-bounded (Switch/GShard) dispatch remains available for
     # experiments by setting dropless=False.
     dropless: bool = True
+    # DeepSeek-V3-style routing ("sigmoid": scores of a sigmoid, the top k
+    # of score plus a selection bias, weights normalised over the k and
+    # scaled by routed_scale).  grouped: the dispatch sorts the routed rows
+    # by expert and runs the grouped product over them (dropless, costing
+    # the routed rows).  experts_held is how many of the num_experts the
+    # router scores this chip holds (0: all), one chip's share under
+    # expert parallelism; it needs grouped (repro.models.moe).
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    grouped: bool = False
+    experts_held: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention without a query LoRA
+    (repro.models.mla)."""
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
 
 
 @dataclasses.dataclass(frozen=True)
@@ -55,6 +77,8 @@ class ModelConfig:
     vocab_size: int
     head_dim: int = 0                   # 0 -> d_model // num_heads
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None
+    first_k_dense: int = 0              # leading layers with a dense MLP
     ssm: Optional[SSMConfig] = None
     sliding_window: Optional[int] = None    # SWA width (tokens)
     local_global_ratio: int = 0         # N local layers per 1 global (gemma3)
@@ -112,9 +136,16 @@ class ModelConfig:
         hd = self.resolved_head_dim
         attn = d * hd * self.num_heads + 2 * d * hd * self.num_kv_heads \
             + hd * self.num_heads * d
+        if self.mla is not None:
+            m, h = self.mla, self.num_heads
+            attn = (d * h * (m.qk_nope_head_dim + m.qk_rope_head_dim)
+                    + d * (m.kv_lora_rank + m.qk_rope_head_dim)
+                    + m.kv_lora_rank * h * (m.qk_nope_head_dim
+                                            + m.v_head_dim)
+                    + h * m.v_head_dim * d)
         if self.moe is not None:
-            ffn = self.moe.num_experts * 3 * d * self.moe.d_ff_expert \
-                + d * self.moe.num_experts
+            ffn = (self.moe.num_experts + self.moe.num_shared_experts) \
+                * 3 * d * self.moe.d_ff_expert + d * self.moe.num_experts
         elif self.d_ff:
             ffn = 3 * d * self.d_ff
         else:
@@ -138,6 +169,8 @@ class ModelConfig:
         else:
             per_layer = attn + ffn
         total = emb + per_layer * self.num_layers
+        if self.first_k_dense:
+            total += self.first_k_dense * (3 * d * self.d_ff - ffn)
         if self.encoder_layers:
             total += self.encoder_layers * (attn + 3 * d * self.d_ff)
         return int(total)
@@ -147,10 +180,11 @@ class ModelConfig:
         if self.moe is None:
             return self.param_count()
         d = self.d_model
-        dense = self.param_count() - self.num_layers * (
+        moe_layers = self.num_layers - self.first_k_dense
+        dense = self.param_count() - moe_layers * (
             self.moe.num_experts * 3 * d * self.moe.d_ff_expert)
-        active_ffn = self.num_layers * (self.moe.top_k
-                                        * 3 * d * self.moe.d_ff_expert)
+        active_ffn = moe_layers * (self.moe.top_k
+                                   * 3 * d * self.moe.d_ff_expert)
         return int(dense + active_ffn)
 
 

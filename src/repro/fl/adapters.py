@@ -9,6 +9,12 @@ None`` — every CNN/MLP sweep) degenerate to the identity view: the exact
 ``model.init``/``model.loss`` objects pass through unwrapped, so full-params
 runs are bit-identical to the pre-adapter code path.
 
+A zoo model (``repro.models.zoo``) gets its adapters from :func:`lora_view`:
+rank-r LoRA factors on named projections, over a frozen base the caller
+hands in.  Its loss is a :class:`FrozenLoss`, which carries the base
+beside the function: the fleet step takes the base as an argument shared
+by every client slot, never as a constant of its compiled program.
+
 On the wire, a hop payload is additionally packed to int8 when
 ``FLConfig.hop_quant == "int8"``: the flattened adapter is cut into
 QUANT_BLOCK-element row-blocks and each block moves as int8 codes plus one
@@ -36,7 +42,8 @@ from repro.kernels.quant import QUANT_BLOCK
 
 Params = Any
 
-__all__ = ["AdapterView", "make_adapter_view", "packed_bits", "pack_rows",
+__all__ = ["AdapterView", "make_adapter_view", "FrozenLoss", "lora_init",
+           "lora_merge", "lora_view", "packed_bits", "pack_rows",
            "unpack_rows", "quant_roundtrip_rows", "quant_roundtrip_tree",
            "quant_roundtrip_slot", "QUANT_BLOCK"]
 
@@ -148,3 +155,102 @@ def make_adapter_view(model, fl_cfg, adapter_hops: bool = True) -> AdapterView:
 
     return AdapterView(init_fn, loss_fn, merge_fn,
                        model_bits(base, fl_cfg.bits_per_param), base)
+
+
+@dataclasses.dataclass(frozen=True, eq=False)
+class FrozenLoss:
+    """A loss over the trained tree that reads a frozen tree:
+    ``fn(params, batch, frozen) -> (loss, counts)``, where ``counts`` maps
+    ``repro.obs`` counter names to int32 device scalars the step computes
+    (the MoE layers' routed rows, ``fl.exec.moe_rows``).  Called as
+    ``loss(params, batch)`` it closes over ``frozen`` and gives the loss;
+    ``FleetExecutor`` instead passes ``frozen`` to its jitted step as an
+    unbatched argument, and adds the counts up on the device."""
+    fn: Callable[[Params, dict, Params], tuple]
+    frozen: Params
+
+    def __call__(self, params: Params, batch: dict) -> jax.Array:
+        return self.fn(params, batch, self.frozen)[0]
+
+
+def _lora_paths(tree, targets: tuple, path=()):
+    """Paths of the ``{"w": ...}`` projections named in ``targets``."""
+    if isinstance(tree, dict):
+        if path and path[-1] in targets and "w" in tree:
+            return [path]
+        items = tree.items()
+    elif isinstance(tree, (list, tuple)):
+        items = enumerate(tree)
+    else:
+        return []
+    return [p for k, v in items for p in _lora_paths(v, targets, path + (k,))]
+
+
+def _nest(paths_leaves: list, like):
+    """The nested dicts/lists of ``like`` that lead to ``paths_leaves``."""
+    if isinstance(like, dict):
+        out = {}
+        for k, v in like.items():
+            sub = [(p[1:], x) for p, x in paths_leaves if p[0] == k]
+            if sub:
+                out[k] = sub[0][1] if sub[0][0] == () else _nest(sub, v)
+        return out
+    return [_nest([(p[1:], x) for p, x in paths_leaves if p[0] == i], v)
+            for i, v in enumerate(like)]
+
+
+def lora_init(base_shapes, key, rank: int, targets: tuple) -> Params:
+    """Rank-``rank`` factors for each projection named in ``targets`` (a
+    ``{"w": (..., d_in, d_out)}``, leading axes stacked layers): ``lora_a``
+    N(0, 1/d_in), ``lora_b`` zero, so the adapted model starts as the
+    base.  The tree holds the paths to those projections alone."""
+    paths = _lora_paths(base_shapes, tuple(targets))
+    out = []
+    for i, path in enumerate(paths):
+        w = base_shapes
+        for k in path:
+            w = w[k]
+        *lead, d_in, d_out = w["w"].shape
+        a = jax.random.normal(jax.random.fold_in(key, i),
+                              (*lead, d_in, rank), jnp.float32)
+        out.append((path, {"lora_a": a / np.sqrt(d_in),
+                           "lora_b": jnp.zeros((*lead, rank, d_out),
+                                               jnp.float32)}))
+    return _nest(out, base_shapes)
+
+
+def lora_merge(base: Params, adapter: Params) -> Params:
+    """The base with each adapted projection's factors beside its ``w``
+    (``models.layers.dense`` applies them)."""
+    if isinstance(adapter, dict):
+        return {k: (lora_merge(v, adapter[k]) if k in adapter else v)
+                for k, v in base.items()} | \
+            {k: v for k, v in adapter.items() if k not in base}
+    if isinstance(adapter, list):
+        return [lora_merge(b, a) for b, a in zip(base, adapter)]
+    return adapter
+
+
+def lora_view(model, base: Params, rank: int, targets: tuple) -> AdapterView:
+    """LoRA adapters over a zoo model's frozen ``base``.  Batches are FL
+    token rows ``{"x": (B, S + 1)}``: tokens ``x[:, :-1]``, labels
+    ``x[:, 1:]``."""
+    shapes = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                          base)
+
+    moe = model.cfg.moe is not None
+
+    def loss(adapter, batch, frozen):
+        tok = batch["x"].astype(jnp.int32)
+        out = model.loss(lora_merge(frozen, adapter),
+                         {"tokens": tok[:, :-1], "labels": tok[:, 1:]},
+                         with_rows=moe)
+        return (out[0], {"fl.exec.moe_rows": out[1]}) if moe else (out, {})
+
+    return AdapterView(
+        init_fn=lambda key: lora_init(shapes, key, rank, targets),
+        loss_fn=FrozenLoss(loss, base),
+        merge_fn=lambda adapter: lora_merge(base, adapter),
+        base_bits=float(sum(8 * a.size * a.dtype.itemsize
+                            for a in jax.tree.leaves(shapes))),
+        base=base)

@@ -78,7 +78,7 @@ from repro.core import aggregation as agg
 from repro.core.schedule import MixOp, PermuteOp, RoundSchedule, TrainOp
 from repro.distributed.fedshard import diffuse_params, masked_stc_compress
 from repro.distributed.sharding import CLIENT_AXIS, FL_AXES, MODEL_AXIS
-from repro.fl.adapters import (pack_rows, quant_roundtrip_rows,
+from repro.fl.adapters import (FrozenLoss, pack_rows, quant_roundtrip_rows,
                                quant_roundtrip_slot, quant_roundtrip_tree,
                                unpack_rows)
 from repro.fl.compression import stc_compress
@@ -203,7 +203,13 @@ class HostExecutor:
 
 
 class FleetExecutor:
-    """Client-stacked execution: one pytree, leading client axis, jitted."""
+    """Client-stacked execution: one pytree, leading client axis, jitted.
+
+    A :class:`~repro.fl.adapters.FrozenLoss` hands its frozen tree to the
+    jitted step as a last, unbatched argument (``in_axes=None``): every
+    slot reads the one copy on the device, and no compiled program holds
+    it as a constant.  Its step also returns the loss's counts, per slot,
+    which a recorded session hands to ``repro.obs`` unread."""
 
     def __init__(self, loss_fn: Callable,
                  client_batches: Sequence[Callable], cfg,
@@ -215,32 +221,44 @@ class FleetExecutor:
         self.prox = cfg.strategy in PROX_STRATEGIES
         opt = opt_lib.sgd(momentum=cfg.momentum)
         mu = float(cfg.prox_mu)
+        if isinstance(loss_fn, FrozenLoss):
+            self._frozen = (loss_fn.frozen,)
+            loss_of = loss_fn.fn
+        else:
+            self._frozen = ()
+            loss_of = loss_fn
+        self._frozen_bytes = sum(x.size * x.dtype.itemsize
+                                 for x in jax.tree.leaves(self._frozen))
 
-        def one(p, mom, batch, active, anchor):
+        def one(p, mom, batch, active, anchor, *frozen):
             def obj(q):
-                loss = loss_fn(q, batch)
+                loss, counts = (loss_of(q, batch, *frozen) if frozen
+                                else (loss_of(q, batch), ()))
                 if self.prox:
                     prox = sum(jnp.sum((a.astype(jnp.float32)
                                         - b.astype(jnp.float32)) ** 2)
                                for a, b in zip(jax.tree.leaves(q),
                                                jax.tree.leaves(anchor)))
                     loss = loss + 0.5 * mu * prox
-                return loss
+                return loss, counts
 
-            loss, grads = jax.value_and_grad(obj)(p)
+            (loss, counts), grads = jax.value_and_grad(obj, has_aux=True)(p)
             if clip is not None:
                 grads, _ = opt_lib.clip_by_global_norm(grads, clip)
             updates, new_state = opt.update(grads, {"mu": mom}, p, cfg.lr)
             p2 = opt_lib.apply_updates(p, updates)
             sel = functools.partial(jnp.where, active)
             return (jax.tree.map(sel, p2, p),
-                    jax.tree.map(sel, new_state["mu"], mom), loss)
+                    jax.tree.map(sel, new_state["mu"], mom), loss) \
+                + ((counts,) if frozen else ())
 
         self._one = one          # per-client step; ShardedFleetExecutor remaps
         # Params and momentum are replaced every step, so the step donates
         # them: the outputs reuse the inputs' device buffers, which is what
         # lets a client-stacked full-width model fit on one chip.
-        self._step = jax.jit(jax.vmap(one), donate_argnums=(0, 1))
+        self._step = jax.jit(
+            jax.vmap(one, in_axes=(0,) * 5 + (None,) * len(self._frozen)),
+            donate_argnums=(0, 1))
 
     # ------------------------------------------------------------------ spans
 
@@ -293,8 +311,16 @@ class FleetExecutor:
         # own, because the fleet step donates the params buffers it reads.
         anchor = jax.tree.map(jnp.copy, params) if self.prox else None
         obs.count("fl.exec.steps", len(steps))
+        if self._frozen_bytes and obs.enabled():
+            # Once an executor: the frozen tree every step reads.
+            obs.count("fl.exec.frozen_bytes", self._frozen_bytes)
+            self._frozen_bytes = 0
         for batch, active in zip(steps, actives):
-            params, mom, _ = self._step(params, mom, batch, active, anchor)
+            params, mom, _, *counts = self._step(params, mom, batch, active,
+                                                 anchor, *self._frozen)
+            if counts and obs.enabled():
+                for name, n in counts[0].items():
+                    obs.count(name, n)
         return params
 
     # ------------------------------------------------- round-state capture
@@ -658,6 +684,9 @@ class ShardedFleetExecutor(FleetExecutor):
         nl, nl_hop = self.nl, self.nl_hop
         nchunks, mb = self.nchunks, self.mb
         D, mbh = self.fused_chunks, self.fused_mb
+        if self._frozen:
+            raise NotImplementedError(
+                "the sharded engine takes no frozen tree; use the fleet")
         vstep = jax.vmap(self._one)
 
         def chunked_session_step(p, mom, batch, active, anchor):

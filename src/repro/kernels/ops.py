@@ -21,6 +21,7 @@ whole system between Pallas and reference bodies.
 """
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
@@ -32,13 +33,16 @@ from repro.kernels.diffusion import (bid_value_fuse_pallas,
                                      mix_aggregate_pallas, stack_ravel,
                                      stack_unravel, stc_rows_pallas)
 from repro.kernels.flash_attention import flash_attention_pallas
+from repro.kernels.moe_gmm import (moe_gmm_pallas, moe_gmm_ref,
+                                   moe_gmm_wgrad)
 from repro.kernels.quant import quant_pack_pallas, quant_unpack_pallas
 from repro.kernels.ssm_scan import ssm_scan_pallas
 from repro.kernels.stc_compress import stc_apply_pallas, stc_reduce_pallas
 
 __all__ = ["flash_attention", "stc_compress", "ssm_scan", "ssd_scan",
            "mix_aggregate", "mix_aggregate_tree", "stc_topk",
-           "dol_bid_scores", "bid_value_fuse", "quant_pack", "quant_unpack"]
+           "dol_bid_scores", "bid_value_fuse", "quant_pack", "quant_unpack",
+           "moe_gmm"]
 
 _IMPLS = ("pallas", "pallas_interpret", "xla", "ref")
 
@@ -229,3 +233,36 @@ def ssd_scan(xh, a, bmat, cmat, *, implementation: str = "auto") -> jax.Array:
         return ssd_scan_ref(xh, a, bmat, cmat)
     interpret = _interpret(impl)
     return ssd_scan_pallas(xh, a, bmat, cmat, interpret=interpret)
+
+
+def moe_gmm(x, w, group_sizes, *, transpose_w: bool = False,
+            implementation: str = "auto") -> jax.Array:
+    """Grouped product over held experts (``kernels/moe_gmm.py``): x (R, K)
+    rows sorted by expert, w (E, K, N) (or (E, N, K) with ``transpose_w``),
+    group_sizes (E,) multiples of ``GMM_ROWS``.  Its VJP is the same
+    product with the weights transposed for ``x``, and ``moe_gmm_wgrad``
+    for ``w`` (dropped unread where ``w`` is frozen)."""
+    return _moe_gmm(x, w, group_sizes, transpose_w, _resolve(implementation))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _moe_gmm(x, w, group_sizes, transpose_w, impl):
+    if impl == "xla":
+        return moe_gmm_ref(x, w, group_sizes, transpose_w=transpose_w)
+    return moe_gmm_pallas(x, w, group_sizes, transpose_w=transpose_w,
+                          interpret=_interpret(impl))
+
+
+def _moe_gmm_fwd(x, w, group_sizes, transpose_w, impl):
+    return (_moe_gmm(x, w, group_sizes, transpose_w, impl),
+            (x, w, group_sizes))
+
+
+def _moe_gmm_bwd(transpose_w, impl, res, dy):
+    x, w, group_sizes = res
+    dw = moe_gmm_wgrad(x, dy, group_sizes, transpose_w=transpose_w)
+    return (_moe_gmm(dy, w, group_sizes, not transpose_w, impl),
+            dw.astype(w.dtype), None)
+
+
+_moe_gmm.defvjp(_moe_gmm_fwd, _moe_gmm_bwd)

@@ -122,12 +122,13 @@ def chunked_attention(q: Array, k: Array, v: Array, spec: AttnSpec,
                       q_offset: int = 0) -> Array:
     """Causal / sliding-window attention over (possibly long) sequences.
 
-    q: (B,Sq,KH,G,Dh), k/v: (B,Sk,KH,Dh).  Returns (B,Sq,KH*G,Dh).
+    q: (B,Sq,KH,G,Dh), k: (B,Sk,KH,Dh), v: (B,Sk,KH,Dv); Dv may differ
+    from Dh (latent attention).  Returns (B,Sq,KH*G,Dv).
     ``q_offset``: absolute position of q[0] within the kv sequence (used by
     cross-shaped prefill; 0 for self-attention where Sq == Sk).
     """
     b, sq, kh, g, dh = q.shape
-    sk = k.shape[1]
+    sk, dv = k.shape[1], v.shape[-1]
     scale = 1.0 / (dh ** 0.5)
     qc = min(spec.q_chunk, sq)
     kc = min(spec.kv_chunk, sk)
@@ -144,7 +145,7 @@ def chunked_attention(q: Array, k: Array, v: Array, spec: AttnSpec,
         q_pos = q_offset + qi * qc + jnp.arange(qc)
         m0 = jnp.full((b, kh, g, qc), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, kh, g, qc), jnp.float32)
-        o0 = jnp.zeros((b, qc, kh, g, dh), jnp.float32)
+        o0 = jnp.zeros((b, qc, kh, g, dv), jnp.float32)
 
         if window is not None:
             # Only the last (window + qc) keys can be visible to this chunk.
@@ -197,12 +198,12 @@ def chunked_attention(q: Array, k: Array, v: Array, spec: AttnSpec,
             (m, l, o), _ = jax.lax.scan(kv_body, (m0, l0, o0),
                                         jnp.arange(nk))
         l_t = jnp.maximum(l.transpose(0, 3, 1, 2)[..., None], 1e-20)
-        return (o / l_t).astype(spec.compute_dtype)   # (B,qc,KH,G,Dh)
+        return (o / l_t).astype(spec.compute_dtype)   # (B,qc,KH,G,Dv)
 
-    out = jax.lax.map(per_q_chunk, jnp.arange(nq))    # (nq,B,qc,KH,G,Dh)
-    out = jnp.moveaxis(out, 0, 1).reshape(b, nq * qc, kh, g, dh)
+    out = jax.lax.map(per_q_chunk, jnp.arange(nq))    # (nq,B,qc,KH,G,Dv)
+    out = jnp.moveaxis(out, 0, 1).reshape(b, nq * qc, kh, g, dv)
     out = out[:, :sq]
-    return out.reshape(b, sq, kh * g, dh)
+    return out.reshape(b, sq, kh * g, dv)
 
 
 def attn_forward(p: Params, spec: AttnSpec, x: Array,

@@ -60,8 +60,15 @@ def init_dense(key, d_in: int, d_out: int, dtype=jnp.float32,
 
 
 def dense(p: Params, x: Array, compute_dtype=jnp.bfloat16) -> Array:
-    w = p["w"].astype(compute_dtype)
-    return jnp.einsum("...i,io->...o", x.astype(compute_dtype), w)
+    """``x @ w``, plus the low-rank term ``(x @ lora_a) @ lora_b`` where the
+    projection carries LoRA factors (``repro.fl.adapters.lora_merge``)."""
+    x = x.astype(compute_dtype)
+    y = jnp.einsum("...i,io->...o", x, p["w"].astype(compute_dtype))
+    if "lora_a" in p:
+        low = jnp.einsum("...i,ir->...r", x, p["lora_a"].astype(compute_dtype))
+        y = y + jnp.einsum("...r,ro->...o", low,
+                           p["lora_b"].astype(compute_dtype))
+    return y
 
 
 # ---------------------------------------------------------------- norms
@@ -175,10 +182,13 @@ def swiglu(p: Params, x: Array, compute_dtype=jnp.bfloat16) -> Array:
 def chunked_cross_entropy(emb_or_head: Params, hidden: Array, labels: Array,
                           *, tie: bool, chunk: int = 512,
                           compute_dtype=jnp.bfloat16,
-                          mask: Array | None = None) -> Array:
+                          mask: Array | None = None,
+                          with_accuracy: bool = False):
     """Mean next-token cross-entropy without materializing (B, S, V) logits.
 
-    ``hidden``: (B, S, D); ``labels``: (B, S) int32.
+    ``hidden``: (B, S, D); ``labels``: (B, S) int32.  ``with_accuracy``
+    returns ``(loss, accuracy)``, the accuracy the share of tokens whose
+    gold logit is the largest.
 
     §Perf P1: the scan runs over SEQUENCE chunks with the batch dimension
     intact.  Flattening (B·S) into the scan axis — the obvious layout —
@@ -245,12 +255,19 @@ def chunked_cross_entropy(emb_or_head: Params, hidden: Array, labels: Array,
                                      0.0), axis=-1)
         else:
             gold = jnp.take_along_axis(logits, y[..., None], axis=-1)[..., 0]
-        loss_sum, cnt = carry
+        loss_sum, cnt, hits = carry
+        if with_accuracy:
+            hits = hits + jnp.sum(
+                (gold >= jnp.max(logits, axis=-1)) * msk)
         return (loss_sum + jnp.sum((logz - gold) * msk),
-                cnt + jnp.sum(msk)), None
+                cnt + jnp.sum(msk), hits), None
 
-    (loss_sum, cnt), _ = jax.lax.scan(body, (0.0, 0.0), (hs, ys, ms))
-    return loss_sum / jnp.maximum(cnt, 1.0)
+    (loss_sum, cnt, hits), _ = jax.lax.scan(body, (0.0, 0.0, 0.0),
+                                            (hs, ys, ms))
+    loss = loss_sum / jnp.maximum(cnt, 1.0)
+    if with_accuracy:
+        return loss, hits / jnp.maximum(cnt, 1.0)
+    return loss
 
 
 def count_params(params) -> int:

@@ -8,7 +8,12 @@ exactly (e.g. gemma3's 5 local + 1 global per scan body; zamba2's 6 mamba2
 blocks + 1 *shared* attention block whose parameters are not scanned).
 
 Layer kinds:
-  ``attn``    full-causal GQA attention + MLP (SwiGLU or MoE)
+  ``attn``    full-causal attention + MLP (SwiGLU or MoE)
+  ``dense``   full-causal attention + SwiGLU MLP: the leading
+              ``first_k_dense`` layers of a MoE model, their own segment
+
+Attention is GQA, or latent attention (``repro.models.mla``) where the
+config has ``mla``.
   ``swa``     sliding-window GQA attention + MLP
   ``mamba1``  Mamba-1 selective-scan block (no MLP, as in the original arch)
   ``mamba2``  Mamba-2 SSD block
@@ -25,6 +30,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import layers as L
+from repro.models import mla as mla_lib
 from repro.models import moe as moe_lib
 from repro.models import ssm as ssm_lib
 from repro.models.attention import (AttnSpec, attn_decode, attn_forward,
@@ -63,7 +69,8 @@ def build_plan(cfg: ModelConfig) -> list[Segment]:
             plan.append((("swa",) * rem, 1))
         return plan
     kind = "swa" if cfg.sliding_window else "attn"
-    return [((kind,), n)]
+    k = cfg.first_k_dense
+    return ([(("dense",), k)] if k else []) + [((kind,), n - k)]
 
 
 def specs_for(cfg: ModelConfig):
@@ -79,6 +86,15 @@ def specs_for(cfg: ModelConfig):
         q_chunk=qc, kv_chunk=kvc,
         norm_eps=cfg.norm_eps, compute_dtype=cd)
     swa = dataclasses.replace(attn, window=cfg.sliding_window or 4096)
+    if cfg.mla is not None:
+        attn = mla_lib.MLASpec(
+            d_model=cfg.d_model, num_heads=cfg.num_heads,
+            kv_lora_rank=cfg.mla.kv_lora_rank,
+            qk_nope_head_dim=cfg.mla.qk_nope_head_dim,
+            qk_rope_head_dim=cfg.mla.qk_rope_head_dim,
+            v_head_dim=cfg.mla.v_head_dim, rope_theta=cfg.rope_theta,
+            norm_eps=cfg.norm_eps, q_chunk=qc, kv_chunk=kvc,
+            compute_dtype=cd)
     moe = None
     if cfg.moe is not None:
         moe = moe_lib.MoESpec(
@@ -87,7 +103,9 @@ def specs_for(cfg: ModelConfig):
             capacity_factor=cfg.moe.capacity_factor,
             router_aux_coef=cfg.moe.router_aux_coef,
             num_shared_experts=cfg.moe.num_shared_experts,
-            dropless=cfg.moe.dropless, compute_dtype=cd)
+            dropless=cfg.moe.dropless, scoring=cfg.moe.scoring,
+            routed_scale=cfg.moe.routed_scale, grouped=cfg.moe.grouped,
+            experts_held=cfg.moe.experts_held, compute_dtype=cd)
     m1 = m2 = None
     if cfg.ssm is not None:
         # §Perf P2b: larger scan chunks cut per-iteration boundary traffic
@@ -111,15 +129,27 @@ def specs_for(cfg: ModelConfig):
 
 # ------------------------------------------------------------------ init
 
+def _init_attn(key, spec) -> Params:
+    if isinstance(spec, mla_lib.MLASpec):
+        return mla_lib.init_mla(key, spec)
+    return init_attention(key, spec)
+
+
+def _attend(p: Params, spec, x: Array, positions: Array | None) -> Array:
+    if isinstance(spec, mla_lib.MLASpec):
+        return mla_lib.mla_forward(p, spec, x, positions)
+    return attn_forward(p, spec, x, positions)
+
+
 def _init_layer(key, kind: str, cfg: ModelConfig) -> Params:
     attn, swa, moe, m1, m2 = specs_for(cfg)
     k1, k2 = jax.random.split(key)
-    if kind in ("attn", "swa", "shared"):
+    if kind in ("attn", "swa", "shared", "dense"):
         spec = swa if kind == "swa" else attn
         p = {"ln1": L.init_rmsnorm(cfg.d_model),
-             "attn": init_attention(k1, spec),
+             "attn": _init_attn(k1, spec),
              "ln2": L.init_rmsnorm(cfg.d_model)}
-        if cfg.moe is not None and kind != "shared":
+        if cfg.moe is not None and kind not in ("shared", "dense"):
             p["moe"] = moe_lib.init_moe(k2, moe)
         else:
             d_ff = cfg.d_ff or 4 * cfg.d_model
@@ -164,17 +194,19 @@ def init_lm(key, cfg: ModelConfig) -> Params:
 # ------------------------------------------------------------------ forward
 
 def _apply_layer(p: Params, kind: str, cfg: ModelConfig, x: Array,
-                 positions: Array | None, aux: Array) -> tuple[Array, Array]:
+                 positions: Array | None, aux: tuple) -> tuple[Array, tuple]:
+    """One layer; ``aux`` is (auxiliary loss, routed expert rows), summed
+    over the layers so far."""
     attn, swa, moe, m1, m2 = specs_for(cfg)
-    if kind in ("attn", "swa", "shared"):
+    if kind in ("attn", "swa", "shared", "dense"):
         spec = swa if kind == "swa" else attn
-        x = x + attn_forward(p["attn"], spec, L.rmsnorm(p["ln1"], x,
-                                                        cfg.norm_eps),
-                             positions)
+        x = x + _attend(p["attn"], spec, L.rmsnorm(p["ln1"], x,
+                                                   cfg.norm_eps),
+                        positions)
         h = L.rmsnorm(p["ln2"], x, cfg.norm_eps)
         if "moe" in p:
-            y, a = moe_lib.moe_forward(p["moe"], moe, h)
-            aux = aux + a
+            y, a, n = moe_lib.moe_forward(p["moe"], moe, h)
+            aux = (aux[0] + a, aux[1] + n)
         else:
             y = L.swiglu(p["mlp"], h, spec.compute_dtype)
         return x + y, aux
@@ -191,10 +223,11 @@ def _apply_layer(p: Params, kind: str, cfg: ModelConfig, x: Array,
 
 def forward_hidden(params: Params, cfg: ModelConfig, x: Array,
                    positions: Array | None = None, *, remat: bool = True
-                   ) -> tuple[Array, Array]:
-    """Embedded inputs (B,S,D) -> final hidden (B,S,D), aux loss."""
+                   ) -> tuple[Array, tuple]:
+    """Embedded inputs (B,S,D) -> final hidden (B,S,D), (aux loss, routed
+    (token, expert) pairs the MoE layers computed, int32)."""
     plan = build_plan(cfg)
-    aux0 = jnp.zeros((), jnp.float32)
+    aux0 = (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32))
 
     def seg_scan(x, aux, seg_p, kinds):
         def body(carry, layer_p):
@@ -232,20 +265,28 @@ def _embed_inputs(params: Params, cfg: ModelConfig, batch: dict) -> Array:
 
 
 def lm_loss(params: Params, cfg: ModelConfig, batch: dict, *,
-            remat: bool = True) -> Array:
+            remat: bool = True, with_accuracy: bool = False,
+            with_rows: bool = False):
     """Next-token CE loss.  batch: tokens (B,S), labels (B,S) [, mask,
-    patch_embeddings]."""
+    patch_embeddings].  ``with_accuracy`` appends the next-token accuracy,
+    ``with_rows`` the routed (token, expert) pairs the MoE layers
+    computed: ``(loss, accuracy, rows)`` with both."""
     x = _embed_inputs(params, cfg, batch)
     b, s, _ = x.shape
     positions = jnp.broadcast_to(jnp.arange(s)[None], (b, s))
-    hidden, aux = forward_hidden(params, cfg, x, positions, remat=remat)
+    hidden, (aux, rows) = forward_hidden(params, cfg, x, positions,
+                                         remat=remat)
     n_text = batch["tokens"].shape[1]
     hidden = hidden[:, -n_text:]    # VLM: loss only over text positions
     head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
     ce = L.chunked_cross_entropy(head, hidden, batch["labels"],
                                  tie=cfg.tie_embeddings,
-                                 mask=batch.get("mask"))
-    return ce + aux
+                                 compute_dtype=jnp.dtype(cfg.compute_dtype),
+                                 mask=batch.get("mask"),
+                                 with_accuracy=with_accuracy)
+    out = ((ce[0] + aux, ce[1]) if with_accuracy else (ce + aux,)) \
+        + ((rows,) if with_rows else ())
+    return out if len(out) > 1 else out[0]
 
 
 # ------------------------------------------------------------------ decode
@@ -258,7 +299,10 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int) -> Params:
         seg: Params = {}
         for pi, kind in enumerate(kinds):
             name = f"{pi}_{kind}"
-            if kind in ("attn", "swa"):
+            if isinstance(attn, mla_lib.MLASpec) and kind in ("attn",
+                                                             "dense"):
+                one = mla_lib.init_mla_cache(attn, batch, max_seq)
+            elif kind in ("attn", "swa", "dense"):
                 spec = swa if kind == "swa" else attn
                 # A sliding-window layer only ever reads the last `window`
                 # entries — allocate a ring of that size, rounded up to a
@@ -304,20 +348,25 @@ def decode_step(params: Params, cfg: ModelConfig, tokens: Array,
             new_c = {}
             for pi, kind in enumerate(kinds):
                 name = f"{pi}_{kind}"
-                if kind in ("attn", "swa", "shared"):
+                if kind in ("attn", "swa", "shared", "dense"):
                     spec = swa if kind == "swa" else attn
                     p = (params["shared_block"] if kind == "shared"
                          else layer_p[name])
                     c = layer_c[name]
-                    # SWA caches are rings of length min(window+1, max_seq);
-                    # the ring math degenerates to linear while pos < length.
-                    y, c2 = attn_decode(p["attn"], spec,
-                                        L.rmsnorm(p["ln1"], h, cfg.norm_eps),
-                                        c, pos, ring=(kind == "swa"))
+                    hn = L.rmsnorm(p["ln1"], h, cfg.norm_eps)
+                    if isinstance(spec, mla_lib.MLASpec):
+                        y, c2 = mla_lib.mla_decode(p["attn"], spec, hn, c,
+                                                   pos)
+                    else:
+                        # SWA caches are rings of length min(window+1,
+                        # max_seq); the ring math degenerates to linear
+                        # while pos < length.
+                        y, c2 = attn_decode(p["attn"], spec, hn, c, pos,
+                                            ring=(kind == "swa"))
                     h = h + y
                     hh = L.rmsnorm(p["ln2"], h, cfg.norm_eps)
                     if "moe" in p:
-                        y2, _ = moe_lib.moe_forward(p["moe"], moe, hh)
+                        y2, _, _ = moe_lib.moe_forward(p["moe"], moe, hh)
                     else:
                         y2 = L.swiglu(p["mlp"], hh, cd)
                     h = h + y2

@@ -37,6 +37,7 @@ from collections import defaultdict
 from typing import NamedTuple
 
 import jax
+import numpy as np
 
 __all__ = ["Span", "Record", "span", "timed", "count", "enabled", "fl_round",
            "recording", "snapshot"]
@@ -84,6 +85,7 @@ class _Recorder:
         self.stack: list = []       # open spans, innermost last
         self.spans: list = []
         self.counters: dict = defaultdict(int)
+        self.device: dict = defaultdict(list)   # device arrays, unread
         self.compiles: dict = defaultdict(int)
         self.listening = False
 
@@ -170,8 +172,14 @@ def timed(name: str) -> _Span:
 
 
 def count(name: str, n: int = 1) -> None:
+    """Add ``n`` to counter ``name``.  ``n`` may be a device array, whose
+    sum counts: it is kept as it is (no device work, no compile, no wait)
+    and read when the record is taken."""
     if _R.on:
-        _R.counters[name] += n
+        if isinstance(n, jax.Array):
+            _R.device[name].append(n)
+        else:
+            _R.counters[name] += n
 
 
 def enabled() -> bool:
@@ -218,10 +226,15 @@ def recording():
 
 def snapshot() -> Record:
     """Everything recorded so far; the recorder starts empty again."""
-    rec = Record(spans=_R.spans, counters=dict(_R.counters),
+    counters = dict(_R.counters)
+    for k, arrays in _R.device.items():
+        counters[k] = counters.get(k, 0) + sum(
+            int(np.sum(a)) for a in jax.device_get(arrays))
+    rec = Record(spans=_R.spans, counters=counters,
                  compiles=dict(_R.compiles))
     _R.spans = []
     _R.counters = defaultdict(int)
+    _R.device = defaultdict(list)
     _R.compiles = defaultdict(int)
     return rec
 

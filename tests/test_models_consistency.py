@@ -15,7 +15,8 @@ from repro.models.attention import AttnSpec, chunked_attention
 from repro.models.moe import MoESpec, init_moe, moe_forward
 
 ARCHS = ["smollm_360m", "qwen3_0_6b", "gemma3_4b", "mixtral_8x22b",
-         "falcon_mamba_7b", "zamba2_2_7b", "qwen3_moe_235b_a22b"]
+         "falcon_mamba_7b", "zamba2_2_7b", "qwen3_moe_235b_a22b",
+         "moonshot_v1_16b_a3b"]
 
 
 @pytest.mark.parametrize("arch", ARCHS)
@@ -121,7 +122,7 @@ def test_moe_routing_conservation():
                    capacity_factor=2.0, compute_dtype=jnp.float32)
     p = init_moe(key, spec)
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 8, 16), jnp.float32)
-    out, aux = moe_forward(p, spec, x)
+    out, aux, _ = moe_forward(p, spec, x)
     assert out.shape == x.shape
     assert jnp.isfinite(out).all() and float(aux) > 0
     # reference dense computation of the same routing
@@ -151,7 +152,97 @@ def test_moe_capacity_drops_overflow():
                    capacity_factor=0.5, compute_dtype=jnp.float32)
     p = init_moe(key, spec)
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 16, 8), jnp.float32)
-    out, _ = moe_forward(p, spec, x)
+    out, _, rows = moe_forward(p, spec, x)
     # some tokens must be dropped (zero contribution) at cf=0.5
     norms = jnp.linalg.norm(out.reshape(16, 8), axis=-1)
     assert int(jnp.sum(norms == 0.0)) >= 1
+    # ... and the layer counts only the pairs it kept.
+    assert int(rows) == 16 - int(jnp.sum(norms == 0.0))
+
+
+def _moe_pair(scoring, **kw):
+    """A grouped-dispatch MoE layer and the same layer over dropless
+    buffers (plain JAX, differentiated by JAX itself), float32."""
+    spec = MoESpec(d_model=16, num_experts=4, top_k=2, d_ff_expert=32,
+                   num_shared_experts=1, scoring=scoring, routed_scale=1.7,
+                   dropless=True, compute_dtype=jnp.float32, **kw)
+    p = init_moe(jax.random.PRNGKey(2), spec)
+    if scoring == "sigmoid":
+        p["router_bias"] = 0.3 * jax.random.normal(jax.random.PRNGKey(3),
+                                                   (4,))
+    return p, spec, dataclasses.replace(spec, grouped=True)
+
+
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+def test_grouped_dispatch_matches_dropless_buffers(scoring):
+    """Forward, routed rows and every gradient (input, router and expert
+    weights) of the grouped dispatch equal the buffers', also per slot
+    under ``vmap`` with the weights shared (the folded dispatch)."""
+    p, buffers, grouped = _moe_pair(scoring)
+    x = jax.random.normal(jax.random.PRNGKey(4), (3, 2, 8, 16))
+    ct = jax.random.normal(jax.random.PRNGKey(5), (3, 2, 8, 16))
+
+    def loss(spec):
+        def f(p, x, ct):
+            out, aux, _ = moe_forward(p, spec, x)
+            return jnp.sum(out * ct) + aux
+        return f
+
+    with jax.default_matmul_precision("highest"):
+        for a, b in zip(moe_forward(p, grouped, x[0]),
+                        moe_forward(p, buffers, x[0])):
+            np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+        assert int(moe_forward(p, grouped, x[0])[2]) == 2 * 8 * 2
+        for argnums in (1, (0, 1)):
+            got = jax.vmap(jax.grad(loss(grouped), argnums),
+                           (None, 0, 0))(p, x, ct)
+            want = jax.vmap(jax.grad(loss(buffers), argnums),
+                            (None, 0, 0))(p, x, ct)
+            for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                                    jax.tree.leaves(want)):
+                np.testing.assert_allclose(
+                    a, b, rtol=1e-4, atol=1e-5,
+                    err_msg=jax.tree_util.keystr(path))
+        for k in ("w_gate", "w_up", "w_down"):
+            assert float(jnp.max(jnp.abs(got[0][k]))) > 0
+
+
+def test_moe_model_trains_its_experts():
+    """The smoke Moonlight (sigmoid router, grouped dispatch over a held
+    share) gives its expert weights the gradient JAX gives the same model
+    over dropless buffers holding every expert, restricted to the share."""
+    cfg = dataclasses.replace(get_smoke_config("moonshot_v1_16b_a3b"),
+                              compute_dtype="float32")
+    held = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, experts_held=2))
+    full = dataclasses.replace(cfg, moe=dataclasses.replace(
+        cfg.moe, grouped=False))
+    params = build_model(full).init(jax.random.PRNGKey(6))
+    toks = jax.random.randint(jax.random.PRNGKey(7), (2, 12), 0,
+                              cfg.vocab_size)
+    batch = {"tokens": toks, "labels": jnp.roll(toks, -1, 1)}
+    moe = params["segments"][1]["0_attn"]["moe"]
+    # Experts 2 and 3 never contribute: their down projections are zero.
+    moe["w_down"] = moe["w_down"].at[:, 2:].set(0.0)
+    share = jax.tree.map(lambda a: a, params)
+    for k in ("w_gate", "w_up", "w_down"):
+        share["segments"][1]["0_attn"]["moe"][k] = moe[k][:, :2]
+    with jax.default_matmul_precision("highest"):
+        (l1, r1), g1 = jax.value_and_grad(
+            lambda q: tf.lm_loss(q, held, batch, with_rows=True),
+            has_aux=True)(share)
+        (l0, r0), g0 = jax.value_and_grad(
+            lambda q: tf.lm_loss(q, full, batch, with_rows=True),
+            has_aux=True)(params)
+    np.testing.assert_allclose(l1, l0, rtol=1e-5)
+    assert int(r0) == 2 * 12 * cfg.moe.top_k      # one MoE layer, all held
+    assert 0 < int(r1) < int(r0)
+    m1 = g1["segments"][1]["0_attn"]["moe"]
+    m0 = g0["segments"][1]["0_attn"]["moe"]
+    for k in ("w_gate", "w_up", "w_down"):
+        assert float(jnp.max(jnp.abs(m1[k]))) > 0
+        np.testing.assert_allclose(m1[k], m0[k][:, :2], rtol=1e-4,
+                                   atol=1e-6)
+    for k in ("router", "shared"):
+        for a, b in zip(jax.tree.leaves(m1[k]), jax.tree.leaves(m0[k])):
+            np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-6)

@@ -79,7 +79,7 @@ def test_full_configs_match_assignment(arch):
     cfg = get_config(arch)
     expected = {
         "qwen3_moe_235b_a22b": (94, 4096, 64, 4, 151936),
-        "moonshot_v1_16b_a3b": (48, 2048, 16, 16, 163840),
+        "moonshot_v1_16b_a3b": (27, 2048, 16, 16, 163840),
         "gemma3_4b": (34, 2560, 8, 4, 262144),
         "mixtral_8x22b": (56, 6144, 48, 8, 32768),
         "smollm_360m": (32, 960, 15, 5, 49152),
@@ -98,6 +98,9 @@ def test_full_configs_match_assignment(arch):
     if arch == "moonshot_v1_16b_a3b":
         assert (cfg.moe.num_experts, cfg.moe.top_k,
                 cfg.moe.d_ff_expert) == (64, 6, 1408)
+        assert (cfg.first_k_dense, cfg.d_ff, cfg.moe.scoring,
+                cfg.moe.num_shared_experts) == (1, 11264, "sigmoid", 2)
+        assert cfg.mla.kv_lora_rank == 512
     if arch == "mixtral_8x22b":
         assert (cfg.moe.num_experts, cfg.moe.top_k,
                 cfg.moe.d_ff_expert) == (8, 2, 16384)

@@ -10,7 +10,10 @@ not attached, and check that the Pallas kernel survives into the program
 Widths: the paper's FMNIST-shaped CNN fleet (C=20, F=206,874), a C=2048
 fleet of the LoRA LM task's adapters (F=3,584), the planner's bid matrices
 for both fleets, and the full smollm_360m parameter stack of two clients
-(the trainer's fleet: F=361,821,120, 1,413,364 int8 row-blocks).
+(the trainer's fleet: F=361,821,120, 1,413,364 int8 row-blocks); and the
+grouped expert product of Moonlight-16B-A3B's MoE layer (8 held experts,
+hidden 2,048, expert width 1,408) over a 16-slot fleet of 1,024-token rows,
+and its weight gradient.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and every test worker
@@ -25,6 +28,7 @@ import pytest
 from repro.kernels.diffusion import (bid_value_fuse_pallas,
                                      dol_bid_scores_pallas,
                                      mix_aggregate_pallas, stc_rows_pallas)
+from repro.kernels.moe_gmm import GMM_ROWS, moe_gmm_pallas, moe_gmm_wgrad
 from repro.kernels.quant import (QUANT_BLOCK, quant_pack_pallas,
                                  quant_unpack_pallas)
 
@@ -121,3 +125,35 @@ def test_quant_pack_compiles(one_chip, r):
 def test_quant_unpack_compiles(one_chip, r):
     _compile(quant_unpack_pallas, one_chip, ((r, QUANT_BLOCK), I8),
              ((r,), F32))
+
+
+# The held experts' row buffer of moonlight_feddif_c16: 16 slots of 1,024
+# tokens, each token's 6 choices, plus one padding tile an expert.
+MOE_ROWS, MOE_HELD = 16 * 1024 * 6 + 8 * GMM_ROWS, 8
+
+
+@pytest.mark.parametrize("k,n,transpose_w", [
+    (2048, 2 * 1408, False), (1408, 2048, False),        # forward
+    (2048, 1408, True), (2 * 1408, 2048, True)],         # input gradients
+    ids=["gate_up", "down", "down_t", "gate_up_t"])
+def test_moe_gmm_compiles(one_chip, k, n, transpose_w):
+    w = (MOE_HELD, n, k) if transpose_w else (MOE_HELD, k, n)
+    _compile(lambda x, w, g: moe_gmm_pallas(x, w, g,
+                                            transpose_w=transpose_w),
+             one_chip, ((MOE_ROWS, k), jnp.bfloat16), (w, jnp.bfloat16),
+             ((MOE_HELD,), jnp.int32))
+
+
+@pytest.mark.parametrize("k,n,transpose_w", [
+    (2048, 2 * 1408, False), (2 * 1408, 2048, True)],
+    ids=["gate_up", "gate_up_t"])
+def test_moe_gmm_weight_gradient_compiles(one_chip, k, n, transpose_w):
+    """The experts' weight gradient (XLA's ragged product, for experts in
+    training) compiles for the chip at the same widths."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+        ((MOE_ROWS, k), jnp.bfloat16), ((MOE_ROWS, n), jnp.bfloat16),
+        ((MOE_HELD,), jnp.int32))]
+    out = jax.jit(lambda x, dy, g: moe_gmm_wgrad(
+        x, dy, g, transpose_w=transpose_w)).lower(*args).compile()
+    want = (MOE_HELD, n, k) if transpose_w else (MOE_HELD, k, n)
+    assert out.out_info.shape == want
