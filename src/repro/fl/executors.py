@@ -18,7 +18,9 @@ Two data planes consume the same schedule object:
   are padded and masked out per step, so the math per client matches the
   host loop; the win is dispatch count — O(max-epoch) jitted calls per op
   instead of O(Σ client batches) — which is what lets sweeps scale past
-  paper-sized fleets.
+  paper-sized fleets.  A session that trains few slots runs on a compact
+  stack of them, at the smallest quarter of the fleet that holds them
+  (:func:`compact_slots`); every such width compiles in the first session.
 
 * :class:`ShardedFleetExecutor` — the large-N plane, on the 2-D
   ``("clients", "model")`` mesh of :func:`repro.launch.mesh.make_fl_mesh`.
@@ -93,6 +95,48 @@ __all__ = ["HostExecutor", "FleetExecutor", "ShardedFleetExecutor",
            "make_executor", "EXECUTORS"]
 
 EXECUTORS = ("host", "fleet", "sharded")
+
+
+def session_widths(num_slots: int) -> tuple[int, ...]:
+    """The widths a fleet session over ``num_slots`` slots runs at: the
+    quarters of ``num_slots``, rounded up (4, 8, 12, 16 for 16 slots)."""
+    return tuple(sorted({-(-j * num_slots // 4) for j in range(1, 5)}))
+
+
+def compact_slots(mask: np.ndarray) -> np.ndarray:
+    """The slots a session of ``mask`` computes: its training slots in slot
+    order, then the first idle slots, up to the smallest of
+    :func:`session_widths` that holds the training ones.  The idle slots
+    are distinct and masked out, so they return their rows unchanged."""
+    mask = np.asarray(mask, dtype=bool)
+    on = np.flatnonzero(mask)
+    width = next(b for b in session_widths(len(mask)) if b >= len(on))
+    return np.concatenate([on, np.flatnonzero(~mask)[:width - len(on)]]
+                          ).astype(np.int32)
+
+
+def _replayed(fn: Callable) -> Callable:
+    """``fn`` traced once per signature of its arguments, then replayed
+    from that jaxpr.  A caller that traces it again, as the fleet step's
+    ``vmap`` does at each session width, batches the jaxpr instead of
+    running ``fn``'s Python (a large model's loss and its gradient) anew."""
+    jaxprs: dict = {}
+
+    @functools.wraps(fn)
+    def replay(*args):
+        leaves, tree = jax.tree.flatten(args)
+        avals = tuple(jax.typeof(x) for x in leaves)
+        if (tree, avals) not in jaxprs:
+            abstract = [jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                             weak_type=a.weak_type)
+                        for a in avals]
+            jaxprs[tree, avals] = jax.make_jaxpr(fn, return_shape=True)(
+                *jax.tree.unflatten(tree, abstract))
+        closed, shape = jaxprs[tree, avals]
+        return jax.tree.unflatten(
+            jax.tree.structure(shape),
+            jax.core.eval_jaxpr(closed.jaxpr, closed.consts, *leaves))
+    return replay
 
 
 def _tree_sub(a, b):
@@ -255,10 +299,31 @@ class FleetExecutor:
         self._one = one          # per-client step; ShardedFleetExecutor remaps
         # Params and momentum are replaced every step, so the step donates
         # them: the outputs reuse the inputs' device buffers, which is what
-        # lets a client-stacked full-width model fit on one chip.
-        self._step = jax.jit(
-            jax.vmap(one, in_axes=(0,) * 5 + (None,) * len(self._frozen)),
+        # lets a client-stacked full-width model fit on one chip.  Every
+        # width of it replays one trace of ``one``.
+        self._vstep = jax.jit(
+            jax.vmap(_replayed(one),
+                     in_axes=(0,) * 5 + (None,) * len(self._frozen)),
             donate_argnums=(0, 1))
+        self._step = self._vstep
+
+        def take(stack, idx):
+            # A compact session's rows, its zero momentum and, under prox,
+            # its anchor: a buffer of its own, as the step donates the rows.
+            rows = jax.tree.map(lambda x: x[idx], stack)
+            mom = jax.tree.map(lambda r: jnp.zeros_like(r, jnp.float32),
+                               rows)
+            return rows, mom, (rows if self.prox else None)
+
+        def put(stack, idx, rows):
+            return jax.tree.map(
+                lambda s, r: s.at[idx].set(r, unique_indices=True),
+                stack, rows)
+
+        self._take = jax.jit(take)
+        self._put = jax.jit(put, donate_argnums=0)
+        # (fleet size, batch signature) -> {width: (take, step, put)}
+        self._compact: dict = {}
 
     # ------------------------------------------------------------------ spans
 
@@ -271,12 +336,15 @@ class FleetExecutor:
 
     # ---------------------------------------------------------------- batches
 
-    def _draw_session(self, mask: np.ndarray):
+    def _draw_session(self, mask: np.ndarray,
+                      slots: np.ndarray | None = None):
         """Draw one local epoch per *masked* slot (preserving each client's
-        host-side batch stream), pad to the longest epoch, stack per step.
+        host-side batch stream), pad to the longest epoch, stack per step
+        the rows of ``slots`` (every slot by default).
 
-        Returns ``(steps, actives)``: per padded step, a client-stacked batch
-        dict and the (C,) bool mask of slots genuinely training that step.
+        Returns ``(steps, actives)``: per padded step, a slot-stacked batch
+        dict and the bool mask of the stacked slots genuinely training that
+        step.
         """
         with obs.span("fl.exec.draw"):
             per_slot = [list(self.client_batches[c]()) if mask[c] else []
@@ -286,15 +354,18 @@ class FleetExecutor:
                 return [], []
             template = jax.tree.map(
                 np.zeros_like, next(b[0] for b in per_slot if b))
+            stacked = (per_slot if slots is None
+                       else [per_slot[c] for c in slots])
             steps, actives = [], []
             for k in range(nb):
-                rows = [b[k] if k < len(b) else template for b in per_slot]
+                rows = [b[k] if k < len(b) else template for b in stacked]
                 steps.append(jax.tree.map(
                     lambda *xs: jnp.asarray(np.stack(xs)), *rows))
                 actives.append(jnp.asarray(
-                    np.array([k < len(b) for b in per_slot])))
+                    np.array([k < len(b) for b in stacked])))
             if obs.enabled():
                 obs.count("fl.exec.slot_steps", len(mask) * nb)
+                obs.count("fl.exec.computed_slot_steps", len(stacked) * nb)
                 obs.count("fl.exec.active_slot_steps",
                           sum(len(b) for b in per_slot))
                 obs.count("fl.exec.h2d_bytes", sum(
@@ -302,26 +373,77 @@ class FleetExecutor:
         return steps, actives
 
     def _session(self, params: Params, mask: np.ndarray) -> Params:
-        """One local-update session at every masked slot (vmapped epoch)."""
+        """One local-update session at every masked slot (vmapped epoch),
+        computed on a compact stack of :func:`compact_slots` where that is
+        narrower than the fleet: gathered once, stepped, scattered back."""
         if not mask.any():
             return params
-        steps, actives = self._draw_session(mask)
+        idx = compact_slots(mask)
+        full = len(idx) == len(mask)
+        steps, actives = self._draw_session(mask, None if full else idx)
+        if not steps:
+            return params
+        programs = self._compact_programs(params, steps[0])
+        obs.count("fl.exec.compacted_sessions", int(not full))
+        if full:
+            return self._full_session(params, steps, actives)
+        take, step, put = programs[len(idx)]
+        index = jnp.asarray(idx)
+        rows, mom, anchor = take(params, index)
+        rows = self._run_steps(step, rows, mom, anchor, steps, actives)
+        return put(params, index, rows)
+
+    def _full_session(self, params: Params, steps: list,
+                      actives: list) -> Params:
+        """A drawn session over every slot of ``params``, through
+        ``_step``."""
         mom = jax.tree.map(lambda p: jnp.zeros_like(p, jnp.float32), params)
         # prox anchor = the received model (host default); a copy of its
         # own, because the fleet step donates the params buffers it reads.
         anchor = jax.tree.map(jnp.copy, params) if self.prox else None
+        return self._run_steps(self._step, params, mom, anchor, steps,
+                               actives)
+
+    def _run_steps(self, step, params: Params, mom: Params, anchor,
+                   steps: list, actives: list) -> Params:
         obs.count("fl.exec.steps", len(steps))
         if self._frozen_bytes and obs.enabled():
             # Once an executor: the frozen tree every step reads.
             obs.count("fl.exec.frozen_bytes", self._frozen_bytes)
             self._frozen_bytes = 0
         for batch, active in zip(steps, actives):
-            params, mom, _, *counts = self._step(params, mom, batch, active,
-                                                 anchor, *self._frozen)
+            params, mom, _, *counts = step(params, mom, batch, active,
+                                           anchor, *self._frozen)
             if counts and obs.enabled():
                 for name, n in counts[0].items():
                     obs.count(name, n)
         return params
+
+    def _compact_programs(self, params: Params, batch) -> dict:
+        """The take, step and put of every compact width of this fleet, for
+        this batch signature, compiled the first time the signature is met
+        (the first session): a width first used mid-run compiles nothing.
+        Compiled from the step's jit itself, never through ``_step``, so a
+        caller that wraps ``_step`` sees full-width sessions alone."""
+        c = jax.tree.leaves(params)[0].shape[0]
+        leaves, tree = jax.tree.flatten(batch)
+        key = (c, tree, tuple((x.shape[1:], x.dtype) for x in leaves))
+        if key not in self._compact:
+            def rows(x, b):
+                return jax.ShapeDtypeStruct((b,) + x.shape[1:], x.dtype)
+            programs = {}
+            for b in session_widths(c)[:-1]:
+                idx = jax.ShapeDtypeStruct((b,), jnp.int32)
+                take = self._take.lower(params, idx).compile()
+                p, mom, anchor = jax.eval_shape(self._take, params, idx)
+                step = self._vstep.lower(
+                    p, mom, jax.tree.map(lambda x: rows(x, b), batch),
+                    jax.ShapeDtypeStruct((b,), jnp.bool_), anchor,
+                    *self._frozen).compile()
+                put = self._put.lower(params, idx, p).compile()
+                programs[b] = (take, step, put)
+            self._compact[key] = programs
+        return self._compact[key]
 
     # ------------------------------------------------- round-state capture
 
@@ -972,7 +1094,12 @@ class ShardedFleetExecutor(FleetExecutor):
         return self._sh_bcast(global_params)
 
     def _session(self, params: Params, mask: np.ndarray) -> Params:
-        return super()._session(params, self._pad_mask(mask))
+        # Every (padded) slot, sharded over the mesh: a compact subset of
+        # the slots would not be.
+        if not mask.any():
+            return params
+        steps, actives = self._draw_session(self._pad_mask(mask))
+        return self._full_session(params, steps, actives)
 
     def _permute(self, params: Params, op: PermuteOp) -> Params:
         send, recv = _permutation_tables(self._pad_perm(op.src_of_dst),

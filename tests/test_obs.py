@@ -12,6 +12,7 @@ import pytest
 
 from repro import obs
 from repro.core.schedule import MixOp, PermuteOp, TrainOp
+from repro.fl.executors import compact_slots
 
 
 @pytest.fixture(autouse=True)
@@ -204,6 +205,13 @@ def test_fleet_round_spans_follow_the_schedule(clients, monkeypatch):
     steps = sum(int(epoch[op.train_mask].max()) for op in drawn)
     assert c["fl.exec.steps"] == steps
     assert c["fl.exec.slot_steps"] == clients * steps
+    # A session computes the smallest quarter of the fleet that holds its
+    # training slots, for every padded step.
+    widths = [len(compact_slots(op.train_mask)) for op in drawn]
+    assert c["fl.exec.computed_slot_steps"] == sum(
+        w * int(epoch[op.train_mask].max()) for w, op in zip(widths, drawn))
+    assert c["fl.exec.compacted_sessions"] == sum(w < clients
+                                                  for w in widths) > 0
     assert c["fl.exec.h2d_bytes"] > 0
     assert 0 < c["fl.plan.hops"] == sum(
         int(op.train_mask.sum()) for op in trains
