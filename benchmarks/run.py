@@ -30,6 +30,7 @@ Paper artifacts covered:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import sys
 import time
@@ -39,22 +40,20 @@ import numpy as np
 sys.path.insert(0, "src")
 
 
-EXECUTOR = "host"      # set by --executor; stamped on every registry sweep
-PLANNER = "host"       # set by --planner; stamped on every registry sweep
-ENGINE = None          # set by --engine; an EngineSpec preset name that wins
-                       # over EXECUTOR/PLANNER on every cell when given
+ENGINE = None          # set by --engine and --planner: the EngineSpec
+                       # stamped on every figure/table cell (None = host)
 
 
 def _fl(strategy, alpha=1.0, rounds=6, clients=8, task="fcn", **kw):
-    from repro.fl import ExperimentSpec, FLConfig, run_experiment
-    kw.setdefault("executor", EXECUTOR)
-    kw.setdefault("engine", ENGINE)
-    # the jax planner does not model underlay CUE interference
-    kw.setdefault("planner", "host" if kw.get("underlay") else PLANNER)
+    from repro.fl import EngineSpec, ExperimentSpec, FLConfig, run_experiment
+    engine = ENGINE or EngineSpec()
+    if kw.get("underlay"):
+        # the jax planner does not model underlay CUE interference
+        engine = dataclasses.replace(engine, planner="host")
     spec = ExperimentSpec(
         task=task, alpha=alpha, num_samples=4000,
         fl=FLConfig(strategy=strategy, rounds=rounds, num_clients=clients,
-                    num_models=clients, seed=0, **kw))
+                    num_models=clients, seed=0, engine=engine, **kw))
     return run_experiment(spec)
 
 
@@ -84,11 +83,10 @@ def _run_registry_sweep(bench_name: str, sweep_name: str, full: bool):
     """Drive one registry sweep; print per-cell CSV lines; write artifact."""
     from repro.experiments import run_sweep
     art = run_sweep(sweep_name, smoke=not full, seeds=(0,),
-                    executor=EXECUTOR, planner=PLANNER,
-                    engine_preset=ENGINE)
+                    engine=ENGINE or "host")
     for c in art["cells"]:
         curve = np.mean(np.asarray(c["accuracy"]), axis=0)
-        print(f"{bench_name},{c['label']},engine={c['engine']},"
+        print(f"{bench_name},{c['label']},executor={c['executor']},"
               f"acc={float(np.max(curve)):.4f},"
               f"dif_rounds={np.mean(c['diffusion_rounds']):.1f},"
               f"subframes={c['comm']['subframes']},"
@@ -422,7 +420,7 @@ def executor_speedup(full: bool):
             task="fcn", alpha=1.0, num_samples=6000,
             fl=FLConfig(strategy="feddif", rounds=rounds,
                         num_clients=clients, num_models=clients, seed=0,
-                        topology_seed=0, executor=executor))
+                        topology_seed=0, engine=executor))
         t0 = time.time()
         r = run_experiment(spec)
         dt = time.time() - t0
@@ -473,7 +471,7 @@ def fleet_scaling(full: bool):
     import jax
     from benchmarks.roofline import fl_round_roofline, measure_machine_peak
     from repro.experiments.artifacts import write_bench_json
-    from repro.fl import ExperimentSpec, FLConfig, run_experiment
+    from repro.fl import EngineSpec, ExperimentSpec, FLConfig, run_experiment
     from repro.fl.experiment import load_experiment_data, spec_model_bits
 
     n_devices = len(jax.devices())
@@ -491,7 +489,7 @@ def fleet_scaling(full: bool):
     big_rounds = 6
     big_n = max(sizes)
 
-    def make_spec(n, executor, rounds=None, **fl_kw):
+    def make_spec(n, executor, rounds=None, overlap="auto"):
         # experiment.py trains on the test_frac side of the split, so this
         # is ~40 train samples (2–3 batches) per client up to N=150.  At
         # N≥1024 the per-client shard is pinned small (5 rows/client): at
@@ -508,7 +506,8 @@ def fleet_scaling(full: bool):
             fl=FLConfig(strategy=strategy, rounds=rounds, num_clients=n,
                         num_models=n, seed=0, topology_seed=0,
                         max_diffusion_rounds=6 if n <= 256 else 3,
-                        executor=executor, **fl_kw))
+                        engine=EngineSpec(mode=executor,
+                                          shard_overlap=overlap)))
 
     arms = []
     for n in sizes:
@@ -517,7 +516,7 @@ def fleet_scaling(full: bool):
         arms.append((n, "fleet", make_spec(n, "fleet")))
         arms.append((n, "sharded", make_spec(n, "sharded")))
     arms.append((big_n, "sharded_off",
-                 make_spec(big_n, "sharded", shard_overlap="off")))
+                 make_spec(big_n, "sharded", overlap="off")))
 
     cells, ledgers, results = [], {}, {}
     for n, label, spec in arms:
@@ -631,8 +630,6 @@ def lm_hops(full: bool):
     fleet plane) per arm.  The roofline readout reports the int8 arm with
     ``d2d_bits`` so the bytes side reflects the packed wire.  Emits
     ``BENCH_lm_hops.json``."""
-    import dataclasses
-
     import jax
     from benchmarks.roofline import fl_round_roofline, measure_machine_peak
     from repro.experiments.artifacts import write_bench_json
@@ -651,7 +648,7 @@ def lm_hops(full: bool):
             fl=FLConfig(strategy="feddif", rounds=rounds,
                         num_clients=clients, num_models=clients, seed=0,
                         topology_seed=0, max_diffusion_rounds=4,
-                        executor=executor, hop_quant=hop_quant))
+                        engine=executor, hop_quant=hop_quant))
 
     # arm -> (adapter_hops, hop_quant); full_f32 is the no-view baseline.
     arms = {"full_f32": (False, "none"),
@@ -1047,23 +1044,18 @@ def _force_cpu_mesh_for(bench_names: list) -> None:
 
 
 def main() -> None:
-    global EXECUTOR, PLANNER, ENGINE
+    global ENGINE
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None)
-    ap.add_argument("--executor", choices=["host", "fleet", "sharded"],
-                    default="host",
-                    help="FL data plane for the figure/table benches "
-                         "(executor_speedup / fleet_scaling always compare)")
+    ap.add_argument("--engine", default="host",
+                    help="EngineSpec preset stamped on every figure/table "
+                         "cell (host/fleet/sharded/auto/async/async_barrier;"
+                         " executor_speedup / fleet_scaling / "
+                         "async_throughput always compare)")
     ap.add_argument("--planner", choices=["host", "jax"], default="host",
                     help="FL control plane for the figure/table benches "
                          "(planner_speedup always compares both)")
-    ap.add_argument("--engine", default=None,
-                    help="EngineSpec preset stamped on every figure/table "
-                         "cell (host/fleet/sharded/auto/async/async_barrier)"
-                         "; wins over --executor/--planner when given "
-                         "(async_throughput always compares async vs "
-                         "async_barrier)")
     ap.add_argument("--check-budgets", action="store_true",
                     help="run no benches; gate existing BENCH artifacts "
                          "against benchmarks/budgets.json and exit nonzero "
@@ -1071,19 +1063,16 @@ def main() -> None:
     args = ap.parse_args()
     if args.check_budgets:
         raise SystemExit(check_budgets())
-    EXECUTOR = args.executor
-    PLANNER = args.planner
-    ENGINE = args.engine
     selected = [b.__name__ for b in BENCHES
                 if not args.only or args.only in b.__name__]
     _force_cpu_mesh_for(selected)   # must precede any repro/jax import
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
-    if args.engine is not None:
-        from repro.fl.engine import ENGINE_PRESETS
-        if args.engine not in ENGINE_PRESETS:
-            raise SystemExit(f"--engine must be one of "
-                             f"{sorted(ENGINE_PRESETS)}")
+    from repro.fl.engine import ENGINE_PRESETS
+    if args.engine not in ENGINE_PRESETS:
+        raise SystemExit(f"--engine must be one of {sorted(ENGINE_PRESETS)}")
+    ENGINE = dataclasses.replace(ENGINE_PRESETS[args.engine],
+                                 planner=args.planner)
     t0 = time.time()
     for bench in BENCHES:
         if bench.__name__ not in selected:

@@ -118,9 +118,9 @@ def feddif_cache_key(cfg, t: int, dsi: np.ndarray, data_sizes: np.ndarray,
     cycle).  Folds in every plan input: the sizing knobs, the full
     :class:`AuctionConfig` surface (incl. ``outage_max`` and
     ``bandwidth_budget``, which alter feasibility/FCFS), the world scenario
-    and learning-value weight, and the planner mode (host and jax plans are
-    parity-checked but not bit-guaranteed, so they never share a cache
-    line).  Schedulers, the replicate engines and the sweep pre-planner all
+    and learning-value weight, and the resolved engine's planner mode (host
+    and jax plans are parity-checked but not bit-guaranteed, so they never
+    share a cache line).  The schedulers and the sweep pre-planner both
     call this helper — hand-built ``extra=`` tuples cannot drift apart.
 
     ``values`` is the round's learning-value vector.  It depends on the
@@ -128,6 +128,7 @@ def feddif_cache_key(cfg, t: int, dsi: np.ndarray, data_sizes: np.ndarray,
     signal is active its digest joins the key and plans stop being
     shareable across replicate seeds (the pre-planner skips those cells).
     """
+    from repro.fl.engine import resolve_engine   # fl imports core
     vdigest = ""
     if values is not None and getattr(cfg, "uncertainty_weight", 0.0):
         vdigest = hashlib.sha1(
@@ -138,7 +139,7 @@ def feddif_cache_key(cfg, t: int, dsi: np.ndarray, data_sizes: np.ndarray,
         extra=(cfg.num_clients, cfg.num_models, float(model_bits),
                cfg.max_diffusion_rounds, cfg.allow_retraining, cfg.underlay,
                float(auction.outage_max), float(auction.bandwidth_budget),
-               getattr(cfg, "planner", "host"),
+               resolve_engine(cfg).planner,
                getattr(cfg, "scenario", "static"),
                float(getattr(cfg, "uncertainty_weight", 0.0)), vdigest))
 

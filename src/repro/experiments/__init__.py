@@ -9,8 +9,8 @@ Public API:
   :func:`run_cell`: expand a registry entry, run it with multi-seed
   replication and a shared diffusion-plan cache, emit a
   ``BENCH_feddif_<sweep>.json`` artifact.
-* :mod:`repro.experiments.replicate` — the replication engines
-  (seed-vmapped data plane vs process-level loop).
+* :mod:`repro.experiments.replicate` — multi-seed replication
+  (:func:`run_replicates_loop`).
 * :mod:`repro.experiments.artifacts` — artifact schema and writer.
 * :mod:`repro.experiments.durability` — durable sweeps: the work-queue
   manifest, per-cell records and plan-cache snapshot behind
@@ -28,15 +28,13 @@ from repro.experiments.orchestrator import run_cell, run_sweep
 from repro.experiments.registry import (REGISTRY, SweepCell, SweepDef,
                                         expand_sweep, get_sweep, register,
                                         sweep_names)
-from repro.experiments.replicate import (SEED_VMAP_STRATEGIES,
-                                         run_replicates_loop,
-                                         run_replicates_vmapped)
+from repro.experiments.replicate import run_replicates_loop
 
 __all__ = [
     "REGISTRY", "SweepCell", "SweepDef", "expand_sweep", "get_sweep",
     "register", "sweep_names",
     "run_cell", "run_sweep",
-    "SEED_VMAP_STRATEGIES", "run_replicates_loop", "run_replicates_vmapped",
+    "run_replicates_loop",
     "bench_file", "bench_path", "build_artifact", "default_out_dir",
     "strip_volatile", "write_artifact", "write_bench_json",
     "SweepManifest", "cell_slug", "default_state_dir",

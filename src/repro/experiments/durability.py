@@ -40,9 +40,8 @@ MANIFEST_VERSION = 1
 
 # Config keys that may differ between the original launch and a --resume
 # without invalidating stored progress: the checkpoint cadence (resume may
-# tighten/loosen it) and the replication engine (durable sweeps force
-# "loop" anyway).
-_RESUME_SAFE_KEYS = ("checkpoint_every", "engine")
+# tighten/loosen it).
+_RESUME_SAFE_KEYS = ("checkpoint_every",)
 
 
 def cell_slug(label: str) -> str:

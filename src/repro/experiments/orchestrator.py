@@ -9,9 +9,8 @@ For every cell of the sweep's grid it
 
 1. stamps a shared ``topology_seed`` so the wireless control plane is
    independent of the replicate seed,
-2. runs the cell at every seed — vmapped over the seed axis on the data
-   plane where the strategy allows (:data:`SEED_VMAP_STRATEGIES`),
-   process-level loop otherwise,
+2. runs the cell at every seed, one ``run_federated`` per seed on the
+   cell's engine (:func:`~repro.experiments.replicate.run_replicates_loop`),
 3. shares one :class:`~repro.core.diffusion.PlanCache` across the whole
    sweep, so FedDif's host-side auction loop runs once per distinct
    (topology seed, round, partition, ε, γ_min) and is *replayed* for every
@@ -38,14 +37,11 @@ from repro.core.diffusion import PlanCache, feddif_cache_key
 from repro.core.dol import DiffusionState
 from repro.experiments import artifacts
 from repro.experiments.registry import SweepCell, expand_sweep, get_sweep
-from repro.experiments.replicate import (SEED_VMAP_STRATEGIES,
-                                         run_replicates_loop,
-                                         run_replicates_vmapped)
-from repro.fl.engine import SHARDED_CROSSOVER_N, resolve_engine
+from repro.experiments.replicate import run_replicates_loop
+from repro.fl.engine import SHARDED_CROSSOVER_N, EngineSpec, resolve_engine
 from repro.fl.server import _uplink_gamma
 
-__all__ = ["run_cell", "run_sweep", "prepopulate_plan_cache",
-           "SHARDED_CROSSOVER_N"]
+__all__ = ["run_cell", "run_sweep", "prepopulate_plan_cache"]
 
 _FEDDIF_STRATEGIES = ("feddif", "feddif_stc", "feddif_prox")
 
@@ -54,15 +50,16 @@ def prepopulate_plan_cache(cells: Sequence[SweepCell], cache: PlanCache
                            ) -> dict:
     """Plan every FedDif cell × communication round in batched device calls.
 
-    For each eligible cell (FedDif family, ``planner='jax'``, topology seed
-    set, no underlay) this replays the control-plane RNG exactly as
-    ``run_federated`` would (positions → uplink draw → Rayleigh rounds),
+    For each eligible cell (FedDif family, an engine whose planner is
+    ``"jax"``, topology seed set, no underlay) this replays the
+    control-plane RNG exactly as ``run_federated`` would (positions →
+    uplink draw → Rayleigh rounds),
     builds one :class:`~repro.core.planner.PlanInputs` per communication
     round, groups them by static signature (N, M, C, max_rounds, metric,
     retraining) and plans each group in **one** vmapped device call.  The
     decoded plans + post-plan states land in ``cache`` under the same
     :func:`~repro.core.diffusion.feddif_cache_key` the schedulers build, so
-    every subsequent ``run_cell`` — any engine, any replicate seed — replays
+    every subsequent ``run_cell`` — any replicate seed — replays
     instead of replanning.
 
     Returns ``{"planned": k, "skipped": j, "batches": b}``.
@@ -77,7 +74,7 @@ def prepopulate_plan_cache(cells: Sequence[SweepCell], cache: PlanCache
     for cell in cells:
         cfg = cell.spec.fl
         if (cell.strategy not in _FEDDIF_STRATEGIES
-                or getattr(cfg, "planner", "host") != "jax"
+                or resolve_engine(cfg).planner != "jax"
                 or cfg.topology_seed is None or cfg.underlay
                 or getattr(cfg, "scenario", "static") != "static"
                 or getattr(cfg, "uncertainty_weight", 0.0) > 0.0):
@@ -140,84 +137,38 @@ def prepopulate_plan_cache(cells: Sequence[SweepCell], cache: PlanCache
     return {"planned": planned, "skipped": skipped, "batches": len(groups)}
 
 
-# The measured fleet/sharded N-crossover now lives in repro.fl.engine
-# (SHARDED_CROSSOVER_N, re-exported above for back-compat); the downgrade
-# heuristic formerly in _pick_executor is EngineSpec.auto().
-
-
-def _pick_executor(cell: SweepCell, engine: str) -> SweepCell:
-    """Crossover downgrade, delegated to :meth:`EngineSpec.auto`.
-
-    ``resolve_engine`` maps the cell's config (typed ``fl.engine`` or the
-    legacy string kwargs) onto a spec and applies the sharded->fleet
-    downgrade below :data:`SHARDED_CROSSOVER_N`; the resolved mode is
-    stamped back onto the cell so replication engines see it.
-    """
+def _pick_executor(cell: SweepCell) -> SweepCell:
+    """Downgrade a sharded cell below :data:`SHARDED_CROSSOVER_N` to the
+    fleet plane (:meth:`EngineSpec.auto`), stamping the picked spec back
+    onto the cell."""
     cfg = cell.spec.fl
-    if engine == "auto" and cfg.engine is None and cfg.executor == "sharded":
-        mode = resolve_engine(cfg).auto(cfg.num_clients).mode
-        if mode != cfg.executor:
-            print(f"orchestrator,{cell.label},executor={mode},"
-                  f"reason=N={cfg.num_clients}<crossover="
-                  f"{SHARDED_CROSSOVER_N}", flush=True)
-            return cell.with_fl(executor=mode)
-    return cell
-
-
-def _pick_engine(cell: SweepCell, engine: str) -> str:
-    mode = resolve_engine(cell.spec.fl).mode
-    if mode in ("fleet", "sharded", "async"):
-        # fleet/sharded already vmap/shard the *client* axis, and the async
-        # plane's event queue is inherently sequential over ticks; replicate
-        # seeds run on the loop engine (the seed_vmap engine is its own
-        # host-side seed-stacked data plane and would bypass the executor
-        # seam).
-        return "loop"
-    if cell.spec.fl.churn_rate > 0.0:
-        # Churn masks are applied schedule-side in run_federated; the
-        # seed_vmap engine hand-rolls fedavg/feddif rounds and would skip
-        # them.
-        return "loop"
-    if (getattr(cell.spec.fl, "scenario", "static") != "static"
-            or getattr(cell.spec.fl, "uncertainty_weight", 0.0) > 0.0):
-        # Evolving-world scenarios advance HostWorld state on the host
-        # control plane, and the value signal makes plans seed-dependent —
-        # both outside the seed-stacked engine's contract.
-        return "loop"
-    if engine == "auto":
-        return ("seed_vmap" if cell.strategy in SEED_VMAP_STRATEGIES
-                else "loop")
-    return engine
+    spec = resolve_engine(cfg)
+    picked = spec.auto(cfg.num_clients)
+    if picked is spec:
+        return cell
+    print(f"orchestrator,{cell.label},executor={picked.mode},"
+          f"reason=N={cfg.num_clients}<crossover={SHARDED_CROSSOVER_N}",
+          flush=True)
+    return cell.with_fl(engine=picked)
 
 
 def run_cell(cell: SweepCell, seeds: Sequence[int],
              plan_cache: PlanCache | None = None,
-             engine: str = "auto",
              checkpoint_root: str | None = None) -> dict:
     """Run one sweep cell at every replicate seed; returns the JSON record.
 
-    ``engine``: ``"auto"`` (vmap the seed axis when the strategy allows),
-    ``"seed_vmap"``, or ``"loop"``; cells with ``fl.executor == "fleet"``
-    always take the loop engine (the executor vmaps the client axis).
-
-    ``checkpoint_root`` (durable sweeps) forces the loop engine — the
-    seed-vmapped cohort bypasses ``run_federated`` and therefore the
-    :class:`~repro.fl.resume.RoundCheckpointer` seam — and gives each
-    replicate seed a round-checkpoint directory under it.
+    A sharded cell below the crossover runs on the fleet plane
+    (:func:`_pick_executor`); the record's ``"executor"`` is the plane that
+    ran.  ``checkpoint_root`` (durable sweeps) gives each replicate seed a
+    round-checkpoint directory under it.
     """
     if not len(seeds):
         raise ValueError("run_cell needs at least one replicate seed")
-    cell = _pick_executor(cell, engine)
-    chosen = _pick_engine(cell, engine)
-    if checkpoint_root is not None:
-        chosen = "loop"
+    cell = _pick_executor(cell)
     cache_before = plan_cache.stats() if plan_cache is not None else None
     t0 = time.time()
-    if chosen == "seed_vmap":
-        results = run_replicates_vmapped(cell.spec, seeds, plan_cache)
-    else:
-        results = run_replicates_loop(cell.spec, seeds, plan_cache,
-                                      checkpoint_root=checkpoint_root)
+    results = run_replicates_loop(cell.spec, seeds, plan_cache,
+                                  checkpoint_root=checkpoint_root)
     wall = time.time() - t0
 
     # Per-cell plan-cache delta: how much of this cell's control plane was
@@ -236,7 +187,6 @@ def run_cell(cell: SweepCell, seeds: Sequence[int],
         "axis": cell.axis,
         "value": cell.value,
         "strategy": cell.strategy,
-        "engine": chosen,
         "executor": resolve_engine(cell.spec.fl).mode,
         "plan_cache": cache_stats,
         "seeds": [int(s) for s in seeds],
@@ -259,9 +209,8 @@ def run_cell(cell: SweepCell, seeds: Sequence[int],
 
 
 def run_sweep(name: str, smoke: bool = True, seeds: Sequence[int] = (0,),
-              out_dir: str | None = "auto", engine: str = "auto",
-              executor: str = "host", planner: str = "host",
-              engine_preset: str | None = None,
+              out_dir: str | None = "auto",
+              engine: EngineSpec | str = "host",
               plan_cache: PlanCache | None = None,
               checkpoint_every: int = 0, resume: bool = False,
               state_dir: str | None = None,
@@ -277,20 +226,13 @@ def run_sweep(name: str, smoke: bool = True, seeds: Sequence[int] = (0,),
         :func:`repro.experiments.artifacts.default_out_dir` (the single
         artifact directory CI globs); ``None`` skips writing (used by tests
         and by callers composing artifacts).
-      engine: replication engine, see :func:`run_cell`.
-      executor: ``FLConfig.executor`` stamped on every cell — ``"host"``
-        reference loop, ``"fleet"`` client-stacked data plane, or
-        ``"sharded"`` client-sharded mesh plane.
-      planner: ``FLConfig.planner`` stamped on every cell — ``"host"``
-        numpy control plane or ``"jax"`` device planner.  With ``"jax"``
-        the whole sweep's diffusion plans are computed up front in batched
-        device calls (:func:`prepopulate_plan_cache`); the per-cell runs
-        then replay them from the shared cache.
-      engine_preset: an :data:`~repro.fl.engine.ENGINE_PRESETS` name (e.g.
-        ``"async"``) stamped as ``FLConfig.engine`` on every cell.  The
-        typed spec wins over the legacy ``executor`` string — this is how
-        ``launch/sweep --engine async`` selects the buffered-async plane
-        sweep-wide.
+      engine: an :class:`~repro.fl.engine.EngineSpec` or an
+        :data:`~repro.fl.engine.ENGINE_PRESETS` name, stamped as
+        ``FLConfig.engine`` on every cell (a sweep whose axis is the engine
+        overrides it per cell).  With ``planner="jax"`` the whole sweep's
+        diffusion plans are computed up front in batched device calls
+        (:func:`prepopulate_plan_cache`); the per-cell runs then replay
+        them from the shared cache.
       plan_cache: share one across sweeps if desired; default is a fresh
         cache per sweep (still shared across all cells *and* seeds).
       checkpoint_every: round-checkpoint cadence R.  Any of
@@ -312,10 +254,9 @@ def run_sweep(name: str, smoke: bool = True, seeds: Sequence[int] = (0,),
     Returns the artifact dict (also written to disk unless out_dir=None).
     """
     defn = get_sweep(name)
-    cells = expand_sweep(name, smoke=smoke, executor=executor,
-                         planner=planner, **spec_overrides)
-    if engine_preset is not None:
-        cells = [c.with_fl(engine=engine_preset) for c in cells]
+    if isinstance(engine, str):
+        engine = EngineSpec.preset(engine)
+    cells = expand_sweep(name, smoke=smoke, engine=engine, **spec_overrides)
     cache = plan_cache if plan_cache is not None else PlanCache()
     durable = checkpoint_every > 0 or resume or state_dir is not None
 
@@ -325,9 +266,9 @@ def run_sweep(name: str, smoke: bool = True, seeds: Sequence[int] = (0,),
         state_dir = state_dir or durability.default_state_dir(name)
         os.makedirs(state_dir, exist_ok=True)
         config = {"sweep": name, "smoke": smoke,
-                  "seeds": [int(s) for s in seeds], "executor": executor,
-                  "planner": planner, "engine": engine,
-                  "engine_preset": engine_preset,
+                  "seeds": [int(s) for s in seeds],
+                  "executor": engine.mode, "planner": engine.planner,
+                  "engine": engine.describe(),
                   "checkpoint_every": int(checkpoint_every),
                   "spec_overrides": spec_overrides}
         manifest = durability.SweepManifest.open(
@@ -342,7 +283,7 @@ def run_sweep(name: str, smoke: bool = True, seeds: Sequence[int] = (0,),
                     f"{cache.stats()['entries']}")
 
     t0 = time.time()
-    if planner == "jax":
+    if engine.planner == "jax":
         pre = prepopulate_plan_cache(cells, cache)
         if log is not None:
             log(f"{name},preplan,planned={pre['planned']},"
@@ -363,7 +304,7 @@ def run_sweep(name: str, smoke: bool = True, seeds: Sequence[int] = (0,),
             cell = cell.with_fl(checkpoint_every=int(checkpoint_every))
             ckpt_root = manifest.cell_checkpoint_root(cell.label)
             try:
-                rec = run_cell(cell, seeds, plan_cache=cache, engine=engine,
+                rec = run_cell(cell, seeds, plan_cache=cache,
                                checkpoint_root=ckpt_root)
             except Exception as e:          # noqa: BLE001 — cell isolation
                 # One broken cell must not sink the grid: record the error,
@@ -380,10 +321,10 @@ def run_sweep(name: str, smoke: bool = True, seeds: Sequence[int] = (0,),
             durability.save_plan_cache_file(state_dir, cache)
             rec = manifest.load_record(cell.label)  # canonical JSON types
         else:
-            rec = run_cell(cell, seeds, plan_cache=cache, engine=engine)
+            rec = run_cell(cell, seeds, plan_cache=cache)
         if log is not None:
             s = rec["summary"]
-            log(f"{name},{rec['label']},engine={rec['engine']},"
+            log(f"{name},{rec['label']},executor={rec['executor']},"
                 f"peak_acc={s['peak_mean']:.4f},"
                 f"subframes={rec['comm']['subframes']},"
                 f"bandwidth_hz_s={rec['comm']['pusch_bandwidth_hz_s']:.3e},"
@@ -392,8 +333,8 @@ def run_sweep(name: str, smoke: bool = True, seeds: Sequence[int] = (0,),
 
     artifact = artifacts.build_artifact(
         sweep_name=name, figure=defn.figure, axis=defn.axis, smoke=smoke,
-        seeds=list(seeds), cells=records, executor=executor,
-        planner=planner, plan_cache_stats=cache.stats(),
+        seeds=list(seeds), cells=records, executor=engine.mode,
+        planner=engine.planner, plan_cache_stats=cache.stats(),
         wall_clock_s=time.time() - t0,
         failed_cells=manifest.failed_cells() if manifest is not None
         else None)

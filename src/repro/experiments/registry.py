@@ -32,7 +32,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any
 
-from repro.fl.engine import ENGINE_PRESETS
+from repro.fl.engine import ENGINE_PRESETS, EngineSpec
 from repro.fl.experiment import ExperimentSpec
 from repro.fl.models import TASK_MODELS
 from repro.fl.server import FLConfig, STRATEGIES
@@ -102,7 +102,7 @@ class SweepDef:
     value_strategies: dict = dataclasses.field(default_factory=dict)
 
     def expand(self, smoke: bool = True, topology_seed: int = 0,
-               executor: str = "host", planner: str = "host",
+               engine: EngineSpec | str = "host",
                **overrides) -> list[SweepCell]:
         """Expand to concrete cells.
 
@@ -111,13 +111,9 @@ class SweepDef:
           topology_seed: control-plane seed stamped on every cell so
             diffusion plans are shareable across replicate seeds (see
             ``FLConfig.topology_seed``).
-          executor: data plane stamped on every cell — ``"host"`` (per-slot
-            reference loop), ``"fleet"`` (client-stacked vmap) or
-            ``"sharded"`` (client axis sharded over a ``("clients",)``
-            mesh); see ``FLConfig.executor``.
-          planner: control plane stamped on every cell — ``"host"`` numpy
-            oracle or ``"jax"`` batched device planner; see
-            ``FLConfig.planner``.
+          engine: ``FLConfig.engine`` stamped on every cell (an
+            :class:`~repro.fl.engine.EngineSpec` or a preset name); an
+            ``"engine"`` axis overrides it per cell.
           overrides: extra ``ExperimentSpec`` field overrides (e.g.
             ``num_samples=500`` for tests).
         """
@@ -135,7 +131,7 @@ class SweepDef:
                 fl_kwargs: dict = dict(
                     strategy=strategy, rounds=rounds, num_clients=clients,
                     num_models=clients, seed=0, topology_seed=topology_seed,
-                    executor=executor, planner=planner)
+                    engine=engine)
                 spec_kwargs: dict = dict(
                     task="fcn", alpha=1.0, num_samples=samples, data_seed=0)
                 fl_kwargs.update(self.fl_overrides)
@@ -258,7 +254,7 @@ register(SweepDef(
     description="Large-N fleet scaling: client population N (M = N models) "
                 "× strategy under per-round churn/straggler dropout — the "
                 "regime the 2-D (clients × model) sharded executor targets "
-                "(run with --executor sharded).  At N ≥ 1024 the Hungarian "
+                "(run with --engine sharded).  At N ≥ 1024 the Hungarian "
                 "auction control plane is O(N³), so only the auction-free "
                 "strategies run there.",
     values=(20, 64, 256, 1024, 4096),

@@ -1,60 +1,23 @@
-"""Multi-seed replication engines.
+"""Multi-seed replication.
 
-Two ways to run one sweep cell at ``S`` replicate seeds:
-
-* :func:`run_replicates_vmapped` — the fast path.  Model-init seeds only
-  differ on the *data plane* (initial params and therefore every subsequent
-  local update), so the whole cohort is trained as one pytree with a leading
-  seed axis: ``init`` is ``jax.vmap``-ed over ``PRNGKey(seed)``s and every
-  local SGD step is a jit-compiled ``vmap`` over that axis.  The *control
-  plane* (topology draw, auction, diffusion plan, ledger charges) is
-  seed-independent by construction (``FLConfig.topology_seed``), runs once,
-  and is shared by every replicate — with a
-  :class:`~repro.core.diffusion.PlanCache` it is not even replanned across
-  cells that share a key.  Supports the strategies whose round structure is
-  identical across seeds: ``fedavg`` and ``feddif``.
-
-* :func:`run_replicates_loop` — the general path: one
-  :func:`~repro.fl.experiment.run_experiment` call per seed (any strategy),
-  still sharing the plan cache so FedDif's host control plane is replayed,
-  not replanned, for seeds after the first.
-
-Both return one :class:`~repro.fl.server.FLResult` per seed with identical
-ledgers across seeds (communication is seed-independent given the topology
-seed), so downstream aggregation code does not care which engine produced
-them.
+:func:`run_replicates_loop` runs one sweep cell at ``S`` replicate seeds:
+one :func:`~repro.fl.experiment.run_experiment` call per seed (any
+strategy, any engine), sharing the plan cache so FedDif's control plane is
+replayed, not replanned, for seeds after the first.  It returns one
+:class:`~repro.fl.server.FLResult` per seed; their ledgers are identical
+(communication is seed-independent given ``FLConfig.topology_seed``).
 """
 from __future__ import annotations
 
-import copy
 import dataclasses
+import os
 from typing import Sequence
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+from repro.core.diffusion import PlanCache
+from repro.fl.experiment import ExperimentSpec, run_experiment
+from repro.fl.server import FLResult
 
-from repro.channels.fading import ChannelModel
-from repro.channels.resources import GAMMA_FLOOR, ResourceLedger
-from repro.channels.topology import CellTopology
-from repro.core import aggregation as agg
-from repro.core.auction import AuctionConfig
-from repro.core.diffusion import (DiffusionPlanner, PlanCache,
-                                  feddif_cache_key)
-from repro.core.dol import DiffusionState, iid_distance
-from repro.fl.experiment import (ExperimentSpec, load_experiment_data,
-                                 run_experiment)
-from repro.fl.models import build_task_model
-from repro.fl.server import FLResult, _uplink_gamma
-from repro.train import optimizer as opt_lib
-
-__all__ = ["SEED_VMAP_STRATEGIES", "run_replicates_vmapped",
-           "run_replicates_loop"]
-
-# Strategies whose per-round control flow is identical for every seed, so the
-# seed axis can live on the data plane.  The others (fedswap's visit loop,
-# gossip's pairings, …) stay on the process-level loop path.
-SEED_VMAP_STRATEGIES = ("fedavg", "feddif")
+__all__ = ["run_replicates_loop"]
 
 
 def run_replicates_loop(spec: ExperimentSpec, seeds: Sequence[int],
@@ -68,7 +31,6 @@ def run_replicates_loop(spec: ExperimentSpec, seeds: Sequence[int],
     resumes mid-cohort: finished seeds rerun from their final checkpoint in
     O(1 rounds), the interrupted seed from its last boundary.
     """
-    import os
     results = []
     for s in seeds:
         spec_s = dataclasses.replace(
@@ -77,171 +39,4 @@ def run_replicates_loop(spec: ExperimentSpec, seeds: Sequence[int],
                     if checkpoint_root is not None else None)
         results.append(run_experiment(spec_s, plan_cache=plan_cache,
                                       checkpoint_dir=ckpt_dir))
-    return results
-
-
-def _make_stacked_local_update(model, cfg, clip: float = 10.0):
-    """Seed-stacked mirror of ``repro.fl.client.make_local_update``.
-
-    The jitted step is ``vmap``-ed over a leading seed axis on (params,
-    momentum); the batch is shared (the data partition is fixed by
-    ``data_seed``, not the replicate seed).  Gradient clipping is *per seed*
-    (inside the vmap), matching the loop engine's math exactly.
-    """
-    opt = opt_lib.sgd(momentum=cfg.momentum)
-
-    def one(params, mu, batch, lr):
-        loss, grads = jax.value_and_grad(
-            lambda p: model.loss(p, batch))(params)
-        grads, _ = opt_lib.clip_by_global_norm(grads, clip)
-        updates, new_state = opt.update(grads, {"mu": mu}, params, lr)
-        return opt_lib.apply_updates(params, updates), new_state["mu"], loss
-
-    step = jax.jit(jax.vmap(one, in_axes=(0, 0, None, None)))
-
-    def local_update(params, batches):
-        mu = jax.tree.map(lambda p: jnp.zeros_like(p, jnp.float32), params)
-        total, nb = None, 0
-        for batch in batches:
-            b = {k: jnp.asarray(v) for k, v in batch.items()}
-            params, mu, loss = step(params, mu, b, cfg.lr)
-            total = loss if total is None else total + loss
-            nb += 1
-        mean = total / max(nb, 1) if total is not None else None
-        return params, mean
-
-    return local_update
-
-
-def run_replicates_vmapped(spec: ExperimentSpec, seeds: Sequence[int],
-                           plan_cache: PlanCache | None = None
-                           ) -> list[FLResult]:
-    """Run one cell at ``len(seeds)`` replicate seeds, seed axis vmapped.
-
-    Requires ``spec.fl.strategy in SEED_VMAP_STRATEGIES`` and
-    ``spec.fl.topology_seed`` set (the control plane must be
-    seed-independent for the cohort to share one plan/ledger).
-    """
-    cfg = spec.fl
-    if cfg.strategy not in SEED_VMAP_STRATEGIES:
-        raise ValueError(
-            f"strategy {cfg.strategy!r} is not seed-vmappable; "
-            f"use run_replicates_loop")
-    if cfg.topology_seed is None:
-        raise ValueError("seed-vmapped replication needs fl.topology_seed "
-                         "(control plane must not depend on the model seed)")
-    if cfg.churn_rate > 0.0:
-        raise ValueError("seed-vmapped replication does not model churn "
-                         "(fl.churn_rate > 0); use run_replicates_loop")
-    if getattr(cfg, "scenario", "static") != "static":
-        # Mobility / handoff / energy evolve HostWorld state per round on
-        # the host control plane; the replicated device loop has no slot
-        # for it (and value-fused plans are seed-dependent anyway).
-        raise ValueError(
-            f"seed-vmapped replication supports scenario='static' only "
-            f"(got {cfg.scenario!r}); use run_replicates_loop")
-    if getattr(cfg, "uncertainty_weight", 0.0) > 0.0:
-        raise ValueError(
-            "seed-vmapped replication cannot fuse learning values "
-            "(fl.uncertainty_weight > 0): the value signal depends on each "
-            "seed's params, so plans are not shareable; use "
-            "run_replicates_loop")
-    seeds = [int(s) for s in seeds]
-
-    # ---- data / model setup (identical to run_experiment, done once) -----
-    train, test, part, loaders = load_experiment_data(spec)
-    model = build_task_model(spec.task, spec.dim, spec.num_classes)
-    dsi, data_sizes = part.dsi, part.data_sizes
-    n, m = cfg.num_clients, cfg.num_models
-
-    keys = jnp.stack([jax.random.PRNGKey(s) for s in seeds])
-    global_params = jax.vmap(model.init)(keys)      # leading seed axis S
-    local_update = _make_stacked_local_update(model, cfg)
-
-    @jax.jit
-    def eval_stacked(params):
-        def one(p):
-            return (model.accuracy(p, test.x, test.y),
-                    model.loss(p, {"x": test.x, "y": test.y}))
-        return jax.vmap(one)(params)
-
-    # ---- shared control plane -------------------------------------------
-    topology = CellTopology(num_pues=n)
-    channel = ChannelModel()
-    auction = AuctionConfig(gamma_min=cfg.gamma_min, metric=cfg.metric,
-                            allow_retraining=cfg.allow_retraining)
-    planner = DiffusionPlanner(topology, channel, auction,
-                               epsilon=cfg.epsilon,
-                               max_rounds=cfg.max_diffusion_rounds,
-                               underlay=cfg.underlay, mode=cfg.planner)
-    ledger = ResourceLedger()
-    one_seed = jax.tree.map(lambda x: x[0], global_params)
-    model_bits = agg.model_bits(one_seed, cfg.bits_per_param)
-    auction.model_bits = model_bits
-
-    acc_hist, loss_hist, dif_hist, iid_hist = [], [], [], []
-
-    for t in range(cfg.rounds):
-        ctrl_rng = np.random.default_rng([cfg.topology_seed, t])
-        pos = topology.sample_positions(ctrl_rng, n)
-        up_gamma = np.maximum(_uplink_gamma(channel, pos, ctrl_rng),
-                              GAMMA_FLOOR)
-
-        if cfg.strategy == "fedavg":
-            ledger.charge_downlink(model_bits, float(np.median(up_gamma)), n)
-            locals_ = []
-            for i in range(n):
-                p, _ = local_update(global_params, list(loaders[i].epoch()))
-                locals_.append(p)
-                ledger.charge_uplink(model_bits, float(up_gamma[i]))
-            global_params = agg.fedavg(locals_, list(data_sizes))
-            dif_hist.append(0)
-            iid_hist.append(float(np.mean(iid_distance(
-                np.asarray(dsi), cfg.metric))))
-        else:                                               # feddif
-            ledger.charge_downlink(model_bits, float(np.median(up_gamma)), n)
-            models = [global_params for _ in range(m)]
-            state = DiffusionState.init(m, n, dsi.shape[1])
-            for mi in range(m):
-                holder = int(state.holder[mi])
-                models[mi], _ = local_update(models[mi],
-                                             list(loaders[holder].epoch()))
-                state.record_training(mi, holder, dsi[holder],
-                                      float(data_sizes[holder]))
-            cache_key = None
-            if plan_cache is not None:
-                cache_key = feddif_cache_key(cfg, t, dsi, data_sizes,
-                                             model_bits, auction)
-            plan = planner.plan_communication_round(
-                state, dsi, data_sizes, ctrl_rng, positions=pos,
-                cache=plan_cache, cache_key=cache_key)
-            for k in range(plan.num_rounds):
-                for hop in plan.hops_in_round(k):
-                    ledger.charge_d2d(model_bits, max(hop.gamma, GAMMA_FLOOR))
-                    models[hop.model], _ = local_update(
-                        models[hop.model], list(loaders[hop.dst].epoch()))
-            for mi in range(m):
-                ledger.charge_uplink(model_bits,
-                                     float(up_gamma[int(state.holder[mi])]))
-            weights = [float(state.chain_size[mi]) for mi in range(m)]
-            global_params = agg.fedavg(models, weights)
-            dif_hist.append(plan.num_rounds)
-            iid_hist.append(float(np.mean(plan.final_iid_distance)))
-
-        if (t + 1) % cfg.eval_every == 0 or t == cfg.rounds - 1:
-            a, l = eval_stacked(global_params)
-            acc_hist.append(np.asarray(a, np.float64))
-            loss_hist.append(np.asarray(l, np.float64))
-
-    # ---- unstack into one FLResult per seed -----------------------------
-    results = []
-    for si, s in enumerate(seeds):
-        results.append(FLResult.from_histories(
-            accuracy=[float(a[si]) for a in acc_hist],
-            loss=[float(l[si]) for l in loss_hist],
-            ledger=copy.deepcopy(ledger),
-            diffusion_rounds=list(dif_hist),
-            iid_distance=list(iid_hist),
-            config=dataclasses.replace(cfg, seed=s),
-            final_params=jax.tree.map(lambda x: x[si], global_params)))
     return results

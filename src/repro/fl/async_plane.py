@@ -232,7 +232,7 @@ def run_buffered_async(init_fn: Callable, loss_fn: Callable,
 
     inner_name = espec.inner_data_plane(n)
     inner = make_executor(inner_name, loss_fn, local_update, batches_view,
-                          cfg)
+                          cfg, espec)
     ledger = ResourceLedger()
     global_params = init_fn(key)
     model_bits = agg.model_bits(global_params, cfg.bits_per_param)

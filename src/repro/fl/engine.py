@@ -1,21 +1,15 @@
 """EngineSpec / RunResult — the typed engine-selection and result API.
 
-Engine selection used to be string sprawl across ``FLConfig``: ``executor``
-(+ the sharded plane's ``shard_overlap`` / ``shard_hop_transport`` /
-``shard_microbatch`` / ``mesh_model_axis``), ``planner``, and the
-orchestrator's ``_pick_executor`` heuristic on top.  The buffered-async
-plane (PR 9) would have added a fourth ad-hoc knob family.  This module
-collapses all of it into one frozen :class:`EngineSpec`:
+One frozen :class:`EngineSpec` says which execution plane runs a round and
+how: the mode ("host" / "fleet" / "sharded" / "async" / "auto"), the
+planner, the sharded plane's knobs and the buffered-async knobs.
 
-* ``EngineSpec`` is the **single selection authority**: every runtime entry
-  point (``run_federated``, the sweep orchestrator, the benches) resolves
-  its engine through :func:`resolve_engine` and nothing else constructs an
-  engine from raw strings.
-* Legacy ``FLConfig`` string kwargs keep working through
-  :meth:`EngineSpec.from_config` — a deprecation shim that warns **once**
-  per process and maps the old fields onto a spec.
-* :meth:`EngineSpec.auto` absorbs ``orchestrator._pick_executor``: the
-  measured sharded/fleet crossover lives here, next to the thing it picks.
+* ``FLConfig.engine`` is the **only** engine field: an ``EngineSpec`` or a
+  preset name.  Every runtime entry point (``run_federated``, the sweep
+  orchestrator, the benches) resolves it through :func:`resolve_engine`,
+  and the executors read their knobs from the resolved spec.
+* :meth:`EngineSpec.auto` holds the measured sharded/fleet crossover, next
+  to the thing it picks.
 * Named :data:`ENGINE_PRESETS` ("host", "fleet", "sharded", "async", …) are
   what ``launch/sweep --engine`` and ``benchmarks/run.py --engine`` accept,
   and what ``FLConfig.engine`` stores when given a string.
@@ -29,7 +23,6 @@ used.  The legacy ``FLResult`` flat attributes (``accuracy``, ``loss``,
 from __future__ import annotations
 
 import dataclasses
-import warnings
 from typing import Any
 
 __all__ = ["AsyncSpec", "EngineSpec", "ENGINE_PRESETS", "resolve_engine",
@@ -39,8 +32,7 @@ __all__ = ["AsyncSpec", "EngineSpec", "ENGINE_PRESETS", "resolve_engine",
 # Measured fleet/sharded crossover (benchmarks/run.py fleet_scaling on the
 # 2-device CPU mesh): below this N the collective rendezvous overhead of the
 # sharded plane exceeds its parallelism win.  EngineSpec.auto() downgrades
-# sharded requests under it — the heuristic formerly in
-# ``orchestrator._pick_executor``.
+# sharded requests under it.
 SHARDED_CROSSOVER_N = 64
 
 #: Execution planes run_federated can dispatch to.
@@ -142,9 +134,17 @@ class EngineSpec:
       data_plane: the async plane's *inner* op executor ("auto" | "host" |
         "fleet") — the buffered-async engine replays each round's schedule
         ops through it, then re-orders the aggregation by arrival.
-      shard_overlap / shard_hop_transport / shard_microbatch /
-        mesh_model_axis: the sharded plane's knobs, verbatim from the old
-        ``FLConfig`` fields.
+      shard_overlap: "auto" | "on" | "off" — the sharded plane's fused
+        round program with double-buffered hop/train stages ("on") or the
+        op-by-op plane ("off"); "auto" takes the fused plane at large N
+        (``ShardedFleetExecutor.FUSED_MIN_CLIENTS``).
+      shard_hop_transport: the fused plane's hop collective — "gather" (one
+        all_gather per hop), "ring" (per-shift ppermute, O(block) memory)
+        or "auto" (gather while the gathered stack fits its byte budget).
+      shard_microbatch: clients per device microbatch on the sharded plane
+        (caps memory).
+      mesh_model_axis: requested "model" axis size of the 2-D
+        ("clients", "model") FL mesh; hops feature-shard over it.
       buffered: the :class:`AsyncSpec` knobs (read when ``mode="async"``).
     """
     mode: str = "host"
@@ -172,12 +172,10 @@ class EngineSpec:
     def auto(self, num_clients: int) -> "EngineSpec":
         """Resolve "auto" and downgrade infeasible sharded requests.
 
-        Absorbs ``orchestrator._pick_executor``: a sharded engine below the
-        measured :data:`SHARDED_CROSSOVER_N` (or on a single device, where
-        the mesh degenerates anyway) downgrades to the fleet plane;
-        ``mode="auto"`` picks sharded above the crossover on a multi-device
-        runtime and fleet otherwise.  Idempotent; never changes an explicit
-        host/fleet/async request.
+        A sharded engine below the measured :data:`SHARDED_CROSSOVER_N`
+        downgrades to the fleet plane; ``mode="auto"`` picks sharded above
+        the crossover on a multi-device runtime and fleet otherwise.
+        Idempotent; never changes an explicit host/fleet/async request.
         """
         import jax
         mode = self.mode
@@ -213,33 +211,6 @@ class EngineSpec:
                 f"/av={b.avail_alpha},{b.avail_beta}"
                 f"/spd={b.speed_sigma}")
 
-    # ------------------------------------------------------ legacy mapping
-
-    @classmethod
-    def from_config(cls, cfg) -> "EngineSpec":
-        """Deprecation shim: map the legacy ``FLConfig`` string kwargs onto
-        a spec.  Warns once per process when any legacy engine field is
-        set away from its default (the new spelling is
-        ``FLConfig(engine=EngineSpec(...))`` or a preset name)."""
-        spec = cls(mode=str(getattr(cfg, "executor", "host")),
-                   planner=str(getattr(cfg, "planner", "host")),
-                   shard_overlap=str(getattr(cfg, "shard_overlap", "auto")),
-                   shard_hop_transport=str(getattr(cfg, "shard_hop_transport",
-                                                   "auto")),
-                   shard_microbatch=int(getattr(cfg, "shard_microbatch", 32)),
-                   mesh_model_axis=int(getattr(cfg, "mesh_model_axis", 1)))
-        global _WARNED_LEGACY
-        if not _WARNED_LEGACY and spec != cls():
-            _WARNED_LEGACY = True
-            warnings.warn(
-                "engine selection via FLConfig string kwargs (executor=, "
-                "planner=, shard_*=) is deprecated; pass "
-                "FLConfig(engine=EngineSpec(...)) or a preset name "
-                "(engine='fleet') instead — the legacy kwargs keep working "
-                "for one release through this shim",
-                DeprecationWarning, stacklevel=3)
-        return spec
-
     @classmethod
     def preset(cls, name: str) -> "EngineSpec":
         try:
@@ -269,20 +240,15 @@ ENGINE_PRESETS: dict[str, EngineSpec] = {
         delay_scale=0.01, delay_sigma=1.0)),
 }
 
-_WARNED_LEGACY = False
-
 
 def resolve_engine(cfg) -> EngineSpec:
     """THE engine-selection authority: ``FLConfig`` -> :class:`EngineSpec`.
 
-    ``cfg.engine`` wins when set (an :class:`EngineSpec`, or a preset name);
-    otherwise the legacy string kwargs map through the deprecation shim.
+    ``cfg.engine`` is an :class:`EngineSpec` or a preset name;
     ``mode="auto"`` resolves against ``cfg.num_clients``.
     """
-    eng = getattr(cfg, "engine", None)
-    if eng is None:
-        spec = EngineSpec.from_config(cfg)
-    elif isinstance(eng, str):
+    eng = cfg.engine
+    if isinstance(eng, str):
         spec = EngineSpec.preset(eng)
     elif isinstance(eng, EngineSpec):
         spec = eng
@@ -381,17 +347,3 @@ class RunResult:
             return float(self.history.virtual_s[min(
                 r - 1, len(self.history.virtual_s) - 1)])
         return float(r)
-
-    @classmethod
-    def from_histories(cls, *, accuracy, loss, ledger, diffusion_rounds,
-                       iid_distance, config=None, final_params=None,
-                       round_wall_s=(), engine=None,
-                       **async_hist) -> "RunResult":
-        """Build a result from the flat legacy field spelling (replication
-        engines, tests)."""
-        hist = RunHistory(accuracy=list(accuracy), loss=list(loss),
-                          diffusion_rounds=list(diffusion_rounds),
-                          iid_distance=list(iid_distance),
-                          round_wall_s=list(round_wall_s), **async_hist)
-        return cls(params=final_params, ledger=ledger, history=hist,
-                   engine=engine, config=config)

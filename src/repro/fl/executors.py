@@ -27,12 +27,12 @@ Two data planes consume the same schedule object:
   The stacked pytree's leading client axis is *sharded* over the combined
   mesh axis (:func:`repro.distributed.sharding.fl_stacked_specs`), padded
   with zero-weighted slots when N does not divide the mesh, and runs in one
-  of two shard_map planes selected by ``FLConfig.shard_overlap``:
+  of two shard_map planes selected by ``EngineSpec.shard_overlap``:
 
   - the **op-by-op plane** (``shard_overlap="off"``): one compiled
     collective per schedule op — sessions are ``shard_map``-ped with the
     per-shard block microbatched (``lax.map`` over chunks of
-    ``FLConfig.shard_microbatch`` clients) so N=256–4096
+    ``EngineSpec.shard_microbatch`` clients) so N=256–4096
     fleets fit in memory, a :class:`~repro.core.schedule.PermuteOp` is a
     ring-shift-decomposed permutation collective (static routing tables +
     per-shift ``lax.ppermute``; with a model axis the flattened parameter
@@ -650,7 +650,7 @@ class ShardedFleetExecutor(FleetExecutor):
       ``"clients"``-ring ``ppermute`` then moves F/km bytes per link, and
       the inverse ``all_to_all`` restores the train layout.
 
-    * **Planes.**  ``FLConfig.shard_overlap`` picks between the inherited
+    * **Planes.**  ``EngineSpec.shard_overlap`` picks between the inherited
       op-by-op round loop (one compiled collective per schedule op) and
       the fused round plane: the
       whole round is ONE jitted shard_map program per round *signature*
@@ -659,7 +659,7 @@ class ShardedFleetExecutor(FleetExecutor):
       so chunk j+1's collectives — which depend only on pre-hop state —
       overlap chunk j's training compute.
 
-    * **Hop transport.**  ``FLConfig.shard_hop_transport`` picks the fused
+    * **Hop transport.**  ``EngineSpec.shard_hop_transport`` picks the fused
       plane's hop collective: ``"gather"`` (one tiled ``all_gather`` over
       the combined axes + local row-take — a single rendezvous per hop)
       or ``"ring"`` (kc ``ppermute`` shifts, O(block) memory, double
@@ -682,13 +682,15 @@ class ShardedFleetExecutor(FleetExecutor):
 
     def __init__(self, loss_fn: Callable,
                  client_batches: Sequence[Callable], cfg,
-                 clip: float | None = 10.0, mesh=None):
+                 clip: float | None = 10.0, mesh=None, *,
+                 mesh_model_axis: int = 1, shard_microbatch: int = 32,
+                 shard_overlap: str = "auto",
+                 shard_hop_transport: str = "auto"):
         super().__init__(loss_fn, client_batches, cfg, clip)
         from repro.launch.mesh import make_fl_mesh
         c = cfg.num_clients
         if mesh is None:
-            mesh = make_fl_mesh(c, model=int(getattr(cfg,
-                                                     "mesh_model_axis", 1)))
+            mesh = make_fl_mesh(c, model=mesh_model_axis)
         self.mesh = mesh
         shape = dict(mesh.shape)
         self.kc = int(shape[CLIENT_AXIS])
@@ -701,7 +703,7 @@ class ShardedFleetExecutor(FleetExecutor):
         self.c_pad = -(-c // self.k) * self.k
         self.nl = self.c_pad // self.k        # train-layout rows per device
         self.nl_hop = self.c_pad // self.kc   # hop-layout rows per ring slot
-        mb_cap = max(1, int(getattr(cfg, "shard_microbatch", 32)))
+        mb_cap = max(1, shard_microbatch)
         self.mb = max(b for b in range(1, min(mb_cap, self.nl) + 1)
                       if self.nl % b == 0)
         self.nchunks = self.nl // self.mb
@@ -710,16 +712,15 @@ class ShardedFleetExecutor(FleetExecutor):
         # pre-hop state, so its collectives can issue while chunk j trains.
         self.fused_chunks = 2 if (self.km == 1 and self.nl % 2 == 0) else 1
         self.fused_mb = self.nl // self.fused_chunks
-        mode = str(getattr(cfg, "shard_overlap", "auto"))
-        assert mode in ("auto", "on", "off"), mode
+        assert shard_overlap in ("auto", "on", "off"), shard_overlap
         # Below FUSED_MIN_CLIENTS the fused program's compile cost and
         # round-signature sensitivity outweigh the dispatch it saves —
         # "auto" therefore takes the fused plane only for large fleets.
-        self.overlap = mode == "on" or (mode == "auto"
-                                        and c >= self.FUSED_MIN_CLIENTS)
-        transport = str(getattr(cfg, "shard_hop_transport", "auto"))
-        assert transport in ("auto", "ring", "gather"), transport
-        self._transport_req = transport
+        self.overlap = shard_overlap == "on" or (
+            shard_overlap == "auto" and c >= self.FUSED_MIN_CLIENTS)
+        assert shard_hop_transport in ("auto", "ring", "gather"), \
+            shard_hop_transport
+        self._transport_req = shard_hop_transport
         self._transport: str | None = None     # resolved on first fused round
         self._stc_cache: dict = {}
         self._fused_cache: dict = {}
@@ -1305,13 +1306,20 @@ class ShardedFleetExecutor(FleetExecutor):
 
 
 def make_executor(name: str, loss_fn: Callable, local_update: Callable,
-                  client_batches: Sequence[Callable], cfg):
-    """Build the executor selected by ``FLConfig.executor``."""
+                  client_batches: Sequence[Callable], cfg, spec):
+    """Build the executor ``name`` (a resolved ``spec.mode``, or the async
+    plane's inner data plane); the sharded plane's knobs come from the
+    :class:`~repro.fl.engine.EngineSpec` ``spec``."""
     if name == "host":
         return HostExecutor(local_update, client_batches, cfg)
     if name == "fleet":
         return FleetExecutor(loss_fn, client_batches, cfg)
     if name == "sharded":
-        return ShardedFleetExecutor(loss_fn, client_batches, cfg)
+        return ShardedFleetExecutor(
+            loss_fn, client_batches, cfg,
+            mesh_model_axis=spec.mesh_model_axis,
+            shard_microbatch=spec.shard_microbatch,
+            shard_overlap=spec.shard_overlap,
+            shard_hop_transport=spec.shard_hop_transport)
     raise ValueError(f"unknown executor {name!r}; expected one of "
                      f"{EXECUTORS}")

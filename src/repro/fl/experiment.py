@@ -124,8 +124,8 @@ def run_experiment(spec: ExperimentSpec, plan_cache=None,
     to the FL runtime; combined with ``spec.fl.topology_seed`` it lets the
     sweep orchestrator replay host-side diffusion plans across replicate
     seeds instead of re-running the auction loop per seed.
-    ``spec.fl.executor`` selects the data plane (``"host"`` per-slot
-    reference loop or ``"fleet"`` client-stacked vmap) — schedules and
+    ``spec.fl.engine`` selects the data plane (``"host"`` per-slot
+    reference loop, ``"fleet"`` client-stacked vmap, …) — schedules and
     ledger charges are identical either way.
 
     ``checkpoint_dir`` + ``spec.fl.checkpoint_every > 0`` makes the cell

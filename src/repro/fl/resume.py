@@ -51,8 +51,8 @@ __all__ = ["RoundCheckpointer", "Preempted", "RoundState"]
 _CONFIG_GUARD = ("strategy", "num_clients", "num_models", "rounds",
                  "local_epochs", "lr", "momentum", "batch_size", "epsilon",
                  "gamma_min", "metric", "stc_sparsity", "prox_mu", "seed",
-                 "topology_seed", "executor", "planner", "churn_rate",
-                 "allow_retraining", "underlay")
+                 "topology_seed", "churn_rate", "allow_retraining",
+                 "underlay")
 
 
 class Preempted(BaseException):

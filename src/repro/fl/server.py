@@ -18,7 +18,8 @@ in three strategy-agnostic stages, mirroring the paper's PUCCH/PUSCH split:
 2. **charge** — :func:`~repro.core.schedule.charge_schedule` replays the wire
    events into the :class:`ResourceLedger` (Sec. III-D metrics), identically
    for every executor.
-3. **execute** — the executor selected by ``cfg.executor`` runs the ops:
+3. **execute** — the executor selected by ``cfg.engine`` (an
+   :class:`~repro.fl.engine.EngineSpec` or preset name) runs the ops:
    ``"host"`` on a per-slot pytree list (the reference semantics), ``"fleet"``
    on one client-stacked pytree via vmapped/jitted fedshard steps, and
    ``"sharded"`` with that client axis sharded over a ``("clients",)`` mesh
@@ -108,26 +109,6 @@ class FLConfig:
     random_walk_hops: int = 3          # hops/round for d2d_random_walk
     max_diffusion_rounds: int | None = None
     eval_every: int = 1
-    executor: str = "host"           # "host" (reference) | "fleet" (stacked)
-                                     # | "sharded" (client-sharded mesh)
-    shard_microbatch: int = 32       # clients per device microbatch when
-                                     # executor="sharded" (caps memory)
-    mesh_model_axis: int = 1         # requested "model" axis size of the 2-D
-                                     # ("clients","model") FL mesh — hops
-                                     # feature-shard over it (make_fl_mesh)
-    shard_overlap: str = "auto"      # "auto"|"on"|"off": fused round plane
-                                     # with double-buffered hop/train stages
-                                     # ("on") vs the op-by-op legacy plane
-                                     # ("off"); "auto" = fused at large N
-                                     # (executors.FUSED_MIN_CLIENTS) where
-                                     # per-op dispatch dominates, op-by-op
-                                     # below it
-    shard_hop_transport: str = "auto"  # fused-plane hop collective:
-                                     # "gather" (one all_gather per hop, the
-                                     # fast path while the gathered stack
-                                     # fits memory) | "ring" (per-shift
-                                     # ppermute, O(block) memory) | "auto"
-                                     # = gather under the byte budget
     churn_rate: float = 0.0          # per-round P(client drops out) — see
                                      # schedulers.apply_round_churn
     scenario: str = "static"         # wireless world evolution
@@ -146,8 +127,6 @@ class FLConfig:
                                      # scenario="energy_capped"; None = the
                                      # scenario default.  Depleted clients
                                      # drop out via churn semantics.
-    planner: str = "host"            # control plane: "host" numpy oracle |
-                                     # "jax" jitted/batched device planner
     allow_retraining: bool = False   # Appendix C-D (drops constraint 18c)
     underlay: bool = False           # Appendix C-F (D2D reuses CUE PRBs)
     checkpoint_every: int = 0        # durable round-state cadence R; 0 = off
@@ -161,18 +140,14 @@ class FLConfig:
                                      # up/downlinks stay fp32.  Composes
                                      # numerically with feddif_stc, whose
                                      # ledger keeps the STC accounting.
-    engine: "EngineSpec | str | None" = None
-                                     # The typed engine selection
+    engine: "EngineSpec | str" = "host"
+                                     # The engine selection
                                      # (repro.fl.engine): an EngineSpec, or
                                      # an ENGINE_PRESETS name ("host",
                                      # "fleet", "sharded", "auto", "async",
-                                     # "async_barrier").  When set it WINS
-                                     # over the legacy string kwargs above
-                                     # (executor / planner / shard_*), which
-                                     # keep working through the one-release
-                                     # EngineSpec.from_config deprecation
-                                     # shim.
-
+                                     # "async_barrier").  Picks the data
+                                     # plane, the planner and the sharded /
+                                     # async knobs.
 
 # Legacy alias, one release: ``run_federated`` now returns the structured
 # :class:`repro.fl.engine.RunResult` (params, ledger, history, engine), whose
@@ -207,8 +182,8 @@ def run_federated(init_fn: Callable, loss_fn: Callable,
         batches.
       dsi / data_sizes: from the Dirichlet partitioner.
       eval_fn: params -> (accuracy, loss) on held-out data.
-      cfg: experiment configuration; ``cfg.executor`` selects the data plane
-        (``"host"`` reference loop or ``"fleet"`` client-stacked vmap).
+      cfg: experiment configuration; ``cfg.engine`` selects the data plane
+        and the planner (see :func:`~repro.fl.engine.resolve_engine`).
       plan_cache: optional :class:`PlanCache` for FedDif strategies; only
         consulted when ``cfg.topology_seed`` is set (otherwise the wireless
         draw depends on ``cfg.seed`` and plans are not shareable).
@@ -244,14 +219,6 @@ def run_federated(init_fn: Callable, loss_fn: Callable,
                                   checkpointer=checkpointer,
                                   base_bits=base_bits, value_fn=value_fn)
     assert espec.mode in EXECUTORS, espec.mode
-    # Materialize the resolved spec onto the config the executor reads, so
-    # an explicit EngineSpec wins over stale legacy fields.
-    cfg_exec = dataclasses.replace(
-        cfg, executor=espec.mode, planner=espec.planner,
-        shard_overlap=espec.shard_overlap,
-        shard_hop_transport=espec.shard_hop_transport,
-        shard_microbatch=espec.shard_microbatch,
-        mesh_model_axis=espec.mesh_model_axis)
     n = cfg.num_clients
     rng = np.random.default_rng(cfg.seed)
     key = jax.random.PRNGKey(cfg.seed)
@@ -271,7 +238,7 @@ def run_federated(init_fn: Callable, loss_fn: Callable,
     else:
         local_update = make_local_update(loss_fn, cfg.momentum)
     executor = make_executor(espec.mode, loss_fn, local_update,
-                             client_batches, cfg_exec)
+                             client_batches, cfg, espec)
     ledger = ResourceLedger()
     # The evolving wireless world.  Static consumes exactly the draws the
     # pre-world control plane did, so the whole run is bit-identical; the

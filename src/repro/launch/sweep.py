@@ -12,8 +12,8 @@ Durable mode (kill-safe, bit-identical resume)::
 
 Expands the named entry of the sweep registry
 (:mod:`repro.experiments.registry`), runs every cell with multi-seed
-replication (seed axis vmapped on the data plane where the strategy allows,
-process loop otherwise; diffusion plans cached across seeds), and writes a
+replication (one run per seed on the cell's engine; diffusion plans cached
+across seeds), and writes a
 ``BENCH_feddif_<sweep>.json`` artifact with per-cell accuracy curves, the
 Eq.-15 cumulative PUSCH bandwidth, sub-frame counts and wall-clock.
 Artifacts land in the repo-wide BENCH directory
@@ -25,22 +25,16 @@ live in one place.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import os
 import sys
 
 from repro.experiments import REGISTRY, run_sweep, sweep_names
 from repro.experiments.artifacts import default_out_dir
-from repro.fl.engine import ENGINE_PRESETS
+from repro.fl.engine import ENGINE_PRESETS, EngineSpec
 from repro.launch.compile_cache import enable_compile_cache
 
 __all__ = ["main"]
-
-# --engine accepts the replication engines (how replicate seeds are run)
-# plus the EngineSpec preset names (which execution plane every cell uses);
-# "auto" belongs to both vocabularies and keeps its replication meaning.
-_REPLICATION_ENGINES = ("auto", "seed_vmap", "loop")
-_ENGINE_CHOICES = list(_REPLICATION_ENGINES) + sorted(
-    set(ENGINE_PRESETS) - {"auto"})
 
 
 def main(argv: list[str] | None = None) -> int:
@@ -56,20 +50,18 @@ def main(argv: list[str] | None = None) -> int:
                     help="paper-approaching grid sizes")
     ap.add_argument("--seeds", type=int, default=1,
                     help="number of replicate seeds (0..N-1)")
-    ap.add_argument("--engine", choices=_ENGINE_CHOICES, default="auto",
-                    help="replication engine (auto/seed_vmap/loop) or an "
-                         "EngineSpec preset stamped on every cell (e.g. "
-                         "'async' for the buffered-async round plane, "
-                         "'async_barrier' for its sync comparison arm)")
-    ap.add_argument("--executor", choices=["host", "fleet", "sharded"],
+    ap.add_argument("--engine", choices=sorted(ENGINE_PRESETS),
                     default="host",
-                    help="data plane per cell: host reference loop, "
-                         "client-stacked fleet, or client-sharded mesh "
-                         "(FLConfig.executor)")
+                    help="EngineSpec preset stamped on every cell: host "
+                         "reference loop, client-stacked fleet, "
+                         "client-sharded mesh (downgraded to fleet below "
+                         "the crossover N), auto, or the buffered-async "
+                         "round plane (async, and async_barrier for its "
+                         "sync comparison arm)")
     ap.add_argument("--planner", choices=["host", "jax"], default="host",
                     help="control plane per cell: host numpy oracle or "
                          "batched jax device planner that pre-plans the "
-                         "whole sweep in one device call (FLConfig.planner)")
+                         "whole sweep in one device call (EngineSpec.planner)")
     ap.add_argument("--out-dir", default=None,
                     help="artifact directory (default: "
                          "$REPRO_BENCH_DIR or benchmarks/results/ — the "
@@ -114,6 +106,8 @@ def main(argv: list[str] | None = None) -> int:
     names = sweep_names() if args.sweep == "all" else [args.sweep]
     seeds = tuple(range(args.seeds))
     out_dir = args.out_dir if args.out_dir is not None else default_out_dir()
+    engine = dataclasses.replace(EngineSpec.preset(args.engine),
+                                 planner=args.planner)
     overrides = {}
     if args.num_samples is not None:
         overrides["num_samples"] = args.num_samples
@@ -126,16 +120,8 @@ def main(argv: list[str] | None = None) -> int:
         state_dir = args.state_dir
         if state_dir is not None and args.sweep == "all":
             state_dir = os.path.join(state_dir, name)
-        # Preset names select the execution plane for every cell; the
-        # replication engine then defaults to "auto" (_pick_engine routes
-        # fleet/sharded/async cells onto the loop engine anyway).
-        preset = (args.engine if args.engine not in _REPLICATION_ENGINES
-                  else None)
-        repl_engine = args.engine if preset is None else "auto"
         artifact = run_sweep(name, smoke=smoke, seeds=seeds,
-                             out_dir=out_dir, engine=repl_engine,
-                             engine_preset=preset,
-                             executor=args.executor, planner=args.planner,
+                             out_dir=out_dir, engine=engine,
                              checkpoint_every=args.checkpoint_every,
                              resume=args.resume,
                              state_dir=state_dir if durable else None,

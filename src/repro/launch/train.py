@@ -46,9 +46,9 @@ def main(argv: list[str] | None = None):
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--alpha", type=float, default=0.5)
     ap.add_argument("--lr", type=float, default=0.01)
-    ap.add_argument("--engine", default=None,
+    ap.add_argument("--engine", default="host",
                     help="EngineSpec preset (host/fleet/sharded/auto/async/"
-                         "async_barrier); default: legacy host loop")
+                         "async_barrier)")
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)

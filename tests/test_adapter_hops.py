@@ -13,20 +13,20 @@ import pytest
 
 import jax
 
-from repro.fl import ExperimentSpec, FLConfig, run_experiment
+from repro.fl import EngineSpec, ExperimentSpec, FLConfig, run_experiment
 from repro.fl.adapters import make_adapter_view, packed_bits
 from repro.fl.experiment import spec_adapter_bits, spec_model_bits
 from repro.fl.models import build_task_model
 
 
-def _spec(executor="host", task="lm", hop_quant="int8", adapter_hops=True,
+def _spec(engine="host", task="lm", hop_quant="int8", adapter_hops=True,
           clients=4, rounds=2, **fl_kw):
     return ExperimentSpec(
         task=task, alpha=0.5, dim=16 if task == "lm" else 64,
         num_samples=640, adapter_hops=adapter_hops,
         fl=FLConfig(strategy="feddif", rounds=rounds, num_clients=clients,
                     num_models=clients, seed=0, topology_seed=1,
-                    max_diffusion_rounds=3, executor=executor,
+                    max_diffusion_rounds=3, engine=engine,
                     hop_quant=hop_quant, **fl_kw))
 
 
@@ -48,7 +48,8 @@ def test_host_fleet_sharded_parity_int8_lm():
     bit-identical, adapters within the executor-parity tolerance."""
     host = run_experiment(_spec("host"))
     fleet = run_experiment(_spec("fleet"))
-    sharded = run_experiment(_spec("sharded", shard_overlap="on"))
+    sharded = run_experiment(_spec(EngineSpec(mode="sharded",
+                                              shard_overlap="on")))
     assert (host.ledger.as_dict() == fleet.ledger.as_dict()
             == sharded.ledger.as_dict())
     assert host.diffusion_rounds == fleet.diffusion_rounds
@@ -67,13 +68,14 @@ def test_sharded_transports_and_planes_parity_2_devices():
     code = """
 import numpy as np, jax
 assert len(jax.devices()) == 2, jax.devices()
-from repro.fl import ExperimentSpec, FLConfig, run_experiment
+from repro.fl import EngineSpec, ExperimentSpec, FLConfig, run_experiment
 def spec(executor, **kw):
     return ExperimentSpec(task="lm", alpha=0.5, dim=16, num_samples=640,
         fl=FLConfig(strategy="feddif", rounds=2, num_clients=4,
                     num_models=4, seed=0, topology_seed=1,
-                    max_diffusion_rounds=3, executor=executor,
-                    hop_quant="int8", **kw))
+                    max_diffusion_rounds=3,
+                    engine=EngineSpec(mode=executor, **kw),
+                    hop_quant="int8"))
 fleet = run_experiment(spec("fleet"))
 for label, kw in (("ring_fused", {"shard_overlap": "on",
                                   "shard_hop_transport": "ring"}),

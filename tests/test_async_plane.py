@@ -28,7 +28,7 @@ from repro.fl.resume import Preempted, RoundCheckpointer
 from repro.fl.server import FLConfig
 
 
-def _spec(strategy="fedavg", n=4, rounds=2, engine=None, **fl_kw):
+def _spec(strategy="fedavg", n=4, rounds=2, engine="host", **fl_kw):
     return ExperimentSpec(
         task="fcn", alpha=0.5, num_samples=600,
         fl=FLConfig(strategy=strategy, rounds=rounds, num_clients=n,

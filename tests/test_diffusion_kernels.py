@@ -263,7 +263,7 @@ def _spec(strategy, executor, clients=4, rounds=2):
         task="fcn", alpha=0.3, num_samples=800,
         fl=FLConfig(strategy=strategy, rounds=rounds, num_clients=clients,
                     num_models=clients, seed=0, topology_seed=3,
-                    executor=executor, tthf_cluster_size=2,
+                    engine=executor, tthf_cluster_size=2,
                     tthf_global_period=2))
 
 

@@ -1,15 +1,14 @@
 """EngineSpec / RunResult API tests (repro.fl.engine).
 
-The spec is the single engine-selection authority: presets, the legacy
-FLConfig-string shim (warns once), auto resolution/downgrade, and the
-RunResult legacy surface (flat FLResult attributes + tuple unpacking).
+The spec is the single engine-selection authority: presets, the one
+``FLConfig.engine`` field, auto resolution/downgrade, the executor knobs
+it hands over, and the RunResult legacy surface (flat FLResult attributes +
+tuple unpacking).
 """
 import dataclasses
-import warnings
 
 import pytest
 
-import repro.fl.engine as engine_mod
 from repro.fl.engine import (ENGINE_PRESETS, AsyncSpec, EngineSpec,
                              SHARDED_CROSSOVER_N, RunHistory, RunResult,
                              engine_fingerprint, resolve_engine)
@@ -43,21 +42,26 @@ def test_async_preset_is_buffered_and_barrier_preset_is_not():
 
 # ----------------------------------------------------------- resolve order
 
-def test_engine_field_wins_over_legacy_strings():
-    cfg = FLConfig(strategy="fedavg", num_clients=4, num_models=4,
-                   executor="host", engine="fleet")
-    assert resolve_engine(cfg).mode == "fleet"
-    cfg = dataclasses.replace(cfg, engine=EngineSpec(mode="host"))
-    assert resolve_engine(cfg).mode == "host"
+def test_engine_defaults_to_the_host_preset():
+    cfg = FLConfig(strategy="fedavg", num_clients=4, num_models=4)
+    assert cfg.engine == "host"
+    assert resolve_engine(cfg) == ENGINE_PRESETS["host"] == EngineSpec()
+    cfg = dataclasses.replace(cfg, engine=EngineSpec(mode="fleet",
+                                                     planner="jax"))
+    assert resolve_engine(cfg) == EngineSpec(mode="fleet", planner="jax")
 
 
-def test_legacy_strings_map_through_shim():
-    cfg = FLConfig(strategy="fedavg", num_clients=4, num_models=4,
-                   executor="fleet", planner="jax", shard_microbatch=8)
-    spec = resolve_engine(cfg)
-    assert spec.mode == "fleet"
-    assert spec.planner == "jax"
-    assert spec.shard_microbatch == 8
+@pytest.mark.parametrize("field,value", [
+    ("executor", "fleet"), ("planner", "jax"), ("shard_microbatch", 8),
+    ("mesh_model_axis", 2), ("shard_overlap", "on"),
+    ("shard_hop_transport", "ring")])
+def test_flconfig_rejects_retired_engine_keywords(field, value):
+    """The engine is chosen through ``FLConfig.engine`` alone."""
+    with pytest.raises(TypeError, match=field):
+        FLConfig(strategy="fedavg", num_clients=4, num_models=4,
+                 **{field: value})
+    assert [f.name for f in dataclasses.fields(FLConfig)
+            if f.name in ("engine", field)] == ["engine"]
 
 
 def test_bad_engine_type_raises():
@@ -65,27 +69,6 @@ def test_bad_engine_type_raises():
                    engine=42)
     with pytest.raises(TypeError, match="EngineSpec or a preset"):
         resolve_engine(cfg)
-
-
-def test_shim_warns_once_per_process(monkeypatch):
-    monkeypatch.setattr(engine_mod, "_WARNED_LEGACY", False)
-    cfg = FLConfig(strategy="fedavg", num_clients=4, num_models=4,
-                   executor="fleet")
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        EngineSpec.from_config(cfg)
-        EngineSpec.from_config(cfg)
-    deps = [x for x in w if issubclass(x.category, DeprecationWarning)]
-    assert len(deps) == 1
-
-
-def test_shim_stays_silent_on_defaults(monkeypatch):
-    monkeypatch.setattr(engine_mod, "_WARNED_LEGACY", False)
-    cfg = FLConfig(strategy="fedavg", num_clients=4, num_models=4)
-    with warnings.catch_warnings(record=True) as w:
-        warnings.simplefilter("always")
-        EngineSpec.from_config(cfg)
-    assert not [x for x in w if issubclass(x.category, DeprecationWarning)]
 
 
 # ------------------------------------------------------------- auto logic
@@ -107,10 +90,22 @@ def test_auto_downgrades_sharded_below_crossover():
 
 def test_resolve_engine_keeps_explicit_sharded():
     # Benches deliberately run sharded below the crossover for parity
-    # checks; only the orchestrator's engine="auto" path downgrades.
+    # checks; only the sweep orchestrator downgrades.
     cfg = FLConfig(strategy="fedavg", num_clients=4, num_models=4,
-                   executor="sharded")
+                   engine=EngineSpec(mode="sharded"))
     assert resolve_engine(cfg).mode == "sharded"
+
+
+def test_make_executor_takes_the_sharded_knobs_from_the_spec():
+    from repro.fl.executors import make_executor
+    cfg = FLConfig(strategy="fedavg", num_clients=8, num_models=8)
+    spec = EngineSpec(mode="sharded", shard_microbatch=2,
+                      shard_overlap="on", shard_hop_transport="ring")
+    ex = make_executor("sharded", lambda p, b: 0.0, None, [], cfg, spec)
+    assert (ex.mb, ex.overlap, ex._transport_req) == (2, True, "ring")
+    ex = make_executor("sharded", lambda p, b: 0.0, None, [], cfg,
+                       EngineSpec(mode="sharded"))
+    assert (ex.mb, ex.overlap, ex._transport_req) == (8, False, "auto")
 
 
 def test_fingerprint_distinguishes_async_knobs():
@@ -157,10 +152,10 @@ def test_validate_rejects_bad_knobs():
 # --------------------------------------------------------------- RunResult
 
 def _result():
-    return RunResult.from_histories(
-        accuracy=[0.1, 0.5, 0.9], loss=[2.0, 1.0, 0.5], ledger="LEDGER",
+    return RunResult(params={"w": 1}, ledger="LEDGER", history=RunHistory(
+        accuracy=[0.1, 0.5, 0.9], loss=[2.0, 1.0, 0.5],
         diffusion_rounds=[1, 2, 1], iid_distance=[0.3, 0.2, 0.1],
-        final_params={"w": 1}, virtual_s=[1.0, 2.0, 4.0])
+        virtual_s=[1.0, 2.0, 4.0]))
 
 
 def test_runresult_legacy_surface_and_unpacking():
@@ -178,9 +173,9 @@ def test_time_to_accuracy_uses_virtual_clock_when_present():
     assert r.rounds_to_accuracy(0.5) == 2
     assert r.time_to_accuracy(0.5) == 2.0     # virtual_s[1]
     assert r.time_to_accuracy(0.99) is None
-    sync = RunResult.from_histories(
-        accuracy=[0.1, 0.5], loss=[1, 1], ledger=None,
-        diffusion_rounds=[0, 0], iid_distance=[0, 0])
+    sync = RunResult(params=None, ledger=None, history=RunHistory(
+        accuracy=[0.1, 0.5], loss=[1, 1], diffusion_rounds=[0, 0],
+        iid_distance=[0, 0]))
     assert sync.time_to_accuracy(0.5) == 2.0  # falls back to round index
 
 

@@ -19,7 +19,7 @@ from repro.core.schedule import (MixOp, PermuteOp, RoundSchedule,
                                  WireEvent, charge_schedule,
                                  complete_round_permutation)
 from repro.channels.resources import ResourceLedger
-from repro.fl import ExperimentSpec, FLConfig, run_experiment
+from repro.fl import EngineSpec, ExperimentSpec, FLConfig, run_experiment
 from repro.fl.adapters import FrozenLoss
 from repro.fl.executors import (FleetExecutor, ShardedFleetExecutor,
                                 compact_slots, session_widths)
@@ -31,7 +31,7 @@ def _spec(strategy, executor, rounds=2, clients=5, **kw):
         task="fcn", alpha=0.3, num_samples=1200,
         fl=FLConfig(strategy=strategy, rounds=rounds, num_clients=clients,
                     num_models=clients, seed=0, topology_seed=3,
-                    executor=executor, tthf_cluster_size=2,
+                    engine=executor, tthf_cluster_size=2,
                     tthf_global_period=2, **kw))
 
 
@@ -112,7 +112,7 @@ def test_every_strategy_runs_on_fleet_executor(strategy):
 
 def test_fleet_rejects_unknown_executor():
     with pytest.raises(AssertionError):
-        run_experiment(_spec("fedavg", "warp"))
+        run_experiment(_spec("fedavg", EngineSpec(mode="warp")))
 
 
 def test_rejects_more_models_than_clients():

@@ -6,12 +6,14 @@ import os
 import numpy as np
 import pytest
 
-from repro.core.diffusion import DiffusionPlanner, PlanCache, plan_cache_key
+from repro.core.auction import AuctionConfig
+from repro.core.diffusion import (DiffusionPlanner, PlanCache,
+                                  feddif_cache_key, plan_cache_key)
 from repro.core.dol import DiffusionState
-from repro.experiments import (REGISTRY, SEED_VMAP_STRATEGIES, bench_path,
-                               expand_sweep, run_sweep, sweep_names)
-from repro.experiments.replicate import (run_replicates_loop,
-                                         run_replicates_vmapped)
+from repro.experiments import (REGISTRY, bench_path, expand_sweep, run_sweep,
+                               sweep_names)
+from repro.experiments.replicate import run_replicates_loop
+from repro.fl.engine import SHARDED_CROSSOVER_N, EngineSpec, resolve_engine
 from repro.fl.experiment import ExperimentSpec
 from repro.fl.models import TASK_MODELS
 from repro.fl.server import STRATEGIES
@@ -69,8 +71,8 @@ def test_fig7_scaling_targets_large_n_with_churn():
     assert d.axis == "num_clients"
     assert max(d.values) >= 256 and max(d.smoke_values) >= 64
     assert d.fl_overrides.get("churn_rate", 0) > 0
-    cells = expand_sweep("fig7_scaling", smoke=True, executor="sharded")
-    assert all(c.spec.fl.executor == "sharded" for c in cells)
+    cells = expand_sweep("fig7_scaling", smoke=True, engine="sharded")
+    assert all(resolve_engine(c.spec.fl).mode == "sharded" for c in cells)
     assert all(c.spec.fl.churn_rate > 0 for c in cells)
 
 
@@ -92,42 +94,33 @@ def test_fig7_full_grid_drops_auction_strategies_at_large_n():
 
 
 def test_auto_engine_downgrades_sharded_below_crossover():
-    """engine="auto" swaps sharded→fleet under the measured N-crossover (the
+    """A sharded cell swaps to fleet under the measured N-crossover (the
     mesh dispatch overhead regime) and keeps sharded at/above it; the chosen
     executor lands in the cell record."""
-    from repro.experiments.orchestrator import (SHARDED_CROSSOVER_N,
-                                                _pick_executor)
-    cells = expand_sweep("fig7_scaling", smoke=True, executor="sharded")
+    from repro.experiments.orchestrator import _pick_executor
+    cells = expand_sweep("fig7_scaling", smoke=True,
+                         engine=EngineSpec(mode="sharded"))
     for cell in cells:
-        picked = _pick_executor(cell, "auto")
+        picked = _pick_executor(cell)
         want = ("fleet" if cell.spec.fl.num_clients < SHARDED_CROSSOVER_N
                 else "sharded")
-        assert picked.spec.fl.executor == want, cell.label
-        # explicit engines leave the user's executor choice alone
-        assert _pick_executor(cell, "loop").spec.fl.executor == "sharded"
+        assert resolve_engine(picked.spec.fl).mode == want, cell.label
+    # cells on the other planes are left alone
+    for cell in expand_sweep("fig7_scaling", smoke=True, engine="host"):
+        assert _pick_executor(cell) is cell
 
 
 def test_run_cell_records_downgraded_executor():
     from repro.experiments.orchestrator import run_cell
     cell = next(c for c in expand_sweep(
-        "fig7_scaling", smoke=True, executor="sharded", num_samples=400)
+        "fig7_scaling", smoke=True, engine=EngineSpec(mode="sharded"),
+        num_samples=400)
         if c.strategy == "fedavg" and c.value == 20)
     cell = dataclasses.replace(
         cell, spec=dataclasses.replace(
             cell.spec, fl=dataclasses.replace(cell.spec.fl, rounds=1)))
     rec = run_cell(cell, seeds=(0,))
     assert rec["executor"] == "fleet"
-
-
-def test_churned_cells_replicate_on_loop_engine():
-    """Churn masks are applied schedule-side in run_federated; the seed_vmap
-    engine would skip them, so engine picking must route to the loop."""
-    from repro.experiments.orchestrator import _pick_engine
-    cell = next(c for c in expand_sweep("fig7_scaling", smoke=True)
-                if c.strategy == "fedavg")
-    assert _pick_engine(cell, "auto") == "loop"
-    with pytest.raises(ValueError, match="churn"):
-        run_replicates_vmapped(cell.spec, (0,))
 
 
 def test_expand_overrides_reach_spec():
@@ -190,6 +183,19 @@ def test_plan_cache_key_distinguishes_inputs():
     assert k1 != plan_cache_key(0, 0, dsi2, sizes, 0.04, 1.0, "w1_norm")
 
 
+def test_feddif_cache_key_separates_the_engine_planners():
+    """Host and jax plans never share a cache line, whichever way the
+    engine is given."""
+    dsi, sizes = _tiny_partition()
+    fl = expand_sweep("fig3_alpha", smoke=True)[0].spec.fl
+    keys = [feddif_cache_key(dataclasses.replace(fl, engine=engine), 0, dsi,
+                             sizes, 1e6, AuctionConfig())
+            for engine in (EngineSpec(planner="host"), "host",
+                           EngineSpec(planner="jax"),
+                           EngineSpec(mode="fleet", planner="jax"))]
+    assert keys[0] == keys[1] != keys[2] == keys[3]
+
+
 def test_plan_cache_lru_eviction():
     dsi, sizes = _tiny_partition()
     cache = PlanCache(max_entries=2)
@@ -203,46 +209,57 @@ def test_plan_cache_lru_eviction():
     assert len(cache) == 2
 
 
-# ------------------------------------------------------- replication engines
+# ------------------------------------------------------------- replication
 
 def _tiny_cells(name="fig3_alpha"):
     return expand_sweep(name, smoke=True, num_samples=300)
 
 
-def test_vmapped_and_loop_engines_agree():
-    cell = next(c for c in _tiny_cells() if c.strategy == "feddif")
+def test_preplanned_jax_cell_replays_from_the_cache():
+    """A FedDif cell whose engine plans on the device is planned up front,
+    and its run replays every round from the cache."""
+    from repro.experiments.orchestrator import (prepopulate_plan_cache,
+                                                run_cell)
+    cell = next(c for c in expand_sweep(
+        "fig3_alpha", smoke=True, num_samples=300,
+        engine=EngineSpec(planner="jax")) if c.strategy == "feddif")
     cache = PlanCache()
-    r_v = run_replicates_vmapped(cell.spec, (0,), cache)
-    r_l = run_replicates_loop(cell.spec, (0,), cache)
-    assert cache.stats()["hits"] >= 1          # loop replayed vmap's plans
-    np.testing.assert_allclose(r_v[0].accuracy, r_l[0].accuracy, atol=2e-3)
-    assert r_v[0].ledger.as_dict() == r_l[0].ledger.as_dict()
-
-
-def test_vmapped_engine_rejects_unsupported_strategy():
-    cell = next(c for c in _tiny_cells("table2_strategies")
-                if c.strategy == "d2d_random_walk")
-    assert cell.strategy not in SEED_VMAP_STRATEGIES
-    with pytest.raises(ValueError):
-        run_replicates_vmapped(cell.spec, (0,))
-
-
-def test_vmapped_engine_requires_topology_seed():
-    cell = next(c for c in _tiny_cells() if c.strategy == "fedavg")
-    spec = dataclasses.replace(
-        cell.spec, fl=dataclasses.replace(cell.spec.fl, topology_seed=None))
-    with pytest.raises(ValueError):
-        run_replicates_vmapped(spec, (0,))
+    pre = prepopulate_plan_cache([cell], cache)
+    assert pre["planned"] == cell.spec.fl.rounds and pre["skipped"] == 0
+    rec = run_cell(cell, (0,), cache)
+    assert rec["plan_cache"]["hits"] >= 1
+    assert rec["plan_cache"]["misses"] == 0
 
 
 def test_replicate_seeds_differ_on_data_plane():
     cell = next(c for c in _tiny_cells() if c.strategy == "fedavg")
-    r = run_replicates_vmapped(cell.spec, (0, 1))
+    r = run_replicates_loop(cell.spec, (0, 1))
     assert r[0].config.seed == 0 and r[1].config.seed == 1
     # Same communication (control plane shared) ...
     assert r[0].ledger.as_dict() == r[1].ledger.as_dict()
     # ... but different models (init seeds differ).
     assert r[0].accuracy != r[1].accuracy
+
+
+def test_sweep_cli_builds_one_engine_for_every_cell(monkeypatch):
+    """``--engine`` names a preset and ``--planner`` sets its planner: the
+    two become one EngineSpec on every cell; ``--executor`` is refused."""
+    from repro.launch import sweep
+    seen = []
+
+    def fake_run_sweep(name, smoke, seeds, out_dir, engine, **kw):
+        seen.extend(resolve_engine(c.spec.fl)
+                    for c in expand_sweep(name, smoke=smoke, engine=engine))
+        return {"plan_cache": {}, "cells": [], "path": "-",
+                "wall_clock_s": 0.0}
+
+    monkeypatch.setattr(sweep, "run_sweep", fake_run_sweep)
+    monkeypatch.setattr(sweep, "enable_compile_cache", lambda: None)
+    assert sweep.main(["--sweep", "fig7_scaling", "--engine", "sharded",
+                       "--planner", "jax", "--out-dir", "-"]) == 0
+    assert seen and set(seen) == {EngineSpec(mode="sharded", planner="jax")}
+    with pytest.raises(SystemExit):
+        sweep.main(["--sweep", "fig7_scaling", "--executor", "sharded"])
 
 
 # ----------------------------------------------------------- end-to-end + IO

@@ -15,7 +15,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.fl import ExperimentSpec, FLConfig, run_experiment
+from repro.fl import EngineSpec, ExperimentSpec, FLConfig, run_experiment
 from repro.fl.executors import _chunked_permutation_tables
 from repro.launch.mesh import make_fl_mesh
 
@@ -96,12 +96,13 @@ def test_nondivisible_clients_and_model_axis_keep_parity():
     code = """
 import numpy as np, jax
 assert len(jax.devices()) == 4, jax.devices()
-from repro.fl import ExperimentSpec, FLConfig, run_experiment
+from repro.fl import EngineSpec, ExperimentSpec, FLConfig, run_experiment
 def spec(executor, **kw):
     return ExperimentSpec(task="fcn", alpha=0.5, num_samples=1000,
         fl=FLConfig(strategy="feddif", rounds=2, num_clients=10,
                     num_models=10, seed=0, topology_seed=1,
-                    max_diffusion_rounds=3, executor=executor, **kw))
+                    max_diffusion_rounds=3,
+                    engine=EngineSpec(mode=executor, **kw)))
 host = run_experiment(spec("host"))
 for label, kw in (("pad_clients", {"shard_overlap": "on"}),
                   ("pad_model", {"shard_overlap": "on",
@@ -128,12 +129,13 @@ def test_n256_parity_on_2d_mesh_8_devices():
     code = """
 import numpy as np, jax
 assert len(jax.devices()) == 8, jax.devices()
-from repro.fl import ExperimentSpec, FLConfig, run_experiment
+from repro.fl import EngineSpec, ExperimentSpec, FLConfig, run_experiment
 def spec(executor, **kw):
     return ExperimentSpec(task="fcn", alpha=0.5, num_samples=25600,
         fl=FLConfig(strategy="feddif", rounds=1, num_clients=256,
                     num_models=256, seed=0, topology_seed=1,
-                    max_diffusion_rounds=2, executor=executor, **kw))
+                    max_diffusion_rounds=2,
+                    engine=EngineSpec(mode=executor, **kw)))
 fleet = run_experiment(spec("fleet"))
 for label, kw in (("fused", {"shard_overlap": "on", "mesh_model_axis": 2}),
                   ("op_by_op", {"mesh_model_axis": 2, "shard_overlap": "off"})):
@@ -160,7 +162,8 @@ def test_mesh_model_axis_degenerates_cleanly_on_one_device():
             task="fcn", alpha=0.5, num_samples=800,
             fl=FLConfig(strategy="feddif", rounds=1, num_clients=8,
                         num_models=8, seed=0, topology_seed=1,
-                        max_diffusion_rounds=2, executor="sharded", **kw))
+                        max_diffusion_rounds=2,
+                        engine=EngineSpec(mode="sharded", **kw)))
     base = run_experiment(spec())
     m2 = run_experiment(spec(mesh_model_axis=4))
     assert base.ledger.as_dict() == m2.ledger.as_dict()

@@ -144,12 +144,13 @@ def test_spans_land_on_the_host_plane_with_their_durations(tmp_path):
 # ------------------------------------------------------- a fleet FedDif round
 
 def _fleet_feddif(clients):
-    from repro.fl import ExperimentSpec, FLConfig
+    from repro.fl import EngineSpec, ExperimentSpec, FLConfig
     return ExperimentSpec(
         task="fcn", alpha=0.3, num_samples=900,
         fl=FLConfig(strategy="feddif", rounds=2, num_clients=clients,
                     num_models=clients, seed=0, topology_seed=5,
-                    executor="fleet", planner="jax", batch_size=16,
+                    engine=EngineSpec(mode="fleet", planner="jax"),
+                    batch_size=16,
                     gamma_min=0.5, epsilon=0.01))
 
 

@@ -21,6 +21,7 @@ from repro.channels.resources import (outage_probability,
 from repro.channels.topology import CellTopology
 from repro.core import DiffusionPlanner, DiffusionState, PlannerState
 from repro.core.diffusion import DiffusionHop, DiffusionPlan, PlanCache
+from repro.fl.engine import EngineSpec
 
 
 def _mkstate(n, m, c, dsi, sizes):
@@ -77,7 +78,8 @@ def test_host_vs_jax_end_to_end_ledger_parity():
                     seed=0, topology_seed=3, max_diffusion_rounds=8))
     r_host = run_experiment(spec)
     spec_j = dataclasses.replace(
-        spec, fl=dataclasses.replace(spec.fl, planner="jax"))
+        spec, fl=dataclasses.replace(spec.fl,
+                                     engine=EngineSpec(planner="jax")))
     r_jax = run_experiment(spec_j)
     assert r_host.ledger.as_dict() == r_jax.ledger.as_dict()
     assert r_host.accuracy == r_jax.accuracy
@@ -90,12 +92,12 @@ def test_batched_preplan_matches_per_round_plans():
     misses and charges the same ledger as an uncached host run."""
     from repro.experiments import run_sweep
     art = run_sweep("fig5_gamma_min", smoke=True, seeds=(0,), out_dir=None,
-                    planner="jax", num_samples=300)
+                    engine=EngineSpec(planner="jax"), num_samples=300)
     assert art["planner"] == "jax"
     assert art["plan_cache"]["misses"] == 0
     assert art["plan_cache"]["hits"] > 0
     host = run_sweep("fig5_gamma_min", smoke=True, seeds=(0,), out_dir=None,
-                     planner="host", num_samples=300)
+                     engine=EngineSpec(planner="host"), num_samples=300)
     for cj, ch in zip(art["cells"], host["cells"]):
         assert cj["comm"] == ch["comm"]
         assert cj["accuracy"] == ch["accuracy"]

@@ -24,6 +24,7 @@ import pytest
 from repro.experiments import strip_volatile
 from repro.experiments.durability import SweepManifest
 from repro.experiments.orchestrator import run_sweep
+from repro.fl.engine import EngineSpec
 from repro.fl.experiment import ExperimentSpec, run_experiment
 from repro.fl.resume import Preempted, RoundCheckpointer
 from repro.fl.server import FLConfig
@@ -34,7 +35,7 @@ ROUNDS = 4
 def _spec(executor: str, strategy: str = "feddif", **fl_overrides
           ) -> ExperimentSpec:
     kwargs = dict(strategy=strategy, num_clients=4, num_models=4,
-                  rounds=ROUNDS, topology_seed=7, executor=executor,
+                  rounds=ROUNDS, topology_seed=7, engine=executor,
                   checkpoint_every=1, batch_size=8)
     kwargs.update(fl_overrides)
     return ExperimentSpec(task="logistic", num_samples=400,
@@ -128,7 +129,11 @@ def test_kill_resume_with_churn(tmp_path, monkeypatch):
     _assert_results_identical(clean, resumed)
 
 
-def test_resume_refuses_mismatched_config(tmp_path, monkeypatch):
+@pytest.mark.parametrize("change", [
+    {"gamma_min": 2.5}, {"engine": "fleet"},
+    {"engine": EngineSpec(planner="jax")}],
+    ids=["gamma_min", "engine_mode", "planner"])
+def test_resume_refuses_mismatched_config(change, tmp_path, monkeypatch):
     spec = _spec("host")
     d = str(tmp_path / "ckpt")
     with monkeypatch.context() as m:
@@ -137,7 +142,7 @@ def test_resume_refuses_mismatched_config(tmp_path, monkeypatch):
             run_experiment(spec, checkpoint_dir=d)
     import dataclasses
     other = dataclasses.replace(
-        spec, fl=dataclasses.replace(spec.fl, gamma_min=2.5))
+        spec, fl=dataclasses.replace(spec.fl, **change))
     with pytest.raises(ValueError, match="different config"):
         run_experiment(other, checkpoint_dir=d)
 

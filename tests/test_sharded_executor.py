@@ -8,6 +8,7 @@ subprocess test forces a 2-device CPU mesh so ppermute / psum_scatter /
 psum actually cross shards; CI's smokes job additionally drives the fig7
 scaling sweep on a 2-device mesh.
 """
+import dataclasses
 import os
 import subprocess
 import sys
@@ -18,18 +19,20 @@ import pytest
 
 from repro.core.schedule import (MixOp, PermuteOp, RoundSchedule, TrainOp,
                                  WireEvent, apply_churn)
-from repro.fl import ExperimentSpec, FLConfig, run_experiment
+from repro.fl import EngineSpec, ExperimentSpec, FLConfig, run_experiment
 from repro.fl.executors import _permutation_tables
 
+FUSED = EngineSpec(mode="sharded", shard_overlap="on")
 
-def _spec(strategy, executor, clients=64, rounds=2, **kw):
+
+def _spec(strategy, engine, clients=64, rounds=2, **kw):
     # experiment.py trains on the test_frac (0.2) side of the split: 100
     # samples/client keeps every Dirichlet shard non-empty at N=64.
     return ExperimentSpec(
         task="fcn", alpha=0.5, num_samples=100 * clients,
         fl=FLConfig(strategy=strategy, rounds=rounds, num_clients=clients,
                     num_models=clients, seed=0, topology_seed=1,
-                    max_diffusion_rounds=4, executor=executor, **kw))
+                    max_diffusion_rounds=4, engine=engine, **kw))
 
 
 # ------------------------------------------------- three-way parity at N=64
@@ -49,9 +52,8 @@ def test_host_fleet_sharded_parity_n64(strategy):
     ``FUSED_MIN_CLIENTS``) so the whole-round program is what parity
     certifies.
     """
-    results = {ex: run_experiment(_spec(strategy, ex,
-                                        **({"shard_overlap": "on"}
-                                           if ex == "sharded" else {})))
+    results = {ex: run_experiment(_spec(strategy,
+                                        FUSED if ex == "sharded" else ex))
                for ex in ("host", "fleet", "sharded")}
     host = results["host"]
     for ex in ("fleet", "sharded"):
@@ -71,8 +73,9 @@ def test_sharded_microbatches_session():
     """N=64 over shard_microbatch=16 runs the lax.map chunk path (4 chunks
     per shard on one device) and still matches the un-chunked fleet plane."""
     fleet = run_experiment(_spec("fedavg", "fleet", rounds=1))
-    shard = run_experiment(_spec("fedavg", "sharded", rounds=1,
-                                 shard_microbatch=16))
+    shard = run_experiment(_spec("fedavg", EngineSpec(mode="sharded",
+                                                      shard_microbatch=16),
+                                 rounds=1))
     assert fleet.ledger.as_dict() == shard.ledger.as_dict()
     for a, b in zip(jax.tree.leaves(fleet.final_params),
                     jax.tree.leaves(shard.final_params)):
@@ -85,8 +88,7 @@ def test_sharded_runs_every_schedule_op_kind():
     """tthf (MixOp), feddif_stc (compressed PermuteOp) and stc (stc_delta
     aggregation) all execute on the sharded plane."""
     for strategy in ("tthf", "feddif_stc", "stc"):
-        res = run_experiment(_spec(strategy, "sharded", clients=8, rounds=1,
-                                   shard_overlap="on",
+        res = run_experiment(_spec(strategy, FUSED, clients=8, rounds=1,
                                    tthf_cluster_size=4, tthf_global_period=1))
         assert len(res.accuracy) == 1
         assert np.all(np.isfinite(np.concatenate(
@@ -101,12 +103,12 @@ def test_sharded_parity_on_multi_device_mesh():
     code = """
 import numpy as np, jax
 assert len(jax.devices()) == 2, jax.devices()
-from repro.fl import ExperimentSpec, FLConfig, run_experiment
+from repro.fl import EngineSpec, ExperimentSpec, FLConfig, run_experiment
 def spec(executor, **kw):
     return ExperimentSpec(task="fcn", alpha=0.5, num_samples=240,
         fl=FLConfig(strategy="feddif", rounds=1, num_clients=8, num_models=8,
                     seed=0, topology_seed=1, max_diffusion_rounds=3,
-                    executor=executor, **kw))
+                    engine=EngineSpec(mode=executor, **kw)))
 host = run_experiment(spec("host"))
 shard = run_experiment(spec("sharded", shard_overlap="on"))
 assert host.ledger.as_dict() == shard.ledger.as_dict()
@@ -131,9 +133,9 @@ def test_hop_transport_parity_single_device():
     """``shard_hop_transport`` must not change results — "auto" resolves to
     gather for the tiny FCN, so force the ring plane explicitly and compare
     against gather (identical ledgers, matching params)."""
-    runs = {t: run_experiment(_spec("feddif", "sharded", clients=8,
-                                    rounds=1, shard_overlap="on",
-                                    shard_hop_transport=t))
+    runs = {t: run_experiment(_spec("feddif", dataclasses.replace(
+                                        FUSED, shard_hop_transport=t),
+                                    clients=8, rounds=1))
             for t in ("gather", "ring")}
     assert (runs["gather"].ledger.as_dict()
             == runs["ring"].ledger.as_dict())
@@ -151,12 +153,12 @@ def test_ring_transport_parity_on_multi_device_mesh():
     code = """
 import numpy as np, jax
 assert len(jax.devices()) == 2, jax.devices()
-from repro.fl import ExperimentSpec, FLConfig, run_experiment
+from repro.fl import EngineSpec, ExperimentSpec, FLConfig, run_experiment
 def spec(executor, **kw):
     return ExperimentSpec(task="fcn", alpha=0.5, num_samples=240,
         fl=FLConfig(strategy="feddif", rounds=2, num_clients=8, num_models=8,
                     seed=0, topology_seed=1, max_diffusion_rounds=3,
-                    executor=executor, **kw))
+                    engine=EngineSpec(mode=executor, **kw)))
 host = run_experiment(spec("host"))
 shard = run_experiment(spec("sharded", shard_overlap="on",
                             shard_hop_transport="ring"))
@@ -268,5 +270,5 @@ def test_churn_parity_across_executors():
 
 def test_rejects_unknown_executor_name():
     spec = _spec("fedavg", "warp", clients=4, rounds=1)
-    with pytest.raises(AssertionError):
+    with pytest.raises(ValueError, match="unknown engine preset 'warp'"):
         run_experiment(spec)
